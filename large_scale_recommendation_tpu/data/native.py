@@ -11,8 +11,11 @@ host-side ingest hot spots:
 
 Build is lazy and cached: first use compiles the .so with g++ into
 ``csrc/`` next to the source (no pybind11 — plain ``extern "C"`` + ctypes).
-Every entry point has a pure-NumPy fallback, so the framework works
-unchanged where no compiler exists.
+The .so is a build output, never committed (``.gitignore``): a checkout
+starts without one and builds it with its own toolchain, so a binary
+from another machine can never be loaded. Every entry point has a
+pure-NumPy fallback, so the framework works unchanged where no compiler
+exists — after a loud warning.
 """
 
 from __future__ import annotations
@@ -53,11 +56,19 @@ def _load() -> ctypes.CDLL | None:
         try:
             if (not os.path.exists(_SO)
                     or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-                subprocess.run(
-                    ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-                     "-o", _SO, _SRC],
-                    check=True, capture_output=True, timeout=120,
-                )
+                # build beside the target, then rename: a concurrent
+                # process never loads a half-written library
+                tmp = f"{_SO}.{os.getpid()}.tmp"
+                try:
+                    subprocess.run(
+                        ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
+                         "-o", tmp, _SRC],
+                        check=True, capture_output=True, timeout=120,
+                    )
+                    os.replace(tmp, _SO)
+                finally:
+                    if os.path.exists(tmp):
+                        os.remove(tmp)
             lib = ctypes.CDLL(_SO)
         except (OSError, subprocess.SubprocessError, FileNotFoundError) as e:
             _build_failed = True

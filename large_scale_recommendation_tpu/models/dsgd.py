@@ -26,6 +26,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -77,6 +78,12 @@ class DSGDConfig:
     # requires the default RegularizedSGDUpdater family,
     # collision_mode="mean" and precompute_collisions=True.
     kernel: str = "xla"
+    # run kernel="pallas" through the Pallas INTERPRETER instead of
+    # Mosaic — the explicit CPU spelling the parity tests use. Off a TPU
+    # without it, kernel="pallas" raises: nothing picks the interpreter
+    # by looking at the backend (it skips the VMEM/SMEM/alignment
+    # guards, so a silent slide into it would hide geometry errors too).
+    pallas_interpret: bool = False
     # factor table storage dtype: "float32" | "bfloat16" (the ALX
     # recipe, training half — ISSUE 6). bf16 halves the tables' HBM
     # footprint and per-sweep factor traffic; BOTH kernels accumulate
@@ -105,6 +112,11 @@ class DSGD:
             schedule=self.config.schedule_fn(),
         )
         self.model: MFModel | None = None
+        # which kernel the last fit ran: "xla", or "pallas/<route>" with
+        # the ops.pallas_sgd.pallas_route the geometry selected — the
+        # Pallas path is two kernels, and which one ran is decided by a
+        # VMEM model, not by the caller
+        self.kernel_route: str | None = None
         # divergence guard (obs.health.TrainingWatchdog): when attached,
         # each segment boundary scans the full tables for NaN/Inf (a
         # segment is seconds of work — the sweep is noise) and trips per
@@ -290,27 +302,36 @@ class DSGD:
             )
 
         if cfg.kernel == "xla":
+            self.kernel_route = "xla"
             return xla
         if cfg.kernel != "pallas":
             raise ValueError(
                 f"unknown kernel {cfg.kernel!r}; expected 'xla' or 'pallas'")
 
         from large_scale_recommendation_tpu.ops.pallas_sgd import (
-            default_interpret,
             dsgd_train_pallas,
+            pallas_route,
+            require_mosaic_platform,
             validate_pallas_contract,
         )
 
         upd = self.updater
         validate_pallas_contract(upd, cfg.collision_mode,
                                  args[-1] is not None)
+        require_mosaic_platform(jax.devices()[0].platform,
+                                cfg.pallas_interpret, "DSGD")
 
         def pallas(U, V, *, iterations, t0, k):
+            self.kernel_route = "pallas/" + pallas_route(
+                int(U.shape[0]) // k, int(V.shape[0]) // k,
+                int(U.shape[-1]), int(args[0].shape[-1]),
+                cfg.minibatch_size, U.dtype.itemsize,
+                interpret=cfg.pallas_interpret)
             return dsgd_train_pallas(
                 U, V, *args,
                 lr=float(upd.learning_rate), lam=float(upd.lambda_),
                 minibatch=cfg.minibatch_size, num_blocks=k,
-                iterations=iterations, interpret=default_interpret(),
+                iterations=iterations, interpret=cfg.pallas_interpret,
                 schedule=upd.schedule, t0=t0,
             )
 

@@ -11,10 +11,9 @@ behavior per kernel) and the way ALX's pod recipe requires (per-host
 HBM visibility):
 
 - ``Introspector.install()`` hooks the ONE funnel every jit compile in
-  this jax passes through (``jax._src.compiler.compile_or_get_cached``,
-  called via module attribute from ``pxla`` — verified at install, and
-  a moved internal degrades to "not installable", never an import
-  error). Each captured executable records its
+  the installed jax passes through
+  (``jax._src.compiler.compile_or_get_cached``, called via module
+  attribute from ``pxla``). Each captured executable records its
   ``cost_analysis()`` FLOPs / bytes-accessed, its
   ``get_compiled_memory_stats()``, and the measured compile wall — and
   is attributed to the *enclosing tracer compile key*
@@ -70,13 +69,24 @@ from typing import Any
 from large_scale_recommendation_tpu.obs.registry import get_registry
 from large_scale_recommendation_tpu.obs.trace import get_tracer
 
-# Chip peaks for the roofline denominators — v5e (TPU v5 lite) single
-# chip, the bench hardware. bench.py mirrors these values (it cannot
-# import the package at module scope: backend-init ordering), so a
-# change here must change there — both sides carry this note.
+# Chip peaks for the roofline denominators — one TPU v5e chip, which
+# jax reports as device_kind "TPU v5 lite". They describe THAT device:
+# on any other (a CPU run, another TPU generation) every share-of-peak
+# field is None, never a share of a v5e's peak (``device_peaks``).
+PEAKS_DEVICE_KIND = "TPU v5 lite"
 HBM_PEAK_GBS = 819.0
 BF16_PEAK_TFLOPS = 197.0
 FP32_PEAK_TFLOPS = 49.0
+
+
+def device_peaks() -> tuple[float | None, float | None]:
+    """``(HBM GB/s, fp32 TFLOP/s)`` of the local device when it is the
+    one the constants describe, else ``(None, None)``."""
+    import jax
+
+    if jax.devices()[0].device_kind == PEAKS_DEVICE_KIND:
+        return HBM_PEAK_GBS, FP32_PEAK_TFLOPS
+    return None, None
 
 DEFAULT_MAX_RECORDS = 1024
 
@@ -183,19 +193,16 @@ class Introspector:
         return self._orig is not None
 
     def install(self) -> bool:
-        """Patch the compile funnel. Returns whether the hook could be
-        installed (False when the jax internal moved — introspection is
-        then unavailable, nothing else breaks)."""
+        """Patch the compile funnel. Returns whether this introspector
+        owns the hook (False when another one already does)."""
         if self._orig is not None:
             return True
-        try:
-            import jax._src.compiler as compiler
-        except ImportError:  # pragma: no cover - jax layout drift
-            return False
-        target = getattr(compiler, "compile_or_get_cached", None)
-        if target is None or hasattr(target, "__lsr_introspector__"):
-            # absent internal, or another introspector already owns the
-            # funnel — stacking hooks would double-count every compile
+        import jax._src.compiler as compiler
+
+        target = compiler.compile_or_get_cached
+        if hasattr(target, "__lsr_introspector__"):
+            # another introspector already owns the funnel — stacking
+            # hooks would double-count every compile
             return False
         introspector = self
 
@@ -334,13 +341,17 @@ class Introspector:
 
     # -- roofline join -------------------------------------------------------
 
-    def roofline(self, hbm_peak_gbs: float = HBM_PEAK_GBS,
-                 fp32_peak_tflops: float = FP32_PEAK_TFLOPS) -> dict:
+    def roofline(self, hbm_peak_gbs: float | None = None,
+                 fp32_peak_tflops: float | None = None) -> dict:
         """The live per-kernel roofline table (the ``/rooflinez``
         body): one row per compile key joining XLA's cost analysis with
         the tracer's measured execute walls and the registered hand
         models. Keys whose spans never executed steady-state rows carry
-        the cost analysis alone (wall fields None)."""
+        the cost analysis alone (wall fields None). The peaks default to
+        ``device_peaks()``: None off the described device, and the
+        share-of-peak columns with them."""
+        if hbm_peak_gbs is None and fp32_peak_tflops is None:
+            hbm_peak_gbs, fp32_peak_tflops = device_peaks()
         walls = {render_key(k): v
                  for k, v in self._tracer.key_walls().items()}
         rows = roofline_rows(self.records(), walls, self.model_costs(),
@@ -367,15 +378,15 @@ class Introspector:
             return 0
         published = 0
         for row in self.roofline()["rows"]:
-            if row["pct_of_hbm_peak"] is None:
+            if row["achieved_gbs"] is None:
                 continue
             key = row["key"]
-            self._obs.gauge("xla_pct_of_hbm_peak", key=key).set(
-                row["pct_of_hbm_peak"])
-            self._obs.gauge("xla_pct_of_fp32_peak", key=key).set(
-                row["pct_of_fp32_peak"])
             self._obs.gauge("xla_achieved_gbs", key=key).set(
                 row["achieved_gbs"])
+            # share-of-peak gauges only on the device the peaks describe
+            for name in ("pct_of_hbm_peak", "pct_of_fp32_peak"):
+                if row[name] is not None:
+                    self._obs.gauge(f"xla_{name}", key=key).set(row[name])
             published += 1
         return published
 
@@ -489,8 +500,8 @@ class _FixedKeyTracer:
 
 
 def roofline_rows(records: list[dict], walls: dict, model_costs: dict,
-                  *, hbm_peak_gbs: float = HBM_PEAK_GBS,
-                  fp32_peak_tflops: float = FP32_PEAK_TFLOPS) -> list[dict]:
+                  *, hbm_peak_gbs: float | None = None,
+                  fp32_peak_tflops: float | None = None) -> list[dict]:
     """The PURE join (pinned against a hand-computed reference in
     tests/test_obs_introspect.py): per compile key, pick the dominant
     executable (max bytes-accessed — a keyed span family compiles
@@ -499,7 +510,8 @@ def roofline_rows(records: list[dict], walls: dict, model_costs: dict,
 
     - ``wall_per_exec``   = execute_total_s / execute_count
     - ``achieved_gbs``    = bytes_accessed / wall_per_exec / 1e9
-    - ``pct_of_hbm_peak`` = 100 · achieved_gbs / hbm_peak_gbs
+    - ``pct_of_hbm_peak`` = 100 · achieved_gbs / hbm_peak_gbs (None
+      when no peak is given — the device is not the described one)
     - ``achieved_tflops`` / ``pct_of_fp32_peak`` likewise from flops
     - ``xla_vs_model_bytes`` = bytes_accessed / (model bytes ×
       iterations-per-execution) — the hand-model cross-check
@@ -543,10 +555,12 @@ def roofline_rows(records: list[dict], walls: dict, model_costs: dict,
                 row["wall_per_exec_s"] = wall
                 row["achieved_gbs"] = dom["bytes_accessed"] / wall / 1e9
                 row["achieved_tflops"] = dom["flops"] / wall / 1e12
-                row["pct_of_hbm_peak"] = (
-                    100.0 * row["achieved_gbs"] / hbm_peak_gbs)
-                row["pct_of_fp32_peak"] = (
-                    100.0 * row["achieved_tflops"] / fp32_peak_tflops)
+                if hbm_peak_gbs:
+                    row["pct_of_hbm_peak"] = (
+                        100.0 * row["achieved_gbs"] / hbm_peak_gbs)
+                if fp32_peak_tflops:
+                    row["pct_of_fp32_peak"] = (
+                        100.0 * row["achieved_tflops"] / fp32_peak_tflops)
             iters_per_exec = w.get("iterations", n_exec) / n_exec
             mc = model_costs.get(key)
             if mc and mc.get("bytes_per_iteration"):

@@ -544,16 +544,10 @@ class Tracer:
         """Tap ``jax.monitoring`` duration events: backend compile events
         land in ``registry`` (default: the module-level one) as a
         ``jax_compile_s`` histogram and in the trace as instant events.
-        Returns whether the hook could be installed (older jax versions
-        may lack the API)."""
-        try:
-            from jax import monitoring
-        except ImportError:  # pragma: no cover - version-dependent
-            return False
-        register = getattr(monitoring,
-                           "register_event_duration_secs_listener", None)
-        if register is None:  # pragma: no cover - version-dependent
-            return False
+        Returns True (a ``NullTracer`` installs nothing and returns
+        False)."""
+        from jax import monitoring
+
         if registry is None:
             from large_scale_recommendation_tpu.obs.registry import (
                 get_registry,
@@ -567,7 +561,7 @@ class Tracer:
             registry.histogram("jax_compile_s", event=event).observe(duration)
             self.instant("jax_compile", event=event, duration_s=duration)
 
-        register(_listener)
+        monitoring.register_event_duration_secs_listener(_listener)
         return True
 
     # -- export -------------------------------------------------------------

@@ -120,14 +120,11 @@ def _build_mesh_als_step(
 
         def varying_zeros(shape):
             # fresh accumulators marked device-varying so the VMA check can
-            # verify the per-shard writes into them (older jax has no VMA
-            # type system — nothing to annotate, the zeros pass through;
-            # the rank-sharded route runs with the checker off, so the
-            # annotation is skipped there too)
+            # verify the per-shard writes into them (the rank-sharded route
+            # runs with the checker off, so the annotation is skipped there)
             z = jnp.zeros(shape, jnp.float32)
-            pcast = getattr(jax.lax, "pcast", None)
-            return (pcast(z, axis, to="varying")
-                    if pcast and not rank_sharded else z)
+            return z if rank_sharded else jax.lax.pcast(z, axis,
+                                                        to="varying")
 
         def full_gram(F):
             # the shared iALS VᵀV term. Replicated tables: one [r, r]

@@ -74,17 +74,9 @@ def initialize_distributed(config: DistributedConfig | None = None) -> bool:
         return False
     import jax
 
-    # XLA:CPU runs a computation spanning processes only through an
-    # explicit cross-process collectives layer; gloo ships with jaxlib
-    # but is NOT the default here — without it every cross-host jit dies
-    # with "Multiprocess computations aren't implemented on the CPU
-    # backend" (measured on the 2-process local cluster). Accelerator
-    # backends ignore the knob, so set it unconditionally; tolerate jax
-    # versions that renamed/removed it.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    # XLA:CPU runs a computation spanning processes through gloo, the
+    # installed jax's default ``jax_cpu_collectives_implementation``;
+    # nothing is set here, so a process that owns TPUs is left alone.
     jax.distributed.initialize(
         coordinator_address=cfg.coordinator_address,
         num_processes=cfg.num_processes,

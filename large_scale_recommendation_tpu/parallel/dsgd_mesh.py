@@ -155,8 +155,8 @@ def _build_mesh_dsgd_step(
         mesh=part.mesh,
         in_specs=factor_in + (spec,) * (n_sharded - 2) + (part.spec(),),
         out_specs=factor_in,
-        # the replication checker has no rule for pallas_call at all on
-        # this jax ("No replication rule for pallas_call" — AOT-measured,
+        # the replication checker has no rule for pallas_call at all
+        # ("No replication rule for pallas_call" — AOT-measured,
         # docs/MOSAIC_AOT.json), and the Pallas interpreter's internal
         # scan additionally drops varying-axis metadata on index arrays;
         # the rank-sharded route mixes model-axis-varying factor slices
@@ -260,6 +260,7 @@ class MeshDSGDConfig:
     precompute_collisions: bool = True  # see DSGDConfig
     minibatch_sort: str | None = None  # see DSGDConfig
     kernel: str = "xla"  # "xla" | "pallas" — see DSGDConfig.kernel
+    pallas_interpret: bool = False  # see DSGDConfig.pallas_interpret
     # "float32" | "bfloat16" — see DSGDConfig.factor_dtype: half-width
     # factor shards at rest (HBM, checkpoints, the ppermute ring) with
     # f32 accumulation inside both kernels
@@ -470,9 +471,15 @@ class MeshDSGD:
         with_inv = bool(inv_args)
         inv_args = tuple(part.place(x, "ratings") for x in inv_args)
 
-        from large_scale_recommendation_tpu.ops.pallas_sgd import (
-            default_interpret,
-        )
+        if cfg.kernel == "pallas":
+            from large_scale_recommendation_tpu.ops.pallas_sgd import (
+                require_mosaic_platform,
+            )
+
+            # the MESH's devices decide, not the default backend: a CPU
+            # mesh on a TPU host still cannot run Mosaic
+            require_mosaic_platform(part.mesh.devices.flat[0].platform,
+                                    cfg.pallas_interpret, "MeshDSGD")
 
         from large_scale_recommendation_tpu.obs.instrument import (
             TrainSegmentTimer,
@@ -488,7 +495,7 @@ class MeshDSGD:
             step_fn = build_mesh_dsgd_step(
                 part, self.updater, cfg.minibatch_size, k, seg,
                 cfg.collision_mode, with_inv, cfg.kernel,
-                default_interpret() if cfg.kernel == "pallas" else False,
+                cfg.kernel == "pallas" and cfg.pallas_interpret,
             )
             with timer.segment(seg) as h:
                 U, V = step_fn(U, V, *args, ou, ov, *inv_args,

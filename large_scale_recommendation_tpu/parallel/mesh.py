@@ -11,20 +11,8 @@ instead of engine shuffles (SURVEY §2.3).
 from __future__ import annotations
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-try:  # jax ≥ 0.6 exports shard_map at the top level (check_vma kwarg)
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, /, *args, check_vma=None, **kwargs):
-        """Compat wrapper: the experimental shard_map spells the
-        replication-check knob ``check_rep``; translate the modern name
-        so call sites are written once against the current API."""
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _shard_map(f, *args, **kwargs)
 
 __all__ = [
     "BLOCK_AXIS", "shard_map", "select_devices", "make_block_mesh",
@@ -36,33 +24,22 @@ BLOCK_AXIS = "blocks"
 
 def select_devices(num_devices: int | None = None, devices=None) -> list:
     """The device pick every mesh constructor shares (``make_block_mesh``
-    and the Partitioner's ``('data', 'model')`` mesh): global
-    ``jax.devices()`` order with the virtual-CPU fallback, truncated to
-    ``num_devices`` — so rings built by either constructor rotate over
-    the same devices in the same order."""
+    and the Partitioner's ``('data', 'model')`` mesh): the first
+    ``num_devices`` of ``devices`` (default: global ``jax.devices()``
+    order) — so rings built by either constructor rotate over the same
+    devices in the same order. Asking for more devices than exist
+    raises: a mesh never lands on another platform than the one asked
+    for. CPU-mesh callers (tests, the dry runs) call
+    ``utils.platform.force_cpu(n_devices=...)`` first or pass
+    ``devices=``."""
     if devices is None:
-        # NOTE: ``jax.devices()`` initializes every backend the
-        # ``jax_platforms`` config names, and a broken accelerator plugin
-        # can raise or hang that init — nothing recoverable here. Entry
-        # points that must never touch the accelerator (tests,
-        # dryrun_multichip) call ``utils.platform.force_cpu()`` before the
-        # first backend init and/or pass explicit ``devices=``.
         devices = jax.devices()
-        if num_devices is not None and len(devices) < num_devices:
-            # Single-accelerator hosts still expose N virtual CPU devices
-            # under --xla_force_host_platform_device_count; multi-chip code
-            # paths are validated there (SURVEY §4).
-            try:
-                cpu = jax.devices("cpu")
-            except RuntimeError:
-                cpu = []
-            if len(cpu) >= num_devices:
-                devices = cpu
     if num_devices is not None:
         if len(devices) < num_devices:
+            platform = devices[0].platform if len(devices) else "none"
             raise ValueError(
-                f"need {num_devices} devices, have {len(devices)}"
-            )
+                f"need {num_devices} devices, have {len(devices)} "
+                f"({platform})")
         devices = devices[:num_devices]
     return list(devices)
 

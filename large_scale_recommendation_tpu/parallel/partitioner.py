@@ -95,8 +95,8 @@ def make_data_model_mesh(num_devices: int | None = None, devices=None,
     ``data`` is the block ring (k = total devices / model_parallel);
     ``model`` is the factor-rank sharding axis (default size 1). The
     device pick order matches ``make_block_mesh`` (global ``jax.devices()``
-    order, virtual-CPU fallback), so a ring over the same devices rotates
-    the same way whichever constructor built it.
+    order; more devices than exist raises), so a ring over the same
+    devices rotates the same way whichever constructor built it.
     """
     devices = select_devices(num_devices, devices)
     n = len(devices)

@@ -22,11 +22,14 @@ A Mosaic lowering failure is itself a result: it prints as
 Usage:
     python scripts/pallas_probe.py                    # current device
     PROBE_RANK=64 PROBE_MB=1024 python scripts/pallas_probe.py
-    PROBE_CPU=1 python scripts/pallas_probe.py        # interpret fallback
+    PROBE_CPU=1 python scripts/pallas_probe.py        # CPU, interpreted
+                                                      # (explicit; off a
+                                                      # TPU without it
+                                                      # the probe raises)
 
 Defaults model one ML-25M block visit at k=32 (rpb_u 5080, rpb_v 1848,
 ~24K ratings) — the production operating point since the k=16 visit
-OOM'd under this jax's 2× stream buffering (docs/MOSAIC_AOT.json);
+OOM'd under the pipeline's 2× stream buffering (docs/MOSAIC_AOT.json);
 VMEM-sized for v5e at rank 128.
 """
 
@@ -41,15 +44,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> None:
-    if os.environ.get("PROBE_CPU") == "1":
-        from large_scale_recommendation_tpu.utils.platform import force_cpu
+    from large_scale_recommendation_tpu.utils.platform import (
+        enable_compilation_cache,
+        force_cpu,
+    )
 
+    on_cpu = os.environ.get("PROBE_CPU") == "1"
+    if on_cpu:
         force_cpu()
 
     import jax
 
+    enable_compilation_cache()
     dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
     rank = int(os.environ.get("PROBE_RANK", 128))
     mb = int(os.environ.get("PROBE_MB", 2048))
     rpb_u = int(os.environ.get("PROBE_RPB_U", 5080))
@@ -68,9 +75,10 @@ def main() -> None:
     res = probe_variants(rank=rank, mb=mb, rpb_u=rpb_u, rpb_v=rpb_v,
                          nnz=e, reps=reps,
                          sort=os.environ.get("PROBE_SORT") == "1",
-                         interpret=not on_tpu)
+                         interpret=on_cpu)
     summary = {
-        "device": str(dev), "tpu": on_tpu, "rank": rank, "mb": mb,
+        "device": str(dev), "tpu": dev.platform == "tpu",
+        "interpreted": on_cpu, "rank": rank, "mb": mb,
         "rpb_u": rpb_u, "rpb_v": rpb_v, "nnz": e, "reps": reps,
     }
     for label, val in res.items():

@@ -74,7 +74,13 @@ def run_two_process_pass(timeout_s: float = 420.0) -> dict:
     """The 2-process local-cluster smoke: launch the distributed demo as
     two coordinated processes (own env — the parent's virtual-device
     XLA flags must not leak) and report pass/fail + the markers that
-    prove each multi-host piece ran."""
+    prove each multi-host piece ran.
+
+    CPU/gloo by design, on any machine: this parent has already imported
+    jax (on the CPU) and both children are pinned to
+    ``JAX_PLATFORMS=cpu``, so nothing here ever asks for a chip — a chip
+    belongs to one process, and ``chip_smoke.py`` is the one-process run
+    on it."""
     import tempfile
 
     with socket.socket() as s:

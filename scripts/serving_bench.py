@@ -50,7 +50,9 @@ rounds are committed as ``SERVING_r*.json`` and gated by
 
 Env knobs (micro): SERVE_USERS, SERVE_ITEMS, SERVE_RANK,
 SERVE_REQUESTS, SERVE_REQ_MAX, SERVE_K, SERVE_MAX_BATCH, SERVE_DEVICES,
-SERVE_FORCE_CPU (=0 to use the default jax backend).
+SERVE_FORCE_CPU (=1 → CPU with SERVE_DEVICES virtual devices — what the
+contract tests and CI set; the default is jax's default backend, and the
+result names the platform it ran on).
 Traffic adds: SERVE_CENTERS, SERVE_CLUSTERS (0 = flat int8 stage 1),
 SERVE_PROBE, SERVE_OVERFETCH, SERVE_PATTERN, SERVE_LEVELS (offered-QPS
 multipliers of measured capacity), SERVE_SLO_MS, SERVE_DEADLINE_MS,
@@ -619,10 +621,15 @@ def run_traffic(num_users=20_000, num_items=262_144, rank=64,
 
 
 def main() -> None:
-    if os.environ.get("SERVE_FORCE_CPU", "1") == "1":
-        from large_scale_recommendation_tpu.utils.platform import force_cpu
+    from large_scale_recommendation_tpu.utils.platform import (
+        enable_compilation_cache,
+        force_cpu,
+        stamp_device,
+    )
 
+    if os.environ.get("SERVE_FORCE_CPU") == "1":
         force_cpu(n_devices=int(os.environ.get("SERVE_DEVICES", 8)))
+    enable_compilation_cache()
     env = os.environ.get
     if env("SERVE_MODE", "micro") == "traffic":
         result = run_traffic(
@@ -656,6 +663,8 @@ def main() -> None:
             k=int(env("SERVE_K", 10)),
             max_batch=int(env("SERVE_MAX_BATCH", 1024)),
         )
+    # nothing here is a chip number unless this says ``tpu``
+    print(f"# ran on {stamp_device(result['extra'])}", file=sys.stderr)
     _emit_final(result)
 
 

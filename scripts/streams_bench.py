@@ -95,8 +95,9 @@ above still rides ``error``).
 
 Env knobs: STREAMS_USERS, STREAMS_ITEMS, STREAMS_RANK, STREAMS_BATCHES,
 STREAMS_BATCH (records per micro-batch), STREAMS_CHECKPOINT_EVERY,
-STREAMS_FSYNC (=1 to fsync appends), STREAMS_FORCE_CPU (=0 for the
-default jax backend). Parallel mode adds: STREAMS_CONSUMERS (the N
+STREAMS_FSYNC (=1 to fsync appends), STREAMS_FORCE_CPU (=1 pins the CPU;
+the default is jax's default backend, and the result names the platform
+it ran on). Parallel mode adds: STREAMS_CONSUMERS (the N
 curve; presence selects the mode), STREAMS_FRESHNESS_S (sustained-pass
 duration, 0 skips), STREAMS_RECOVERY (=0 skips the kill/restart pass),
 STREAMS_CONTENTION_OUT (path for the sustained pass's /contentionz
@@ -849,10 +850,15 @@ def _sustained_pass(tmp, n, total_users, total_items, rank,
 
 
 def main() -> None:
-    if os.environ.get("STREAMS_FORCE_CPU", "1") == "1":
-        from large_scale_recommendation_tpu.utils.platform import force_cpu
+    from large_scale_recommendation_tpu.utils.platform import (
+        enable_compilation_cache,
+        force_cpu,
+        stamp_device,
+    )
 
+    if os.environ.get("STREAMS_FORCE_CPU") == "1":
         force_cpu()
+    enable_compilation_cache()
     consumers = os.environ.get("STREAMS_CONSUMERS")
     tier_slots = os.environ.get("STREAMS_TIER_SLOTS")
     if tier_slots:
@@ -893,6 +899,8 @@ def main() -> None:
                 os.environ.get("STREAMS_CHECKPOINT_EVERY", 1)),
             fsync=os.environ.get("STREAMS_FSYNC") == "1",
         )
+    # nothing here is a chip number unless this says ``tpu``
+    print(f"# ran on {stamp_device(result['extra'])}", file=sys.stderr)
     _emit_final(result)
 
 

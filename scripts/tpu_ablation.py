@@ -26,13 +26,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> None:
-    if os.environ.get("ABL_CPU") == "1":
-        from large_scale_recommendation_tpu.utils.platform import force_cpu
+    from large_scale_recommendation_tpu.utils.platform import (
+        enable_compilation_cache,
+        force_cpu,
+    )
 
+    if os.environ.get("ABL_CPU") == "1":
         force_cpu()
 
     import numpy as np
     import jax
+
+    enable_compilation_cache()
 
     from large_scale_recommendation_tpu.core.updaters import (
         RegularizedSGDUpdater,

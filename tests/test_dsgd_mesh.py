@@ -124,7 +124,7 @@ class TestMeshDSGD:
 
     def test_pallas_kernel_matches_single_device(self, gen):
         """kernel='pallas' on the mesh (per-device block sweeps through the
-        VMEM-staged Pallas path inside shard_map, interpret mode on CPU)
+        VMEM-staged Pallas path inside shard_map, explicitly interpreted)
         must match the single-device XLA run — so a measured kernel win on
         hardware needs zero plumbing on the mesh too (VERDICT r4 #4).
         Decaying schedule on purpose: exercises the runtime-scalar η."""
@@ -134,7 +134,7 @@ class TestMeshDSGD:
                               learning_rate=0.05,
                               lr_schedule="inverse_sqrt",
                               seed=0, minibatch_size=256, init_scale=0.3,
-                              kernel="pallas")
+                              kernel="pallas", pallas_interpret=True)
         mm = MeshDSGD(mcfg, mesh=mesh).fit(train)
 
         scfg = DSGDConfig(num_factors=8, lambda_=0.01, iterations=3,

@@ -239,18 +239,73 @@ def test_dsgd_kernel_flag_routes_through_pallas():
               learning_rate=0.05, lr_schedule="inverse_sqrt", seed=0,
               minibatch_size=128, init_scale=0.3)
     mx = DSGD(DSGDConfig(**kw, kernel="xla")).fit(train, num_blocks=2)
-    mp = DSGD(DSGDConfig(**kw, kernel="pallas")).fit(train, num_blocks=2)
+    pallas = DSGD(DSGDConfig(**kw, kernel="pallas", pallas_interpret=True))
+    mp = pallas.fit(train, num_blocks=2)
     np.testing.assert_allclose(np.asarray(mp.U), np.asarray(mx.U),
                                rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(np.asarray(mp.V), np.asarray(mx.V),
                                rtol=2e-4, atol=2e-5)
+    # the run says which of the two Pallas kernels it was
+    assert pallas.kernel_route == "pallas/stratum_pipeline"
 
     with pytest.raises(ValueError, match="pallas"):
         DSGD(DSGDConfig(**{**kw, "collision_mode": "sum"},
-                        kernel="pallas")).fit(train, num_blocks=2)
+                        kernel="pallas", pallas_interpret=True)).fit(
+                            train, num_blocks=2)
     with pytest.raises(ValueError, match="kernel"):
         DSGD(DSGDConfig(**kw, kernel="tensorcore")).fit(train,
                                                         num_blocks=2)
+
+
+def test_pallas_off_tpu_raises_unless_interpretation_is_explicit():
+    """kernel='pallas' on a non-TPU backend must RAISE, not quietly run
+    the interpreter (which also skips every VMEM/SMEM/alignment guard):
+    interpretation is the caller's explicit ``pallas_interpret=True``.
+    Single-device model, mesh model and the probe all obey it."""
+    from large_scale_recommendation_tpu.core.generators import (
+        SyntheticMFGenerator,
+    )
+    from large_scale_recommendation_tpu.models.dsgd import DSGD, DSGDConfig
+    from large_scale_recommendation_tpu.ops.pallas_sgd import (
+        probe_variants,
+    )
+    from large_scale_recommendation_tpu.parallel.dsgd_mesh import (
+        MeshDSGD,
+        MeshDSGDConfig,
+    )
+    from large_scale_recommendation_tpu.parallel.partitioner import (
+        Partitioner,
+    )
+
+    train = SyntheticMFGenerator(num_users=32, num_items=24, rank=4,
+                                 noise=0.1, seed=1).generate(800)
+    kw = dict(num_factors=8, iterations=1, minibatch_size=64,
+              kernel="pallas")
+    with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
+        DSGD(DSGDConfig(**kw)).fit(train, num_blocks=2)
+    with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
+        MeshDSGD(MeshDSGDConfig(**kw),
+                 partitioner=Partitioner(num_devices=2)).fit(train)
+    with pytest.raises(RuntimeError, match="needs a TPU.*'cpu'"):
+        probe_variants(rank=8, mb=64, rpb_u=32, rpb_v=24, nnz=128, reps=1,
+                       variants=("pallas_loop",))
+
+
+def test_pallas_route_reports_the_kernel_the_budget_selects():
+    """``pipeline=None`` keeps routing by the VMEM/SMEM model — and the
+    route is now a visible answer: the AOT-accepted pipelined geometry
+    (ML-25M k=32, mb 1024, f32) is the stratum pipeline, the bench's own
+    k=32 / mb 2048 geometry models 15.9 MB > 14 and is per-block."""
+    from large_scale_recommendation_tpu.ops.pallas_sgd import pallas_route
+
+    assert pallas_route(5080, 1848, 128, 24576, 1024, 4) == \
+        "stratum_pipeline"
+    assert pallas_route(5080, 1848, 128, 24576, 2048, 4) == "per_block"
+    assert pallas_route(5080, 1848, 128, 24576, 2048, 4,
+                        gather="take") == "per_block"
+    # interpretation skips the budgets, so it always pipelines
+    assert pallas_route(5080, 1848, 128, 24576, 2048, 4,
+                        interpret=True) == "stratum_pipeline"
 
 
 def test_full_training_schedule_parity():
@@ -394,6 +449,7 @@ def test_bf16_training_parity_and_rmse():
                - np.asarray(test.ratings, np.float64)) * np.asarray(mask)
         return float(np.sqrt((err ** 2).sum() / max(mask.sum(), 1)))
 
+    kw["pallas_interpret"] = True  # read by kernel="pallas" only
     for kernel in ("xla", "pallas"):
         m32 = DSGD(DSGDConfig(**kw, kernel=kernel)).fit(train,
                                                         num_blocks=2)

@@ -59,8 +59,8 @@ class TestRulesTable:
             assert part.spec("users", "rank") == part.spec("items", "rank")
 
     def test_all_axes_resolve_on_16_device_abstract_mesh(self):
-        part = Partitioner(mesh=AbstractMesh(((DATA_AXIS, 16),
-                                              (MODEL_AXIS, 1))))
+        part = Partitioner(mesh=AbstractMesh((16, 1),
+                                             (DATA_AXIS, MODEL_AXIS)))
         assert part.num_blocks == 16
         for name in LOGICAL_AXES:
             part.spec(name)
@@ -95,8 +95,8 @@ class TestRulesTable:
         assert list(part.ring_backward()) == ring_backward(8)
 
     def test_model_parallel_guard(self):
-        part = Partitioner(mesh=AbstractMesh(((DATA_AXIS, 4),
-                                              (MODEL_AXIS, 2))))
+        part = Partitioner(mesh=AbstractMesh((4, 2),
+                                             (DATA_AXIS, MODEL_AXIS)))
         assert part.model_parallel == 2
         with pytest.raises(NotImplementedError, match="rank"):
             part.require_no_model_parallel("mesh DSGD")
@@ -331,6 +331,40 @@ class TestShardingFunnel:
         assert mesh.axis_names == (BLOCK_AXIS,)
         assert list(mesh.devices.flat) == select_devices(4)
         assert mesh == make_legacy_block_mesh(4)
+
+    def test_more_devices_than_exist_raises_instead_of_going_to_cpu(
+            self, monkeypatch):
+        """A one-chip host asked for a 4-device mesh must RAISE. The
+        removed behaviour: ``select_devices`` looked at
+        ``jax.devices("cpu")`` and quietly handed back virtual CPU
+        devices, so ``Partitioner(num_devices=4)`` "worked" — off the
+        chip."""
+        import jax
+
+        from large_scale_recommendation_tpu.parallel import mesh as mesh_mod
+
+        cpus = jax.devices()
+        assert len(cpus) >= 4  # the virtual devices the fallback found
+
+        class OneChip:
+            platform = "tpu"
+
+        chip = [OneChip()]
+
+        def devices(backend=None):
+            return cpus if backend == "cpu" else chip
+
+        monkeypatch.setattr(mesh_mod.jax, "devices", devices)
+        assert mesh_mod.select_devices() == chip
+        with pytest.raises(ValueError, match=r"need 4 devices, have 1 "
+                                             r"\(tpu\)"):
+            mesh_mod.select_devices(4)
+        with pytest.raises(ValueError, match="need 4 devices, have 1"):
+            Partitioner(num_devices=4)
+        with pytest.raises(ValueError, match="need 4 devices, have 1"):
+            make_block_mesh(4)
+        # explicit devices= is how CPU-mesh callers name their devices
+        assert mesh_mod.select_devices(4, devices=cpus) == list(cpus[:4])
 
     def test_replicated_equals_hand_rolled(self):
         from large_scale_recommendation_tpu.parallel.mesh import (

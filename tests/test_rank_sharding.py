@@ -99,7 +99,8 @@ class TestMeshDSGDParity:
 
         ru, ri, rv, _ = ratings.to_numpy()
         part = Partitioner(num_devices=8, model_parallel=2)
-        cfg = dataclasses.replace(_dsgd_cfg(), kernel="pallas")
+        cfg = dataclasses.replace(_dsgd_cfg(), kernel="pallas",
+                                  pallas_interpret=True)
         with pytest.raises(NotImplementedError, match="model"):
             MeshDSGD(cfg, partitioner=part).fit_device(ru, ri, rv, NU, NI)
 

@@ -425,7 +425,8 @@ class TestStoreObs:
 
             out = write_bundle(str(tmp_path), trigger="test")
             doc = load_bundle(out)
-            assert doc["manifest"]["bundle_version"] == 7
+            # the store plane arrived at bundle v7; later planes bump it
+            assert doc["manifest"]["bundle_version"] >= 7
             assert doc["store"]["hot"]["slot_capacity"] == 32
             assert doc["store"]["cold"]["rows"] == m.users.num_rows
         finally:
