@@ -242,9 +242,10 @@ def _errors(ratings: jax.Array, u: jax.Array, v: jax.Array,
     the rank-sharded mesh kernels hold only a rank slice of u/v, so the
     full dot is a ``psum`` over the ``'model'`` axis that must happen
     OUTSIDE the updater (ops.sgd.sgd_minibatch_update computes it)."""
-    if pred is not None:
-        return ratings - pred
-    return ratings - jnp.einsum("bk,bk->b", u, v)
+    with jax.named_scope("residual"):  # HLO metadata only
+        if pred is not None:
+            return ratings - pred
+        return ratings - jnp.einsum("bk,bk->b", u, v)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -50,6 +50,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from large_scale_recommendation_tpu.obs.trace import get_tracer
 
 # --------------------------------------------------------------------------
 # Synthetic generation (device)
@@ -346,27 +347,34 @@ def _bucket_entries(key, u, i, r, w, row_of_u, row_of_i,
     shuffle + stable bucket sort, data/blocking.py ``block_ratings``).
     Weight-0 padding entries keep their slots (static shapes) but carry
     w=0 through to the layout — no-ops everywhere downstream."""
-    urow = row_of_u[u]
-    irow = row_of_i[i]
-    ublk = urow // rpb_u
-    iblk = irow // rpb_v
-    strat = (iblk - ublk) % k
-    flat = (strat * k + ublk).astype(jnp.int32)
-    # padding entries spread round-robin over ALL buckets: their ids are 0
-    # so they would otherwise pile into one bucket and inflate bmax (and
-    # the whole k²·bmax layout) by the total pad count
-    n = flat.shape[0]
-    flat = jnp.where(w > 0, flat,
-                     jnp.arange(n, dtype=jnp.int32) % (k * k))
-    sizes = jnp.zeros(k * k, jnp.int32).at[flat].add(1)
+    # named scopes here and in _layout: HLO metadata only, so a device
+    # trace can name the phases (the sorts, the offsets, the scatters)
+    with jax.named_scope("bucket/assign"):
+        urow = row_of_u[u]
+        irow = row_of_i[i]
+        ublk = urow // rpb_u
+        iblk = irow // rpb_v
+        strat = (iblk - ublk) % k
+        flat = (strat * k + ublk).astype(jnp.int32)
+        # padding entries spread round-robin over ALL buckets: their ids
+        # are 0 so they would otherwise pile into one bucket and inflate
+        # bmax (and the whole k²·bmax layout) by the total pad count
+        n = flat.shape[0]
+        flat = jnp.where(w > 0, flat,
+                         jnp.arange(n, dtype=jnp.int32) % (k * k))
+    with jax.named_scope("bucket/sizes"):
+        sizes = jnp.zeros(k * k, jnp.int32).at[flat].add(1)
     # seeded permutation + stable bucket sort: buckets become contiguous
     # runs with random within-bucket order (≙ the host pass's shuffle +
     # stable counting sort; avoids 64-bit composite keys, see _assign_rows)
-    perm = jax.random.permutation(key, n)
-    order = perm[jnp.argsort(flat[perm], stable=True)]
-    return (sizes, flat[order], urow[order], irow[order],
-            jnp.asarray(r, jnp.float32)[order],
-            jnp.asarray(w, jnp.float32)[order])
+    with jax.named_scope("bucket/permutation"):
+        perm = jax.random.permutation(key, n)
+    with jax.named_scope("bucket/sort"):
+        order = perm[jnp.argsort(flat[perm], stable=True)]
+    with jax.named_scope("bucket/reorder"):
+        return (sizes, flat[order], urow[order], irow[order],
+                jnp.asarray(r, jnp.float32)[order],
+                jnp.asarray(w, jnp.float32)[order])
 
 
 def _inv_counts_2d(rows: jax.Array, w: jax.Array,
@@ -423,19 +431,21 @@ def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
     """Scatter bucket-sorted entries into the padded [k, k, bmax] layout and
     compute the per-minibatch collision scales (both sides) on device."""
     n = flat_s.shape[0]
-    starts = jnp.concatenate(
-        [jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)[:-1]])
-    idx_in = jnp.arange(n, dtype=jnp.int32) - starts[flat_s]
-    dest = flat_s * bmax + idx_in
+    with jax.named_scope("layout/offsets"):
+        starts = jnp.concatenate(
+            [jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)[:-1]])
+        idx_in = jnp.arange(n, dtype=jnp.int32) - starts[flat_s]
+        dest = flat_s * bmax + idx_in
     total = k * k * bmax
-    su = jnp.zeros(total, jnp.int32).at[dest].set(urow_s,
-                                                  unique_indices=True)
-    si = jnp.zeros(total, jnp.int32).at[dest].set(irow_s,
-                                                  unique_indices=True)
-    sv = jnp.zeros(total, jnp.float32).at[dest].set(vals_s,
-                                                    unique_indices=True)
-    sw = jnp.zeros(total, jnp.float32).at[dest].set(w_s,
-                                                    unique_indices=True)
+    with jax.named_scope("layout/scatter"):
+        su = jnp.zeros(total, jnp.int32).at[dest].set(
+            urow_s, unique_indices=True)
+        si = jnp.zeros(total, jnp.int32).at[dest].set(
+            irow_s, unique_indices=True)
+        sv = jnp.zeros(total, jnp.float32).at[dest].set(
+            vals_s, unique_indices=True)
+        sw = jnp.zeros(total, jnp.float32).at[dest].set(
+            w_s, unique_indices=True)
 
     def two_d(a):
         return a.reshape(-1, mb)
@@ -445,18 +455,22 @@ def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
         # minibatch_sort): membership unchanged, math identical up to
         # float reassociation
         keyarr = su if sort_side == "user" else si
-        order = jnp.argsort(two_d(keyarr), axis=-1)
+        with jax.named_scope("layout/minibatch_sort"):
+            order = jnp.argsort(two_d(keyarr), axis=-1)
 
         def apply(a):
             return jnp.take_along_axis(two_d(a), order,
                                        axis=-1).reshape(total)
 
-        su, si, sv, sw = apply(su), apply(si), apply(sv), apply(sw)
+        with jax.named_scope("layout/minibatch_reorder"):
+            su, si, sv, sw = apply(su), apply(si), apply(sv), apply(sw)
 
-    icu = _inv_counts_2d(two_d(su), two_d(sw),
-                         presorted=sort_side == "user").reshape(total)
-    icv = _inv_counts_2d(two_d(si), two_d(sw),
-                         presorted=sort_side == "item").reshape(total)
+    with jax.named_scope("layout/inv_counts_u"):
+        icu = _inv_counts_2d(two_d(su), two_d(sw),
+                             presorted=sort_side == "user").reshape(total)
+    with jax.named_scope("layout/inv_counts_v"):
+        icv = _inv_counts_2d(two_d(si), two_d(sw),
+                             presorted=sort_side == "item").reshape(total)
     shape = (k, k, bmax)
     return (su.reshape(shape), si.reshape(shape), sv.reshape(shape),
             sw.reshape(shape), icu.reshape(shape), icv.reshape(shape))
@@ -494,48 +508,53 @@ def device_block_problem(
     k = num_blocks
     if np.shape(u)[0] == 0:  # no-copy for device arrays (shape attr)
         raise ValueError("device_block_problem: empty ratings input")
-    # pre-cast range check: an OOB int64 id would wrap through the int32
-    # cast into a wrong-but-plausible layout (e.g. raw 1-based MovieLens
-    # ids). One tiny scalar sync, once per fit.
-    validate_dense_ids(u, i, num_users, num_items, "device_block_problem")
-    u = jnp.asarray(u, jnp.int32)
-    i = jnp.asarray(i, jnp.int32)
-    w = (jnp.ones(u.shape[0], jnp.float32) if weights is None
-         else jnp.asarray(weights, jnp.float32))
-    base = jax.random.PRNGKey(seed)
+    seam = get_tracer().seam
+    with seam("fit/blocking/bucket"):
+        # pre-cast range check: an OOB int64 id would wrap through the
+        # int32 cast into a wrong-but-plausible layout (e.g. raw 1-based
+        # MovieLens ids). One tiny scalar sync, once per fit.
+        validate_dense_ids(u, i, num_users, num_items,
+                           "device_block_problem")
+        u = jnp.asarray(u, jnp.int32)
+        i = jnp.asarray(i, jnp.int32)
+        w = (jnp.ones(u.shape[0], jnp.float32) if weights is None
+             else jnp.asarray(weights, jnp.float32))
+        base = jax.random.PRNGKey(seed)
 
-    rpb_u, rpb_v = rows_per_block(num_users, k, row_multiple), \
-        rows_per_block(num_items, k, row_multiple)
-    counts_u, counts_v = _weighted_counts(u, i, w, num_users, num_items)
-    row_of_u, omega_u, id_of_ur = _assign_rows(
-        jax.random.fold_in(base, 10), counts_u, k, rpb_u, k * rpb_u)
-    row_of_i, omega_v, id_of_ir = _assign_rows(
-        jax.random.fold_in(base, 11), counts_v, k, rpb_v, k * rpb_v)
+        rpb_u, rpb_v = rows_per_block(num_users, k, row_multiple), \
+            rows_per_block(num_items, k, row_multiple)
+        counts_u, counts_v = _weighted_counts(u, i, w, num_users, num_items)
+        row_of_u, omega_u, id_of_ur = _assign_rows(
+            jax.random.fold_in(base, 10), counts_u, k, rpb_u, k * rpb_u)
+        row_of_i, omega_v, id_of_ir = _assign_rows(
+            jax.random.fold_in(base, 11), counts_v, k, rpb_v, k * rpb_v)
 
-    sizes, flat_s, urow_s, irow_s, vals_s, w_s = _bucket_entries(
-        jax.random.fold_in(base, 12), u, i, r, w, row_of_u, row_of_i,
-        k, rpb_u, rpb_v)
+        sizes, flat_s, urow_s, irow_s, vals_s, w_s = _bucket_entries(
+            jax.random.fold_in(base, 12), u, i, r, w, row_of_u, row_of_i,
+            k, rpb_u, rpb_v)
 
-    sizes_host = np.asarray(sizes)  # the one tiny device→host sync
-    bmax = max(int(sizes_host.max()), 1)
-    mbm = max(minibatch_multiple, 1)
-    bmax = -(-bmax // mbm) * mbm
+        # the one tiny device→host sync: it also makes this seam's end
+        # the device's true end of the bucket phase
+        sizes_host = np.asarray(sizes)
+    with seam("fit/blocking/layout"):
+        bmax = max(int(sizes_host.max()), 1)
+        mbm = max(minibatch_multiple, 1)
+        bmax = -(-bmax // mbm) * mbm
 
-    su, si, sv, sw, icu, icv = _layout(
-        flat_s, urow_s, irow_s, vals_s, w_s, sizes, k, bmax, mbm,
-        minibatch_sort)
-
-    nnz = (int(sizes_host.sum()) if weights is None
-           else int(jnp.sum(w > 0)))
-    return DeviceBlockedProblem(
-        su=su, si=si, sv=sv, sw=sw, icu=icu, icv=icv,
-        omega_u=omega_u, omega_v=omega_v,
-        row_of_user=row_of_u, row_of_item=row_of_i,
-        id_of_user_row=id_of_ur, id_of_item_row=id_of_ir,
-        num_blocks=k, rows_per_block_u=rpb_u, rows_per_block_v=rpb_v,
-        nnz=nnz, max_pad_ratio=(k * k * bmax) / max(nnz, 1),
-        minibatch=mbm,
-    )
+        su, si, sv, sw, icu, icv = _layout(
+            flat_s, urow_s, irow_s, vals_s, w_s, sizes, k, bmax, mbm,
+            minibatch_sort)
+        nnz = (int(sizes_host.sum()) if weights is None
+               else int(jnp.sum(w > 0)))
+        return DeviceBlockedProblem(
+            su=su, si=si, sv=sv, sw=sw, icu=icu, icv=icv,
+            omega_u=omega_u, omega_v=omega_v,
+            row_of_user=row_of_u, row_of_item=row_of_i,
+            id_of_user_row=id_of_ur, id_of_item_row=id_of_ir,
+            num_blocks=k, rows_per_block_u=rpb_u, rows_per_block_v=rpb_v,
+            nnz=nnz, max_pad_ratio=(k * k * bmax) / max(nnz, 1),
+            minibatch=mbm,
+        )
 
 
 def recompute_inv_counts(problem: DeviceBlockedProblem, minibatch: int):

@@ -41,6 +41,7 @@ from large_scale_recommendation_tpu.core.updaters import (
 from large_scale_recommendation_tpu.core.types import Ratings
 from large_scale_recommendation_tpu.data import blocking
 from large_scale_recommendation_tpu.models.mf import MFModel
+from large_scale_recommendation_tpu.obs.trace import get_tracer
 from large_scale_recommendation_tpu.obs.transfers import guard_scope
 from large_scale_recommendation_tpu.ops import sgd as sgd_ops
 
@@ -236,6 +237,7 @@ class DSGD:
         # static args (frozen-dataclass updater) → refits/segments with the
         # same shapes/config hit the XLA compile cache.
         train = self._train_fn(args)
+        seam = get_tracer().seam
         timer = TrainSegmentTimer(
             "dsgd", kind,
             shape_key=(tuple(np.shape(U)), tuple(np.shape(V)),
@@ -250,27 +252,31 @@ class DSGD:
                     U, V = train(U, V, iterations=seg, t0=done, k=k)
                 h.out = (U, V)
             done += seg
-            if self.watchdog is not None:
-                # BEFORE the checkpoint: a tripped segment must not
-                # persist its poisoned tables as a resume point
-                self.watchdog.after_segment(U, V, label=kind)
-            if self.evaluator is not None:
-                # segment-boundary quality: the armed row-space holdout
-                # scores against THIS segment's tables (segments are
-                # seconds of work — the eval is noise next to them)
-                self.evaluator.on_segment(U, V, label=kind, step=done)
-            if self._events is not None:
-                self._events.emit("train.segment", model="dsgd", kind=kind,
-                                  iterations=int(seg), done=int(done),
-                                  total=int(cfg.iterations))
-            if checkpoint_manager is not None:
-                checkpoint_manager.save(
-                    done, {"U": np.asarray(U), "V": np.asarray(V)},
-                    {"kind": kind, "iterations": cfg.iterations},
-                )
+            # the host's time between sweeps
+            with seam("fit/dsgd/after_segment"):
+                if self.watchdog is not None:
+                    # BEFORE the checkpoint: a tripped segment must not
+                    # persist its poisoned tables as a resume point
+                    self.watchdog.after_segment(U, V, label=kind)
+                if self.evaluator is not None:
+                    # segment-boundary quality: the armed row-space
+                    # holdout scores against THIS segment's tables
+                    # (segments are seconds of work — the eval is noise
+                    # next to them)
+                    self.evaluator.on_segment(U, V, label=kind, step=done)
                 if self._events is not None:
-                    self._events.emit("train.checkpoint", model="dsgd",
-                                      kind=kind, step=int(done))
+                    self._events.emit(
+                        "train.segment", model="dsgd", kind=kind,
+                        iterations=int(seg), done=int(done),
+                        total=int(cfg.iterations))
+                if checkpoint_manager is not None:
+                    checkpoint_manager.save(
+                        done, {"U": np.asarray(U), "V": np.asarray(V)},
+                        {"kind": kind, "iterations": cfg.iterations},
+                    )
+                    if self._events is not None:
+                        self._events.emit("train.checkpoint", model="dsgd",
+                                          kind=kind, step=int(done))
         timer.finish(n_ratings, bytes_per_iteration=(
             None if n_ratings is None else sgd_ops.dsgd_bytes_per_sweep(
                 n_ratings, int(np.shape(U)[-1]), kernel=cfg.kernel,
@@ -378,7 +384,9 @@ class DSGD:
             seed=cfg.seed if cfg.seed is not None else 0,
             minibatch_sort=cfg.minibatch_sort,
         )
-        U, V = init_factors_device(p, cfg.num_factors, scale=cfg.init_scale)
+        with get_tracer().seam("fit/dsgd/init"):
+            U, V = init_factors_device(p, cfg.num_factors,
+                                       scale=cfg.init_scale)
 
         use_inv = cfg.precompute_collisions and cfg.collision_mode == "mean"
         inv = (p.icu, p.icv) if use_inv else (None, None)

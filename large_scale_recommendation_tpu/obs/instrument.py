@@ -4,14 +4,16 @@
 warmup-excluded throughput logic used by every batch trainer
 (``models.dsgd``, ``parallel.dsgd_mesh``, ``models.als``): each segment
 gets a blocked wall-clock measurement into ``train_segment_s{model=}``
-and a compile-keyed trace span (the first segment of a given kind
-carries the XLA compile, so it labels ``compile``); ``finish()``
+and is bracketed by the seam ``fit/<label>/segment`` (``obs.trace.SEAMS``:
+a profiler annotation always, and on a live tracer a compile-keyed span —
+the first segment of a given kind carries the XLA compile, so it labels
+``compile``); ``finish()``
 publishes ``train_throughput_ratings_per_s`` gauges with the first
 segment EXCLUDED from the ``steady`` phase — compile time must not be
 laundered into a throughput claim (the ALX-style split).
 
-Zero-cost when disabled: with the null registry/tracer every method is
-a couple of no-op calls and no clock is read.
+Zero-cost when disabled: with the null registry/tracer a segment is one
+inert profiler annotation and no clock is read.
 """
 
 from __future__ import annotations
@@ -50,6 +52,7 @@ class TrainSegmentTimer:
         self._on = obs.enabled
         self._trace = get_tracer()
         self.label = model_label
+        self._seam = f"fit/{model_label}/segment"
         self._kind = kind or model_label
         # shapes belong in the compile key: a second fit of the same
         # kind at DIFFERENT table/strata shapes pays a fresh XLA
@@ -65,11 +68,15 @@ class TrainSegmentTimer:
     def segment(self, iterations: int):
         holder = _Holder()
         t0 = time.perf_counter() if self._on else 0.0
-        with self._trace.span(f"train/{self.label}",
-                              key=self._key,
+        # the seam fit/<label>/segment: a profiler annotation around the
+        # segment's dispatch (what a device trace reads), and on a live
+        # tracer the compile-keyed span of the same name, blocked on the
+        # segment's tables before its clock stops
+        with self._trace.seam(self._seam, key=self._key,
                               iterations=iterations) as sp:
             yield holder
-            sp.out = holder.out
+            if self._trace.enabled:
+                sp.out = holder.out
         if self._on:
             _block(holder.out)
             wall = time.perf_counter() - t0

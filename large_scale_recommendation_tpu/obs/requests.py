@@ -10,9 +10,10 @@ trace. FLAME (PAPERS.md, arXiv 2509.22681) frames serving efficiency
 as exactly this attribution problem; Dapper-style tail-based sampling
 is the standard answer. Three pieces close it:
 
-- **stage ledgers** — the engine's flush seams (``serving.engine``,
-  ``serving.retrieval.TwoStageRetriever.topk``, the pipelined drain in
-  ``parallel.serving``) mark a per-flush ``FlushLedger`` whose stages
+- **stage ledgers** — the serving seams (``obs.trace.SEAMS``: in
+  ``serving.engine``, ``serving.retrieval.TwoStageRetriever.topk`` and
+  the pipelined drain in ``parallel.serving``) close into a per-flush
+  ``FlushLedger`` (``SEAM_STAGE``) whose stages
   — ``batch_form``, ``gather``, ``score_stage1``, ``score_stage2``,
   ``topk_merge``, ``host_post`` — partition the flush wall *exactly by
   construction*: every ``mark`` is one clock read attributing the
@@ -64,6 +65,22 @@ from large_scale_recommendation_tpu.obs.transfers import _NULL_CONTEXT
 # dispatch — it lands in score_stage1 and score_stage2 stays 0.
 STAGES = ("queue_wait", "batch_form", "gather", "score_stage1",
           "score_stage2", "topk_merge", "host_post")
+
+# The ledger is a SINK of the serving seams (``obs.trace.SEAMS``): the
+# seam opened on each of these lines closes into ``FlushLedger.on_seam``,
+# which makes the stage's one clock read. ``serving/engine/results`` has
+# no row: host_post is the residual ``finish`` assigns from the flush's
+# own end, never a read of its own.
+SEAM_STAGE = {
+    "serving/engine/form": "batch_form",
+    "serving/engine/excl": "batch_form",
+    "serving/engine/gather": "gather",
+    "serving/retrieval/stage1": "score_stage1",
+    "serving/retrieval/stage2": "score_stage2",
+    # the exact mesh path's one fused dispatch lands in stage 1
+    "serving/engine/score_exact": "score_stage1",
+    "serving/pipeline/drain": "topk_merge",
+}
 
 # exemplar classes, worst-first for display ordering ties
 EXEMPLAR_KINDS = ("shed", "violating", "degraded", "slow")
@@ -119,6 +136,19 @@ class FlushLedger:
                               + (now - self._last))
         self._last = now
         return now
+
+    def on_seam(self, name: str) -> None:
+        """The seam ``name`` just closed: mark its stage (one clock
+        read). The ``sink`` of ``Tracer.seam``."""
+        stage = SEAM_STAGE.get(name)
+        if stage is not None:
+            self.mark(stage)
+
+    @property
+    def last(self) -> float:
+        """The clock reading of the latest mark (``t0`` before any) —
+        for a caller that wants to share it rather than read again."""
+        return self._last
 
     def finish(self, end: float,
                residual_stage: str = "host_post") -> float:

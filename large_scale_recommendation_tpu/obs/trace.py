@@ -35,6 +35,16 @@ Distributed tracing (``obs.disttrace`` builds on these primitives):
 
 ``NullTracer`` is the zero-cost disabled twin: ``span()`` returns one
 shared stateless no-op context manager.
+
+Seams (``seam(name)``, names from the closed set ``SEAMS``) are the
+spans the device trace reads: a seam is ALWAYS a
+``jax.profiler.TraceAnnotation``, which is inert unless a profiler session
+is capturing — so "seam tracing on" is any capture (``/profilez``, an
+operator's ``jax.profiler.trace``, the benchmark's ``--trace 1``), with no
+switch of its own. On a live ``Tracer`` the same seam is also a ``Span`` of
+the same name, so the Chrome export and the device trace carry one
+taxonomy; on the ``NullTracer`` it is the bare annotation (no clock read,
+no lock, no allocation beyond the annotation object).
 """
 
 from __future__ import annotations
@@ -47,6 +57,8 @@ import threading
 import time
 from typing import Any
 
+from jax.profiler import TraceAnnotation
+
 # cap on buffered events: a runaway instrumented loop must not grow the
 # host heap without bound; overflow is counted, not silently dropped
 DEFAULT_MAX_EVENTS = 200_000
@@ -56,6 +68,26 @@ DEFAULT_MAX_EVENTS = 200_000
 # bounded-memory discipline as the flight recorder's series table and
 # the introspector's record table
 DEFAULT_MAX_KEY_WALLS = 4096
+
+# The CLOSED set of seam names (docs/OBSERVABILITY.md, "Span taxonomy",
+# has the table: where each is opened, what it brackets, the request-ledger
+# stage and the per-layer metric it feeds). A seam lands in the profiler's
+# own trace (host plane, same clock as the device planes), where a reader
+# keeps host events by the prefixes ``serving/`` and ``fit/`` and charges
+# chip-idle time to them. A name here must never equal one a caller wraps
+# around the program itself (``serving/flush``, ``fit/fit_device``): a
+# per-flush figure divides by the count of such spans, and a second
+# emitter would halve it silently (tests/test_obs_seams.py pins it).
+SEAMS = frozenset({
+    "serving/engine/form", "serving/engine/excl", "serving/engine/gather",
+    "serving/engine/score_exact", "serving/engine/results",
+    "serving/retrieval/stage1", "serving/retrieval/stage2",
+    "serving/pipeline/drain",
+    "fit/blocking/bucket", "fit/blocking/layout",
+    "fit/dsgd/init", "fit/mesh_dsgd/init", "fit/mesh/place",
+    "fit/dsgd/segment", "fit/mesh_dsgd/segment", "fit/als/segment",
+    "fit/dsgd/after_segment", "fit/mesh_dsgd/after_segment",
+})
 
 # span sequence numbers are PROCESS-unique (module-level, not
 # per-tracer): an enable()/disable()/enable() cycle must not restart the
@@ -181,10 +213,14 @@ class Span:
     span family. The exported args additionally carry
     ``parent_span_id`` (the enclosing span on this thread, or the
     active ``TraceContext``'s parent for a top-level span — the
-    cross-thread causal link) and ``trace_id`` (the active context's)."""
+    cross-thread causal link) and ``trace_id`` (the active context's).
+
+    A seam (``Tracer.seam``) is a ``Span`` that also enters the
+    profiler annotation of its name and, on close, calls its ``sink``
+    (the request plane's stage ledger) with that name."""
 
     __slots__ = ("name", "cat", "t0", "args", "out", "id", "key",
-                 "parent_id", "trace_id", "_tracer")
+                 "parent_id", "trace_id", "_tracer", "_ann", "_sink")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict,
                  span_id: str, key: Any = None):
@@ -198,8 +234,12 @@ class Span:
         self.parent_id = None
         self.trace_id = None
         self.t0 = 0.0
+        self._ann = None
+        self._sink = None
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         stack = self._tracer._stack()
         ctx = self._tracer.current_context()
         if ctx is not None:
@@ -216,13 +256,52 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if self.out is not None:
-            _block(self.out)
-        t1 = time.perf_counter()
-        stack = self._tracer._stack()
-        if stack and stack[-1] is self:
-            stack.pop()
-        self._tracer._record(self, t1)
+        try:
+            if self.out is not None:
+                _block(self.out)
+            if self._sink is not None:
+                self._sink(self.name)
+            t1 = time.perf_counter()
+            stack = self._tracer._stack()
+            if stack and stack[-1] is self:
+                stack.pop()
+            self._tracer._record(self, t1)
+        finally:
+            # a device error surfacing in _block (or a sink's fault) must
+            # not leave the profiler's annotation nesting unbalanced
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
+
+
+class _SinkSeam:
+    """A seam with a sink and no live tracer: the profiler annotation,
+    then ``sink(name)`` on close. Reads no clock of its own (the sink —
+    the request plane's ``FlushLedger`` — makes its one read)."""
+
+    __slots__ = ("_ann", "_sink", "_name")
+
+    def __init__(self, name: str, sink):
+        self._ann = TraceAnnotation(name)
+        self._sink = sink
+        self._name = name
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        try:
+            self._sink(self._name)
+        finally:
+            self._ann.__exit__(exc_type, exc, tb)
+
+
+def _seam_name(name: str) -> str:
+    if name not in SEAMS:
+        raise ValueError(f"{name!r} is not a seam: add it to "
+                         "obs.trace.SEAMS and to the table in "
+                         "docs/OBSERVABILITY.md (Span taxonomy)")
+    return name
 
 
 class _NullSpan:
@@ -347,6 +426,17 @@ class Tracer:
                     cat = "compile"
         return Span(self, name, cat, args,
                     f"{process_namespace()}:{next(_SPAN_IDS)}", key)
+
+    def seam(self, name: str, key: Any = None, sink=None, **args):
+        """Open seam ``name`` (a member of ``SEAMS``; use as a context
+        manager): a profiler ``TraceAnnotation`` — what a device trace
+        sees, inert outside a capture — that on this live tracer is also
+        a ``Span`` of the same name (``key``/``args`` as for ``span``).
+        ``sink(name)`` is called first thing when the seam closes."""
+        sp = self.span(_seam_name(name), key, **args)
+        sp._ann = TraceAnnotation(name)
+        sp._sink = sink
+        return sp
 
     def depth(self) -> int:
         """Current nesting depth on the calling thread."""
@@ -600,6 +690,13 @@ class NullTracer(Tracer):
 
     def span(self, name: str, key: Any = None, **args):
         return NULL_SPAN
+
+    def seam(self, name: str, key: Any = None, sink=None, **args):
+        """The bare profiler annotation: no clock read, no lock, nothing
+        recorded unless a profiler session is capturing."""
+        if sink is None:
+            return TraceAnnotation(_seam_name(name))
+        return _SinkSeam(_seam_name(name), sink)
 
     def complete(self, name: str, t0: float, t1: float,
                  cat: str = "complete", tid: int | None = None,

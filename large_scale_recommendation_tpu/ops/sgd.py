@@ -154,32 +154,41 @@ def sgd_minibatch_update(
         raise ValueError(
             f"collision must be 'mean' or 'sum', got {collision!r}"
         )
-    u = U[u_rows]
-    v = V[i_rows]
-    pred = None
-    if pred_axis is not None:
-        pred = jax.lax.psum(jnp.einsum("bk,bk->b", u, v), pred_axis)
-    du, dv = updater.delta(
-        values,
-        u,
-        v,
-        weights=weights,
-        omega_u=None if omega_u is None else omega_u[u_rows],
-        omega_v=None if omega_v is None else omega_v[i_rows],
-        t=t,
-        **({} if pred is None else {"pred": pred}),
-    )
-    if collision == "mean":
-        if inv_cu is not None:
-            du = du * inv_cu[:, None]
-            dv = dv * inv_cv[:, None]
-        else:
-            cu = jnp.zeros(U.shape[0], U.dtype).at[u_rows].add(weights)
-            cv = jnp.zeros(V.shape[0], V.dtype).at[i_rows].add(weights)
-            du = du / jnp.maximum(cu[u_rows], 1.0)[:, None]
-            dv = dv / jnp.maximum(cv[i_rows], 1.0)[:, None]
-    U = U.at[u_rows].add(du)
-    V = V.at[i_rows].add(dv)
+    # named scopes: HLO metadata only, so a device trace can name the
+    # phases of the sweep (the residual is scoped inside the updater,
+    # core.updaters._errors); no arithmetic moves
+    with jax.named_scope("sgd/gather"):
+        u = U[u_rows]
+        v = V[i_rows]
+        ou = None if omega_u is None else omega_u[u_rows]
+        ov = None if omega_v is None else omega_v[i_rows]
+    with jax.named_scope("sgd/update"):
+        pred = None
+        if pred_axis is not None:
+            pred = jax.lax.psum(jnp.einsum("bk,bk->b", u, v), pred_axis)
+        du, dv = updater.delta(
+            values,
+            u,
+            v,
+            weights=weights,
+            omega_u=ou,
+            omega_v=ov,
+            t=t,
+            **({} if pred is None else {"pred": pred}),
+        )
+        if collision == "mean":
+            if inv_cu is not None:
+                du = du * inv_cu[:, None]
+                dv = dv * inv_cv[:, None]
+            else:
+                cu = jnp.zeros(U.shape[0], U.dtype).at[u_rows].add(weights)
+                cv = jnp.zeros(V.shape[0], V.dtype).at[i_rows].add(weights)
+                du = du / jnp.maximum(cu[u_rows], 1.0)[:, None]
+                dv = dv / jnp.maximum(cv[i_rows], 1.0)[:, None]
+    with jax.named_scope("sgd/scatter_u"):
+        U = U.at[u_rows].add(du)
+    with jax.named_scope("sgd/scatter_v"):
+        V = V.at[i_rows].add(dv)
     return U, V
 
 

@@ -46,6 +46,7 @@ from jax.sharding import Mesh  # noqa: F401 — annotation surface
 from large_scale_recommendation_tpu.core.types import Ratings
 from large_scale_recommendation_tpu.data import blocking
 from large_scale_recommendation_tpu.models.mf import MFModel
+from large_scale_recommendation_tpu.obs.trace import get_tracer
 from large_scale_recommendation_tpu.ops import sgd as sgd_ops
 from large_scale_recommendation_tpu.parallel.mesh import shard_map
 from large_scale_recommendation_tpu.parallel.partitioner import (
@@ -228,8 +229,9 @@ def _build_mesh_dsgd_step(
             # — ≙ the reference's inter-superstep shuffle of item blocks
             # (DSGDforMF.scala:611-619 / OfflineSpark.scala:196-201), now an
             # ICI ppermute on the partitioner's data axis.
-            V = jax.lax.ppermute(V, axis, perm)
-            ov = jax.lax.ppermute(ov, axis, perm)
+            with jax.named_scope("ring/ppermute"):  # HLO metadata only
+                V = jax.lax.ppermute(V, axis, perm)
+                ov = jax.lax.ppermute(ov, axis, perm)
             return (U, V, ov), None
 
         (U_l, V_l, ov_l), _ = jax.lax.scan(
@@ -298,6 +300,12 @@ class MeshDSGD:
             schedule=sched,
         )
         self.model: MFModel | None = None
+        # model-quality hook (obs.quality.OnlineEvaluator or anything
+        # with its ``on_segment``): called at every segment boundary with
+        # that segment's sharded tables — same contract and same point
+        # of the loop as ``DSGD.evaluator``. None (the default) adds one
+        # pointer test per segment.
+        self.evaluator = None
 
     @property
     def num_blocks(self) -> int:
@@ -394,18 +402,21 @@ class MeshDSGD:
             seed=cfg.seed if cfg.seed is not None else 0,
             minibatch_sort=cfg.minibatch_sort,
         )
-        # stratum-major [s, p, b] global rows → device-major [p, s, b]
-        # local rows (≙ device_major_local_strata, on device)
-        ru = (jnp.transpose(p.su, (1, 0, 2)) % p.rows_per_block_u)
-        ri = (jnp.transpose(p.si, (1, 0, 2)) % p.rows_per_block_v)
-        rv = jnp.transpose(p.sv, (1, 0, 2))
-        rw = jnp.transpose(p.sw, (1, 0, 2))
-        U, V = init_factors_device(p, cfg.num_factors, scale=cfg.init_scale)
-        if cfg.precompute_collisions and cfg.collision_mode == "mean":
-            inv_args = (jnp.transpose(p.icu, (1, 0, 2)),
-                        jnp.transpose(p.icv, (1, 0, 2)))
-        else:
-            inv_args = ()
+        with get_tracer().seam("fit/mesh_dsgd/init"):
+            # stratum-major [s, p, b] global rows → device-major
+            # [p, s, b] local rows (≙ device_major_local_strata, on
+            # device)
+            ru = (jnp.transpose(p.su, (1, 0, 2)) % p.rows_per_block_u)
+            ri = (jnp.transpose(p.si, (1, 0, 2)) % p.rows_per_block_v)
+            rv = jnp.transpose(p.sv, (1, 0, 2))
+            rw = jnp.transpose(p.sw, (1, 0, 2))
+            U, V = init_factors_device(p, cfg.num_factors,
+                                       scale=cfg.init_scale)
+            if cfg.precompute_collisions and cfg.collision_mode == "mean":
+                inv_args = (jnp.transpose(p.icu, (1, 0, 2)),
+                            jnp.transpose(p.icv, (1, 0, 2)))
+            else:
+                inv_args = ()
         U, V = self._train_segments(
             U, V, (ru, ri, rv, rw), p.omega_u, p.omega_v, inv_args,
             "mesh_dsgd_device_segment",
@@ -454,22 +465,24 @@ class MeshDSGD:
         V = jnp.asarray(V).astype(fdt)
         part.require_rank_divisible(int(np.shape(U)[-1]), "mesh DSGD")
 
-        if resume:
-            if checkpoint_manager is None:
-                raise ValueError("resume=True requires a checkpoint_manager")
-            # host U/V go in directly: on a successful restore only their
-            # shape/dtype are read, so the fresh init tables are never
-            # shipped to device just to be discarded
-            U, V, done = restore_segment_state_sharded(
-                checkpoint_manager, kind, U, V, partitioner=part)
-        else:
-            U = part.place(U, "users", "rank")
-            V = part.place(V, "items", "rank")
-        args = tuple(part.place(x, "ratings") for x in strata)
-        ou = part.place(omega_u, "users")
-        ov = part.place(omega_v, "items")
-        with_inv = bool(inv_args)
-        inv_args = tuple(part.place(x, "ratings") for x in inv_args)
+        if resume and checkpoint_manager is None:
+            raise ValueError("resume=True requires a checkpoint_manager")
+        seam = get_tracer().seam
+        with seam("fit/mesh/place"):
+            if resume:
+                # host U/V go in directly: on a successful restore only
+                # their shape/dtype are read, so the fresh init tables
+                # are never shipped to device just to be discarded
+                U, V, done = restore_segment_state_sharded(
+                    checkpoint_manager, kind, U, V, partitioner=part)
+            else:
+                U = part.place(U, "users", "rank")
+                V = part.place(V, "items", "rank")
+            args = tuple(part.place(x, "ratings") for x in strata)
+            ou = part.place(omega_u, "users")
+            ov = part.place(omega_v, "items")
+            with_inv = bool(inv_args)
+            inv_args = tuple(part.place(x, "ratings") for x in inv_args)
 
         if cfg.kernel == "pallas":
             from large_scale_recommendation_tpu.ops.pallas_sgd import (
@@ -502,14 +515,20 @@ class MeshDSGD:
                                jnp.asarray(done, jnp.int32))
                 h.out = (U, V)
             done += seg
-            if checkpoint_manager is not None:
-                # every process writes its OWN device shards; no gather,
-                # no replicated copy of the model anywhere
-                jax.block_until_ready((U, V))
-                checkpoint_manager.save(
-                    done, {"U": U, "V": V},
-                    {"kind": kind, "iterations": cfg.iterations},
-                )
+            # the host's time between sweeps
+            with seam("fit/mesh_dsgd/after_segment"):
+                if self.evaluator is not None:
+                    # segment-boundary quality, BEFORE the checkpoint:
+                    # the hook sees THIS segment's (still sharded) tables
+                    self.evaluator.on_segment(U, V, label=kind, step=done)
+                if checkpoint_manager is not None:
+                    # every process writes its OWN device shards; no
+                    # gather, no replicated copy of the model anywhere
+                    jax.block_until_ready((U, V))
+                    checkpoint_manager.save(
+                        done, {"U": U, "V": V},
+                        {"kind": kind, "iterations": cfg.iterations},
+                    )
         m = part.model_parallel
         timer.finish(n_ratings, bytes_per_iteration=(
             None if n_ratings is None else sgd_ops.dsgd_bytes_per_sweep(

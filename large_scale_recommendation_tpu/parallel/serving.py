@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh  # noqa: F401 — annotation surface
 
+from large_scale_recommendation_tpu.obs.trace import get_tracer
 from large_scale_recommendation_tpu.parallel.mesh import shard_map
 from large_scale_recommendation_tpu.parallel.partitioner import (
     as_partitioner,
@@ -308,7 +309,7 @@ def mesh_supports_donation(mesh: Mesh) -> bool:
 
 def run_pipelined_topk(user_rows, *, k: int, k_out: int, n_rows: int,
                        slice_size: int, bucket_fn, score_chunk,
-                       on_batch=None, on_drain=None):
+                       on_batch=None, seam=None):
     """The chunk-loop machinery shared by ``mesh_top_k_recommend`` and
     the serving engine: walk ``user_rows`` in ``slice_size`` slices,
     pad each to ``bucket_fn(len(slice))`` rows, score via
@@ -321,10 +322,11 @@ def run_pipelined_topk(user_rows, *, k: int, k_out: int, n_rows: int,
     always valid table indices, dead slots identified by score). ONE
     copy of the pipeline + clamp so the per-call path and the engine
     cannot drift. ``on_batch(bucket)`` observes each dispatched bucket;
-    ``on_drain()`` fires after each drain's device sync completes (the
-    request plane marks its ``topk_merge`` stage there — None, the
-    default, adds nothing to the loop).
+    each drain (the wait for the device and the copy back) is the seam
+    ``serving/pipeline/drain``, opened through ``seam`` (default: the
+    installed tracer's ``seam`` — ``obs.trace.SEAMS``).
     """
+    seam = seam or get_tracer().seam
     n = len(user_rows)
     out_rows = np.zeros((n, k), np.int32)
     out_scores = np.full((n, k), -np.inf, np.float32)
@@ -338,10 +340,9 @@ def run_pipelined_topk(user_rows, *, k: int, k_out: int, n_rows: int,
         # its scalar start indices host->device and trips an armed
         # transfer guard
         p0, pc, pv, pr = p
-        out_rows[p0:p0 + pc, :k_out] = np.asarray(pr)[:pc]
-        out_scores[p0:p0 + pc, :k_out] = np.asarray(pv)[:pc]
-        if on_drain is not None:
-            on_drain()
+        with seam("serving/pipeline/drain"):
+            out_rows[p0:p0 + pc, :k_out] = np.asarray(pr)[:pc]
+            out_scores[p0:p0 + pc, :k_out] = np.asarray(pv)[:pc]
 
     for c0 in range(0, n, slice_size):
         cu = user_rows[c0:c0 + slice_size]
@@ -392,8 +393,9 @@ def mesh_top_k_recommend(U, V, user_rows, k: int = 10,
     # scored it. One `is not None` test when the plane is off — no
     # clock reads on the null path. The request plane (obs.requests)
     # mirrors the seam: the call is noted as a one-request flush whose
-    # stage ledger marks the same seams the engine does (the residual
-    # lands in topk_merge — the pad clamp runs after the final drain).
+    # stage ledger marks the stages the engine's seams do (the drain
+    # seam closes into it; the residual lands in topk_merge — the pad
+    # clamp runs after the final drain).
     from large_scale_recommendation_tpu.obs.budget import get_budget
     from large_scale_recommendation_tpu.obs.requests import get_requests
 
@@ -442,8 +444,8 @@ def mesh_top_k_recommend(U, V, user_rows, k: int = 10,
     out = run_pipelined_topk(
         user_rows, k=k, k_out=k_out, n_rows=n_rows, slice_size=chunk,
         bucket_fn=lambda c: chunk, score_chunk=score_chunk,
-        on_drain=(None if led is None
-                  else lambda: led.mark("topk_merge")))
+        seam=(None if led is None
+              else partial(get_tracer().seam, sink=led.on_seam)))
     if budget is not None or led is not None:
         t_end = time.perf_counter()  # ONE read shared by both planes
         if budget is not None:

@@ -60,6 +60,7 @@ swaps) and ``.degraded`` (stage-1-only admission fallback) attributes.
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 
@@ -75,7 +76,7 @@ from large_scale_recommendation_tpu.obs.events import get_events
 from large_scale_recommendation_tpu.obs.lineage import get_lineage
 from large_scale_recommendation_tpu.obs.registry import get_registry
 from large_scale_recommendation_tpu.obs.requests import get_requests
-from large_scale_recommendation_tpu.obs.trace import get_tracer
+from large_scale_recommendation_tpu.obs.trace import NULL_SPAN, get_tracer
 from large_scale_recommendation_tpu.obs.transfers import (
     get_transfers,
     guard_scope,
@@ -726,88 +727,93 @@ class ServingEngine:
             if self._obs_on:
                 for ts in stamps:
                     self._m_qwait.observe(t0 - ts)
+            # the flush's seams (obs.trace.SEAMS): profiler annotations
+            # on the device trace's clock, each also a span on a live
+            # tracer; with the request plane armed the ledger is the sink
+            # of every seam's close (one clock read per seam)
+            seam = (self._trace.seam if led is None else
+                    functools.partial(self._trace.seam, sink=led.on_seam))
             # id → row space per request, then one shared row stream:
             # rows from all requests pack together, so ten 30-user
             # requests cost one 512-row micro-batch, not ten 32-row
             # calls
-            known_masks, row_slices, bounds = [], [], [0]
-            for ids in requests:
-                u_rows, u_mask = self.model.users.rows_for(ids)
-                known = u_mask > 0
-                known_masks.append((len(ids), known))
-                row_slices.append(u_rows[known])
-                bounds.append(bounds[-1] + int(known.sum()))
-            rows_all = (np.concatenate(row_slices) if row_slices
-                        else np.zeros(0, np.int64))
-            if self._obs_on or led is not None:
+            with seam("serving/engine/form"):
+                known_masks, row_slices, bounds = [], [], [0]
+                for ids in requests:
+                    u_rows, u_mask = self.model.users.rows_for(ids)
+                    known = u_mask > 0
+                    known_masks.append((len(ids), known))
+                    row_slices.append(u_rows[known])
+                    bounds.append(bounds[-1] + int(known.sum()))
+                rows_all = (np.concatenate(row_slices) if row_slices
+                            else np.zeros(0, np.int64))
+            if self._obs_on:
                 # ONE clock read feeds both the assembly histogram and
-                # the ledger's batch_form mark — the shared-read
-                # discipline that keeps the stage sum reconcilable
-                t_asm = time.perf_counter()
-                if self._obs_on:
-                    self._m_assembly.observe(t_asm - t0)
-                if led is not None:
-                    led.mark("batch_form", t_asm)
+                # the ledger's batch_form mark (made as the seam closed)
+                # — the shared-read discipline that keeps the stage sum
+                # reconcilable
+                t_asm = led.last if led is not None else time.perf_counter()
+                self._m_assembly.observe(t_asm - t0)
+            span = NULL_SPAN
             if self._trace.enabled:
                 # compile-keyed: the first flush at a fresh catalog
                 # geometry carries the bucket family's XLA compiles.
                 # catalog_version in the args is the serve-side join of
                 # the assembled record trace: swap watermark → version
                 # → the flush that made the record's trace servable.
+                # NOT a seam: the benchmark emits this name itself.
                 geom = (self._catalog.rows_per_shard
                         if self._catalog is not None
                         else self._retriever.n_rows)
-                with self._trace.span(
-                        "serving/flush",
-                        key=("serving_flush", geom),
-                        rows=len(rows_all), requests=len(requests),
-                        catalog_version=int(self.version)):
-                    top_rows, top_scores = self._serve_rows(
-                        rows_all, stage1_only=degraded, ledger=led)
-            else:
+                span = self._trace.span(
+                    "serving/flush", key=("serving_flush", geom),
+                    rows=len(rows_all), requests=len(requests),
+                    catalog_version=int(self.version))
+            with span:
                 top_rows, top_scores = self._serve_rows(
-                    rows_all, stage1_only=degraded, ledger=led)
-            version = self.version
-            results = []
-            for (n_ids, known), b0, b1 in zip(known_masks, bounds,
-                                              bounds[1:]):
-                results.append(RecResult(
-                    _assemble_topk(
-                        n_ids, self.k, known, top_rows[b0:b1],
-                        top_scores[b0:b1], self._item_ids_of_row,
-                        return_mask),
-                    catalog_version=version, degraded=degraded))
-            self.stats["requests"] += len(requests)
-            self.stats["rows"] += len(rows_all)
-            self.stats["flushes"] += 1
-            wall = time.perf_counter() - t0
-            end = t0 + wall
-            # the rung exemplars report: read BEFORE observe() below
-            # re-evaluates the ladder — the level that served THIS flush
-            adm_level = (self._admission.level
-                         if self._admission is not None else None)
-            self.meter.record(len(rows_all), wall)
-            if self._slo is not None:
-                # one sample per REQUEST: queue wait since submit plus
-                # the flush wall — the latency a client saw. Tracking
-                # the flush wall alone would let the burn recover while
-                # a backlog is still seconds deep (shedding shrinks
-                # batches, walls look great, clients still suffer).
-                for ts in stamps:
-                    self._slo.record(end - ts)
-            if self._admission is not None:
-                # the burn just moved — re-evaluate the ladder while the
-                # lock is held, so the level the NEXT submit sees is
-                # consistent with this flush's latency
-                if degraded:
-                    self._admission.count_degraded(len(requests))
-                self._admission.observe()
-            if self._obs_on:
-                # results are host numpy by here, so the flush wall is a
-                # SYNCED end-to-end latency, not a dispatch time
-                self._m_flush.observe(wall)
-                self._m_requests.inc(len(requests))
-                self._m_rows.inc(len(rows_all))
+                    rows_all, stage1_only=degraded, seam=seam)
+            with seam("serving/engine/results"):
+                version = self.version
+                results = []
+                for (n_ids, known), b0, b1 in zip(known_masks, bounds,
+                                                  bounds[1:]):
+                    results.append(RecResult(
+                        _assemble_topk(
+                            n_ids, self.k, known, top_rows[b0:b1],
+                            top_scores[b0:b1], self._item_ids_of_row,
+                            return_mask),
+                        catalog_version=version, degraded=degraded))
+                self.stats["requests"] += len(requests)
+                self.stats["rows"] += len(rows_all)
+                self.stats["flushes"] += 1
+                wall = time.perf_counter() - t0
+                end = t0 + wall
+                # the rung exemplars report: read BEFORE observe() below
+                # re-evaluates the ladder — the level that served THIS flush
+                adm_level = (self._admission.level
+                             if self._admission is not None else None)
+                self.meter.record(len(rows_all), wall)
+                if self._slo is not None:
+                    # one sample per REQUEST: queue wait since submit plus
+                    # the flush wall — the latency a client saw. Tracking
+                    # the flush wall alone would let the burn recover while
+                    # a backlog is still seconds deep (shedding shrinks
+                    # batches, walls look great, clients still suffer).
+                    for ts in stamps:
+                        self._slo.record(end - ts)
+                if self._admission is not None:
+                    # the burn just moved — re-evaluate the ladder while the
+                    # lock is held, so the level the NEXT submit sees is
+                    # consistent with this flush's latency
+                    if degraded:
+                        self._admission.count_degraded(len(requests))
+                    self._admission.observe()
+                if self._obs_on:
+                    # results are host numpy by here, so the flush wall is a
+                    # SYNCED end-to-end latency, not a dispatch time
+                    self._m_flush.observe(wall)
+                    self._m_requests.inc(len(requests))
+                    self._m_rows.inc(len(rows_all))
         if self._lineage is not None:
             # the serve-side half of the lineage join: the version every
             # result of this flush carries resolves to its provenance,
@@ -848,18 +854,19 @@ class ServingEngine:
         return results
 
     def _serve_rows(self, user_rows: np.ndarray,
-                    stage1_only: bool = False, ledger=None):
+                    stage1_only: bool = False, seam=None):
         """Row-space scoring through pow2-bucketed micro-batches, on the
         shared two-deep dispatch pipeline (``run_pipelined_topk`` — one
         copy of the overlap + pad-clamp machinery with the per-call
         path). Routes to the exact mesh step or the two-stage fast path
         (``stage1_only`` skips the exact rescore — the admission
-        ladder's degraded operating point). ``ledger`` (a
-        ``obs.requests.FlushLedger``, None when the plane is off) marks
-        the stage seams: exclusion builds land in ``batch_form``, user
-        gathers in ``gather``, score dispatches in ``score_stage1``/
-        ``score_stage2``, drain syncs in ``topk_merge`` — each mark one
-        clock read over the contiguous host interval since the last."""
+        ladder's degraded operating point). ``seam`` opens the seams of
+        ``obs.trace.SEAMS`` (default: the bound tracer's; ``flush`` passes
+        one that also closes into the request plane's ledger): the
+        exclusion build, the user gather, the score dispatches (inside
+        ``TwoStageRetriever.topk``) and the drain (inside
+        ``run_pipelined_topk``) each open one."""
+        seam = seam or self._trace.seam
         store = self._user_store
 
         def gather_users(cu, want_dtype):
@@ -881,16 +888,12 @@ class ServingEngine:
             ret = self._retriever
 
             def base_chunk(cu, c):
-                excl = self._build_excl(cu, c)
-                if ledger is not None:
-                    ledger.mark("batch_form")  # exclusion build
-                U_chunk = gather_users(cu, jnp.float32)
-                if ledger is not None:
-                    ledger.mark("gather")
+                with seam("serving/engine/excl"):
+                    excl = self._build_excl(cu, c)
+                with seam("serving/engine/gather"):
+                    U_chunk = gather_users(cu, jnp.float32)
                 return ret.topk(U_chunk, excl, k=self.k,
-                                stage1_only=stage1_only,
-                                mark=(ledger.mark if ledger is not None
-                                      else None))
+                                stage1_only=stage1_only, seam=seam)
 
             k_out = min(self.k, ret.candidate_count(self.k))
             n_rows = ret.n_rows
@@ -902,20 +905,14 @@ class ServingEngine:
             cat, step = self._catalog, self._step
 
             def base_chunk(cu, c):
-                excl = self._build_excl(cu, c)
-                if ledger is not None:
-                    ledger.mark("batch_form")  # exclusion build
-                U_chunk = gather_users(cu, self._dtype)
-                if ledger is not None:
-                    ledger.mark("gather")
-                out = step(U_chunk, cat.V_sh, cat.w_sh,
-                           jnp.asarray(excl[0]), jnp.asarray(excl[1]),
-                           jnp.asarray(excl[2]))
-                if ledger is not None:
-                    # the exact path's one fused score dispatch lands
-                    # in stage 1; score_stage2 stays 0 by construction
-                    ledger.mark("score_stage1")
-                return out
+                with seam("serving/engine/excl"):
+                    excl = self._build_excl(cu, c)
+                with seam("serving/engine/gather"):
+                    U_chunk = gather_users(cu, self._dtype)
+                with seam("serving/engine/score_exact"):
+                    return step(U_chunk, cat.V_sh, cat.w_sh,
+                                jnp.asarray(excl[0]), jnp.asarray(excl[1]),
+                                jnp.asarray(excl[2]))
 
             k_out, n_rows, slice_size = (self._k_out, cat.n_rows,
                                          self.max_batch)
@@ -957,6 +954,4 @@ class ServingEngine:
                 slice_size=slice_size,
                 bucket_fn=lambda c: min(pow2_pad(c, self.min_bucket),
                                         slice_size),
-                score_chunk=score_chunk, on_batch=on_batch,
-                on_drain=(None if ledger is None
-                          else lambda: ledger.mark("topk_merge")))
+                score_chunk=score_chunk, on_batch=on_batch, seam=seam)

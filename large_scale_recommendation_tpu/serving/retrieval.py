@@ -64,6 +64,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from large_scale_recommendation_tpu.obs.trace import get_tracer
 from large_scale_recommendation_tpu.parallel.serving import catalog_version
 from large_scale_recommendation_tpu.utils.metrics import DEAD_SLOT_OFFSET
 from large_scale_recommendation_tpu.utils.shapes import pow2_pad
@@ -539,13 +540,18 @@ def _stage1_flat(qU, u_scale, Q, scale, item_w,
     quantized catalog, dequantized by the outer product of scales, the
     exact path's additive mask offset and scatter-min exclusions
     applied, top-``kc`` candidates out."""
-    scores = jax.lax.dot_general(
-        qU, Q, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32).astype(jnp.float32)
-    scores = scores * (u_scale[:, None] * scale[None, :])
-    scores = scores + item_w[None, :]
-    scores = scores.at[excl_rows, excl_cols].min(excl_w)
-    return jax.lax.top_k(scores, kc)
+    # named scopes: HLO metadata only (the device trace can then name
+    # the phases the fusion numbers hide); no arithmetic moves
+    with jax.named_scope("stage1/int8_dot"):
+        scores = jax.lax.dot_general(
+            qU, Q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32).astype(jnp.float32)
+    with jax.named_scope("stage1/dequant_mask"):
+        scores = scores * (u_scale[:, None] * scale[None, :])
+        scores = scores + item_w[None, :]
+        scores = scores.at[excl_rows, excl_cols].min(excl_w)
+    with jax.named_scope("stage1/top_k"):
+        return jax.lax.top_k(scores, kc)
 
 
 @partial(jax.jit, static_argnames=("kc", "n_probe"))
@@ -607,11 +613,13 @@ def _stage2(U_chunk, V, item_w, cand_v, cand_rows,
     n = V.shape[0]
     safe_rows = jnp.minimum(cand_rows, n - 1)  # slab pads carry n
     if exact:
-        Vc = V[safe_rows]  # [b, kc, r]
-        sc = jnp.einsum("br,bkr->bk", U_chunk, Vc)
-        sc = sc + item_w[safe_rows]
-        # pads (row == n) must stay dead even though row n-1 is real
-        sc = jnp.where(cand_rows >= n, -jnp.inf, sc)
+        with jax.named_scope("stage2/gather"):
+            Vc = V[safe_rows]  # [b, kc, r]
+        with jax.named_scope("stage2/rescore"):
+            sc = jnp.einsum("br,bkr->bk", U_chunk, Vc)
+            sc = sc + item_w[safe_rows]
+            # pads (row == n) must stay dead even though row n-1 is real
+            sc = jnp.where(cand_rows >= n, -jnp.inf, sc)
     else:
         sc = cand_v
     # membership: real exclusion entries carry w = DEAD_SLOT_OFFSET,
@@ -632,8 +640,9 @@ def _stage2(U_chunk, V, item_w, cand_v, cand_rows,
     pos = jnp.clip(jnp.searchsorted(keys, cand_keys), 0, keys.shape[0] - 1)
     hit = keys[pos] == cand_keys
     sc = jnp.where(hit, DEAD_SLOT_OFFSET, sc)
-    v, p = jax.lax.top_k(sc, k)
-    return v, jnp.take_along_axis(cand_rows, p, axis=1)
+    with jax.named_scope("stage2/top_k"):
+        v, p = jax.lax.top_k(sc, k)
+        return v, jnp.take_along_axis(cand_rows, p, axis=1)
 
 
 # --------------------------------------------------------------------------
@@ -698,14 +707,18 @@ class TwoStageRetriever:
                    hard)
 
     def topk(self, U_chunk, excl, k: int, stage1_only: bool = False,
-             mark=None):
+             seam=None):
         """Top-``k`` of one padded query chunk: ``(values f32 [b, k],
         rows int32 [b, k])``, rows ≥ ``n_rows`` possible only for slab
-        pads (callers clamp, as with mesh padding). ``mark`` (the
-        request plane's ``FlushLedger.mark``, None when off) splits the
-        dispatch wall at the stage-1/stage-2 seam — one clock read per
-        mark, including under ``stage1_only`` (the degraded path still
-        attributes its approximate stage-2 dispatch)."""
+        pads (callers clamp, as with mesh padding). The two dispatches
+        are the seams ``serving/retrieval/stage1`` (exclusion ship,
+        query quantization, stage-1 call) and ``serving/retrieval/stage2``
+        (``obs.trace.SEAMS``), opened through ``seam`` (default: the
+        installed tracer's; the engine passes its flush's, which also
+        closes into the request plane's ledger) — including under
+        ``stage1_only`` (the degraded path still attributes its
+        approximate stage-2 dispatch)."""
+        seam = seam or get_tracer().seam
         cat = self.catalog
         kc = self.candidate_count(k)
         if U_chunk.shape[0] * (cat.n_rows + 1) >= 2**32:
@@ -715,36 +728,34 @@ class TwoStageRetriever:
                 f"bucket {U_chunk.shape[0]} × catalog {cat.n_rows} "
                 f"exceeds the uint32 membership-key capacity — lower "
                 f"RetrievalConfig.max_bucket")
-        if self.partitioner is not None:
-            # rank-sharded catalogs: the query chunk and exclusion triple
-            # replicate onto the mesh so the jitted stages see one device
-            # set (GSPMD then partitions the contractions over 'model')
-            U_chunk = self.partitioner.shard(U_chunk)
-            excl = tuple(self.partitioner.shard(e) for e in excl)
-        excl_rows, excl_cols, excl_w = (jnp.asarray(e) for e in excl)
-        if cat.clustered:
-            n_probe = min(self.config.n_probe, cat.slab_q.shape[0])
-            self.buckets_seen.add(("clustered", U_chunk.shape[0], kc))
-            cand_v, cand_rows = _stage1_clustered(
-                U_chunk, cat.centroids, cat.slab_q,
-                cat.slab_scale, cat.slab_w, cat.slab_rows,
-                cat.ovf_q, cat.ovf_scale, cat.ovf_w, cat.ovf_rows,
-                kc=kc, n_probe=n_probe)
-        else:
-            # only the flat int8×int8 dot consumes quantized queries
-            qU, u_scale = _quantize_rows(U_chunk)
-            self.buckets_seen.add(("flat", U_chunk.shape[0], kc))
-            cand_v, cand_rows = _stage1_flat(
-                qU, u_scale, cat.q, cat.scale, cat.item_w,
-                excl_rows, excl_cols, excl_w, kc=kc)
-        if mark is not None:
-            mark("score_stage1")
-        out = _stage2(U_chunk, self.V, cat.item_w, cand_v, cand_rows,
-                      excl_rows, excl_cols, excl_w,
-                      k=min(k, kc), exact=not stage1_only)
-        if mark is not None:
-            mark("score_stage2")
-        return out
+        with seam("serving/retrieval/stage1"):
+            if self.partitioner is not None:
+                # rank-sharded catalogs: the query chunk and exclusion
+                # triple replicate onto the mesh so the jitted stages see
+                # one device set (GSPMD then partitions the contractions
+                # over 'model')
+                U_chunk = self.partitioner.shard(U_chunk)
+                excl = tuple(self.partitioner.shard(e) for e in excl)
+            excl_rows, excl_cols, excl_w = (jnp.asarray(e) for e in excl)
+            if cat.clustered:
+                n_probe = min(self.config.n_probe, cat.slab_q.shape[0])
+                self.buckets_seen.add(("clustered", U_chunk.shape[0], kc))
+                cand_v, cand_rows = _stage1_clustered(
+                    U_chunk, cat.centroids, cat.slab_q,
+                    cat.slab_scale, cat.slab_w, cat.slab_rows,
+                    cat.ovf_q, cat.ovf_scale, cat.ovf_w, cat.ovf_rows,
+                    kc=kc, n_probe=n_probe)
+            else:
+                # only the flat int8×int8 dot consumes quantized queries
+                qU, u_scale = _quantize_rows(U_chunk)
+                self.buckets_seen.add(("flat", U_chunk.shape[0], kc))
+                cand_v, cand_rows = _stage1_flat(
+                    qU, u_scale, cat.q, cat.scale, cat.item_w,
+                    excl_rows, excl_cols, excl_w, kc=kc)
+        with seam("serving/retrieval/stage2"):
+            return _stage2(U_chunk, self.V, cat.item_w, cand_v, cand_rows,
+                           excl_rows, excl_cols, excl_w,
+                           k=min(k, kc), exact=not stage1_only)
 
     def apply_delta(self, rows, values, version: int) -> None:
         """Install only the touched rows: patch the f32 rescore table

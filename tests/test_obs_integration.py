@@ -547,5 +547,6 @@ class TestEndToEndArtifacts:
         events = validate_chrome_trace(doc)
         cats = {e["cat"] for e in events}
         assert "compile" in cats and "execute" in cats, cats
-        train_spans = [e for e in events if e["name"] == "train/dsgd"]
+        train_spans = [e for e in events
+                       if e["name"] == "fit/dsgd/segment"]
         assert [e["cat"] for e in train_spans] == ["compile", "execute"]

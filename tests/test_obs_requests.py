@@ -716,12 +716,10 @@ class TestE2ESlowRequestAttribution:
             # injected slowest request the reservoir must surface
             orig = eng._serve_rows
 
-            def dragging(rows, stage1_only=False, ledger=None):
-                time.sleep(0.05)
-                if ledger is not None:
-                    ledger.mark("gather")
-                return orig(rows, stage1_only=stage1_only,
-                            ledger=ledger)
+            def dragging(rows, stage1_only=False, seam=None):
+                with seam("serving/engine/gather"):
+                    time.sleep(0.05)
+                return orig(rows, stage1_only=stage1_only, seam=seam)
 
             rng = np.random.default_rng(11)
             eng.serve([rng.integers(0, 50, 4).astype(np.int64)])

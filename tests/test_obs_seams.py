@@ -1,0 +1,247 @@
+"""The seams (``obs.trace.SEAMS``) reach the profiler's trace, on its
+clock, with nothing enabled: one tiny flush of each serving path and one
+tiny fit of each trainer run under a ``jax.profiler`` capture, which is
+read back with the benchmark's own reader (``benchmark.trace_reduce
+.read_xplane`` — the code the per-layer metrics stand on). One case per
+seam of the table: it is there and it lies inside its caller's extent.
+Then: ``obs.enable()`` puts the same names in the Chrome export; with
+nothing enabled a flush and a fit record nothing anywhere; the mesh
+trainer's ``evaluator.on_segment`` hook.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+
+from benchmark import trace_reduce
+from large_scale_recommendation_tpu import obs
+from large_scale_recommendation_tpu.core.generators import (
+    SyntheticMFGenerator,
+)
+from large_scale_recommendation_tpu.models.als import ALS, ALSConfig
+from large_scale_recommendation_tpu.models.dsgd import DSGD, DSGDConfig
+from large_scale_recommendation_tpu.obs.trace import (
+    SEAMS,
+    get_tracer,
+    validate_chrome_trace,
+)
+from large_scale_recommendation_tpu.parallel import MeshDSGD, make_block_mesh
+from large_scale_recommendation_tpu.parallel.dsgd_mesh import MeshDSGDConfig
+from large_scale_recommendation_tpu.serving import (
+    RetrievalConfig,
+    ServingEngine,
+)
+
+NU, NI, RANK = 200, 150, 8
+SEGMENTS = 2
+# the spans the benchmark puts around the program, which the test puts
+# there in its place: every seam must lie inside one of its caller's
+CALLER = {"serving": "serving/flush", "fit": "fit/fit_device"}
+# names the benchmark's own files emit around the program
+BENCHMARK_SPANS = tuple(CALLER.values())
+
+
+def _model(seed=0):
+    import jax.numpy as jnp
+
+    from large_scale_recommendation_tpu.data.blocking import flat_index
+    from large_scale_recommendation_tpu.models.mf import MFModel
+
+    rng = np.random.default_rng(seed)
+    return MFModel(
+        U=jnp.asarray(rng.normal(size=(NU, RANK)).astype(np.float32)),
+        V=jnp.asarray(rng.normal(size=(NI, RANK)).astype(np.float32)),
+        users=flat_index(np.arange(NU, dtype=np.int64)),
+        items=flat_index(np.arange(NI, dtype=np.int64)))
+
+
+def _ratings():
+    gen = SyntheticMFGenerator(num_users=NU, num_items=NI, rank=4,
+                               noise=0.05, seed=0)
+    train = gen.generate(6000)
+    ru, ri, rv, _ = train.to_numpy()
+    return train, (ru, ri, rv)
+
+
+def _solver_kw():
+    return dict(num_factors=RANK, lambda_=0.01, iterations=SEGMENTS,
+                learning_rate=0.05, lr_schedule="constant", seed=0,
+                minibatch_size=256, init_scale=0.3)
+
+
+class _Hook:
+    """Stands where ``obs.quality.OnlineEvaluator`` does."""
+
+    def __init__(self):
+        self.calls = []
+
+    def on_segment(self, U, V, label="segment", step=None):
+        self.calls.append((np.asarray(U).copy(), np.asarray(V).copy(),
+                           label, step))
+
+
+def _drive(annotate):
+    """One flush of each serving path and one fit of each trainer, each
+    inside the span its benchmark runner would put around it."""
+    train, (ru, ri, rv) = _ratings()
+    rng = np.random.default_rng(1)
+    engines = [
+        ServingEngine(_model(), k=5, max_batch=64,
+                      retrieval=RetrievalConfig(n_clusters=None,
+                                                overfetch=4)),
+        ServingEngine(_model(), k=5, max_batch=64)]
+    for eng in engines:
+        eng.submit(rng.integers(0, NU, 100).astype(np.int64))
+        with annotate(CALLER["serving"]):
+            assert len(eng.flush()) == 1
+    with annotate(CALLER["fit"]):
+        DSGD(DSGDConfig(num_blocks=2, **_solver_kw())).fit_device(
+            ru, ri, rv, NU, NI, checkpoint_every=1)
+    with annotate(CALLER["fit"]):
+        MeshDSGD(MeshDSGDConfig(**_solver_kw()),
+                 mesh=make_block_mesh(4)).fit_device(
+            ru, ri, rv, NU, NI, checkpoint_every=1)
+    with annotate(CALLER["fit"]):
+        ALS(ALSConfig(num_factors=RANK, lambda_=0.05,
+                      iterations=1)).fit(train)
+
+
+@pytest.fixture(scope="module")
+def capture(tmp_path_factory):
+    """The host events of one profiler capture of ``_drive``, with the
+    null obs layer installed: no ``enable`` of any kind."""
+    assert not get_tracer().enabled
+    trace_dir = str(tmp_path_factory.mktemp("seam_trace"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        _drive(jax.profiler.TraceAnnotation)
+    finally:
+        jax.profiler.stop_trace()
+    events = trace_reduce.read_xplane(trace_reduce.find_xplane(trace_dir))
+    return events["host"]
+
+
+@pytest.mark.parametrize("seam", sorted(SEAMS))
+def test_seam_reaches_the_profiler_trace(capture, seam):
+    mine = [(s, s + d) for n, s, d in capture if n == seam]
+    assert mine, f"{seam} is in no event of the capture"
+    callers = [(s, s + d) for n, s, d in capture
+               if n == CALLER[seam.split("/", 1)[0]]]
+    for a, b in mine:
+        assert any(c0 <= a and b <= c1 for c0, c1 in callers), (
+            f"a {seam} span lies outside every {CALLER} span")
+
+
+def test_no_seam_has_a_name_the_benchmark_emits(capture):
+    for name in SEAMS:
+        assert name.startswith(("serving/", "fit/"))  # read_xplane keeps
+        assert name not in BENCHMARK_SPANS
+        assert not name.startswith("bench/")
+    # and the program emitted none of the benchmark's names itself: the
+    # only events of those names are the ones _drive opened
+    counts = {n: sum(1 for e in capture if e[0] == n)
+              for n in CALLER.values()}
+    assert counts == {"serving/flush": 2, "fit/fit_device": 3}
+
+
+def test_every_seam_is_a_row_of_the_docs_table():
+    # the code holds the names, docs/OBSERVABILITY.md the table
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "docs", "OBSERVABILITY.md")) as f:
+        rows = [line for line in f if line.startswith("| `")]
+    for name in SEAMS:
+        assert any(f"`{name}`" in r.split("|")[1] for r in rows), name
+
+
+def test_segment_seams_are_one_per_segment(capture):
+    for label in ("dsgd", "mesh_dsgd"):
+        for what in ("segment", "after_segment"):
+            n = sum(1 for e in capture if e[0] == f"fit/{label}/{what}")
+            assert n == SEGMENTS, (label, what, n)
+
+
+def test_unknown_seam_is_refused():
+    with pytest.raises(ValueError, match="not a seam"):
+        get_tracer().seam("serving/flush")
+
+
+def test_enabled_tracer_exports_the_same_names(null_obs):
+    _, tracer = obs.enable()
+    try:
+        _drive(lambda name: tracer.span(name))
+        events = validate_chrome_trace(tracer.chrome_trace())
+    finally:
+        obs.disable()
+    names = {e["name"] for e in events}
+    assert set(SEAMS) <= names, sorted(set(SEAMS) - names)
+    # the segment seam kept the tracer's compile/execute split
+    cats = [e["cat"] for e in events if e["name"] == "fit/dsgd/segment"]
+    assert cats == ["compile", "execute"]
+
+
+def test_nothing_enabled_records_nothing(null_obs):
+    import contextlib
+
+    tracer = get_tracer()
+    assert not tracer.enabled
+    _drive(lambda name: contextlib.nullcontext())
+    assert tracer.events() == []
+    assert null_obs.names() == set()
+    assert null_obs.snapshot()["metrics"] == []
+
+
+def test_mesh_evaluator_fires_once_per_segment_with_its_tables():
+    _, (ru, ri, rv) = _ratings()
+    hook = _Hook()
+    solver = MeshDSGD(MeshDSGDConfig(**_solver_kw()),
+                      mesh=make_block_mesh(4))
+    assert solver.evaluator is None
+    solver.evaluator = hook
+    model = solver.fit_device(ru, ri, rv, NU, NI, checkpoint_every=1)
+    assert [c[3] for c in hook.calls] == list(range(1, SEGMENTS + 1))
+    assert {c[2] for c in hook.calls} == {"mesh_dsgd_device_segment"}
+    # the last call saw the final tables, the first one other tables
+    np.testing.assert_array_equal(hook.calls[-1][0], np.asarray(model.U))
+    np.testing.assert_array_equal(hook.calls[-1][1], np.asarray(model.V))
+    assert not np.array_equal(hook.calls[0][0], hook.calls[-1][0])
+    # one segment per call of the hook, sweeps as without it: the same
+    # fit with no hook gives the same tables
+    plain = MeshDSGD(MeshDSGDConfig(**_solver_kw()),
+                     mesh=make_block_mesh(4)).fit_device(
+        ru, ri, rv, NU, NI, checkpoint_every=1)
+    np.testing.assert_array_equal(np.asarray(plain.U),
+                                  np.asarray(model.U))
+
+
+@pytest.mark.parametrize("live", [False, True], ids=["null", "tracer"])
+def test_a_seam_whose_sink_raises_still_closes_its_annotation(
+        null_obs, monkeypatch, live):
+    from large_scale_recommendation_tpu.obs import trace
+
+    open_now = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            open_now.append(self.name)
+
+        def __exit__(self, *exc):
+            open_now.remove(self.name)
+
+    def sink(name):
+        raise RuntimeError("the ledger's fault")
+
+    monkeypatch.setattr(trace, "TraceAnnotation", Ann)
+    tracer = trace.Tracer() if live else trace.NullTracer()
+    with pytest.raises(RuntimeError, match="ledger's fault"):
+        with tracer.seam("serving/engine/form", sink=sink):
+            assert open_now == ["serving/engine/form"]
+    assert open_now == []
