@@ -1,10 +1,16 @@
 """On-device workload generation + DSGD blocking (the XLA data pipeline).
 
 TPU-first counterpart of the host blocking pass (``data.blocking``).
-Blocking is a pure data-layout transform — sort, prefix-sum, scatter — and
-XLA's sort/cumsum/scatter primitives run it at HBM speed on chip. Keeping
-the whole pipeline on device means the host never materializes the
-``k × k × bmax`` stratum expansion at all:
+Blocking is a pure data-layout transform, and on the chip it is built from
+the operations that stream: multi-operand sorts (the payload moves with the
+key), cumulative and segmented scans, and contiguous slice copies. An index
+vector applied one element at a time (``x[order]``, ``take_along_axis``,
+``.at[dest].set``) is not among them: measured on a TPU v5e, a gather or a
+scatter over the 96.5M-entry layout costs 22-27 ns an ELEMENT whatever the
+element's size (2.2-2.6 s a pass, where a streaming pass over the same 386
+MB is a millisecond), and the pass once held eighteen of them (PERF.md,
+PR 27). Keeping the whole pipeline on device means the host never
+materializes the ``k × k × bmax`` stratum expansion at all:
 
 - synthetic benchmarks (``synthetic_like_device``) move only scalars and a
   256-byte size vector across the host↔device link — the difference between
@@ -348,7 +354,7 @@ def _bucket_entries(key, u, i, r, w, row_of_u, row_of_i,
     Weight-0 padding entries keep their slots (static shapes) but carry
     w=0 through to the layout — no-ops everywhere downstream."""
     # named scopes here and in _layout: HLO metadata only, so a device
-    # trace can name the phases (the sorts, the offsets, the scatters)
+    # trace can name the phases (the sorts, the offsets, the block copies)
     with jax.named_scope("bucket/assign"):
         urow = row_of_u[u]
         irow = row_of_i[i]
@@ -360,21 +366,61 @@ def _bucket_entries(key, u, i, r, w, row_of_u, row_of_i,
         # are 0 so they would otherwise pile into one bucket and inflate
         # bmax (and the whole k²·bmax layout) by the total pad count
         n = flat.shape[0]
-        flat = jnp.where(w > 0, flat,
-                         jnp.arange(n, dtype=jnp.int32) % (k * k))
-    with jax.named_scope("bucket/sizes"):
-        sizes = jnp.zeros(k * k, jnp.int32).at[flat].add(1)
+        ar = jnp.arange(n, dtype=jnp.int32)
+        flat = jnp.where(w > 0, flat, ar % (k * k))
     # seeded permutation + stable bucket sort: buckets become contiguous
     # runs with random within-bucket order (≙ the host pass's shuffle +
-    # stable counting sort; avoids 64-bit composite keys, see _assign_rows)
+    # stable counting sort). ``perm[argsort(flat[perm], stable)]`` orders
+    # the entries by (bucket, place in the shuffle), so ONE two-key sort
+    # carries every column along: no index vector is ever applied one
+    # element at a time (avoids 64-bit composite keys, see _assign_rows)
     with jax.named_scope("bucket/permutation"):
         perm = jax.random.permutation(key, n)
+        _, rank = jax.lax.sort_key_val(perm, ar)  # rank[perm[t]] = t
     with jax.named_scope("bucket/sort"):
-        order = perm[jnp.argsort(flat[perm], stable=True)]
-    with jax.named_scope("bucket/reorder"):
-        return (sizes, flat[order], urow[order], irow[order],
-                jnp.asarray(r, jnp.float32)[order],
-                jnp.asarray(w, jnp.float32)[order])
+        flat_s, _, urow_s, irow_s, vals_s, w_s = jax.lax.sort(
+            (flat, rank, urow, irow, jnp.asarray(r, jnp.float32),
+             jnp.asarray(w, jnp.float32)), num_keys=2, is_stable=False)
+    with jax.named_scope("bucket/sizes"):
+        ends = jnp.searchsorted(
+            flat_s, jnp.arange(1, k * k + 1, dtype=jnp.int32))
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    return sizes, flat_s, urow_s, irow_s, vals_s, w_s
+
+
+def _carry(flag: jax.Array, vals: tuple, reverse: bool = False) -> tuple:
+    """Segmented carry along the last axis: each of ``vals`` (32-bit) read
+    at the nearest flagged position at or before every position (at or
+    after, ``reverse``) — ``take_along_axis(v, cummax(where(flag, j, -1)))``
+    without the gather, the values moved and never computed with.
+
+    A running max over keys ``position << c | c bits of the value``: the
+    position leads, so the max is the nearest flagged position's key and
+    the value's bits ride below it, ``c = 31 - bits(position)`` of them a
+    pass (16 at minibatch 32768: two passes a value). ``lax.cummax`` is the
+    scan the TPU streams (a reduce-window); the same carry written as a
+    ``lax.associative_scan`` over 32768-wide rows held the TPU compiler
+    for minutes and then crashed it (PR 27). The first position (the last,
+    ``reverse``) must be flagged, as a run's start (end) always is."""
+    axis = flag.ndim - 1
+    m = flag.shape[axis]
+    c = 31 - max((m - 1).bit_length(), 1)
+    if c < 1:
+        raise ValueError(f"_carry: rows of {m} leave no bit for a payload")
+    low = jnp.uint32((1 << c) - 1)
+    j = jax.lax.broadcasted_iota(jnp.int32, flag.shape, axis)
+    pos = ((m - 1 - j) if reverse else j) << c
+    out = []
+    for v in vals:
+        bits = jax.lax.bitcast_convert_type(v, jnp.uint32)
+        got = jnp.zeros(v.shape, jnp.uint32)
+        for shift in range(0, 32, c):
+            part = ((bits >> shift) & low).astype(jnp.int32)
+            key = jax.lax.cummax(jnp.where(flag, pos | part, -1),
+                                 axis=axis, reverse=reverse)
+            got = got | ((key.astype(jnp.uint32) & low) << shift)
+        out.append(jax.lax.bitcast_convert_type(got, v.dtype))
+    return tuple(out)
 
 
 def _inv_counts_2d(rows: jax.Array, w: jax.Array,
@@ -382,42 +428,38 @@ def _inv_counts_2d(rows: jax.Array, w: jax.Array,
     """Per-entry 1/(weight-sum of its row within its minibatch).
 
     Device form of ``blocking.minibatch_inv_counts`` / the native
-    ``minibatch_inv_counts_flat``: sort each minibatch by row, find each
-    run's weighted size with two cummax passes + a cumsum difference, and
-    un-sort. Padding (weight 0) contributes nothing; its own scale is
-    irrelevant (its delta is zero regardless).
+    ``minibatch_inv_counts_flat``: sort each minibatch by row with the
+    weights and the positions as payload, find each run's weighted size as
+    a cumsum difference between the run's two ends (``_carry`` brings the
+    ends' values to every member), and un-sort by the positions. Padding
+    (weight 0) contributes nothing; its own scale is irrelevant (its delta
+    is zero regardless).
 
     ``presorted``: the caller guarantees each minibatch row-vector is
-    already ascending (the ``minibatch_sort`` side in ``_layout``) — the
-    inner argsort and the final un-sort drop out, saving one full sort +
-    three gathers over the whole layout (run detection is identical on
-    sorted input, so the result is bit-equal).
+    already ascending (the ``minibatch_sort`` side in ``_layout``) — both
+    sorts drop out (run detection is identical on sorted input, so the
+    result is bit-equal).
     """
-    mb = rows.shape[-1]
-    j = jnp.arange(mb, dtype=jnp.int32)[None, :]
     if presorted:
         sr, sw = rows, w
     else:
-        sidx = jnp.argsort(rows, axis=-1)
-        sr = jnp.take_along_axis(rows, sidx, axis=-1)
-        sw = jnp.take_along_axis(w, sidx, axis=-1)
+        j = jax.lax.broadcasted_iota(jnp.int32, rows.shape, rows.ndim - 1)
+        sr, sw, sj = jax.lax.sort((rows, w, j), dimension=-1, num_keys=1,
+                                  is_stable=True)
     diff = sr[:, 1:] != sr[:, :-1]
     ones = jnp.ones_like(sr[:, :1], bool)
     new = jnp.concatenate([ones, diff], axis=-1)  # run starts
     last = jnp.concatenate([diff, ones], axis=-1)  # run ends
-    start = jax.lax.cummax(jnp.where(new, j, -1), axis=1)
-    end_rev = jax.lax.cummax(
-        jnp.where(last, mb - 1 - j, -1)[:, ::-1], axis=1)[:, ::-1]
-    end = mb - 1 - end_rev
     cumw = jnp.cumsum(sw, axis=-1)
-    W = (jnp.take_along_axis(cumw, end, axis=-1)
-         - jnp.take_along_axis(cumw, start, axis=-1)
-         + jnp.take_along_axis(sw, start, axis=-1))
+    cumw_start, sw_start = _carry(new, (cumw, sw))
+    cumw_end, = _carry(last, (cumw,), reverse=True)
+    W = cumw_end - cumw_start + sw_start
     inv_sorted = 1.0 / jnp.maximum(W, 1.0)
     if presorted:
         return inv_sorted
-    inv_back = jnp.argsort(sidx, axis=-1)
-    return jnp.take_along_axis(inv_sorted, inv_back, axis=-1)
+    # un-sort: the original positions rode along as payload
+    return jax.lax.sort((sj, inv_sorted), dimension=-1, num_keys=1,
+                        is_stable=False)[1]
 
 
 @jax.jit
@@ -428,49 +470,51 @@ def _inv_counts_pair(su2, si2, sw2):
 @partial(jax.jit, static_argnames=("k", "bmax", "mb", "sort_side"))
 def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
             k: int, bmax: int, mb: int, sort_side: str | None):
-    """Scatter bucket-sorted entries into the padded [k, k, bmax] layout and
-    compute the per-minibatch collision scales (both sides) on device."""
-    n = flat_s.shape[0]
-    with jax.named_scope("layout/offsets"):
-        starts = jnp.concatenate(
-            [jnp.zeros(1, jnp.int32), jnp.cumsum(sizes)[:-1]])
-        idx_in = jnp.arange(n, dtype=jnp.int32) - starts[flat_s]
-        dest = flat_s * bmax + idx_in
-    total = k * k * bmax
-    with jax.named_scope("layout/scatter"):
-        su = jnp.zeros(total, jnp.int32).at[dest].set(
-            urow_s, unique_indices=True)
-        si = jnp.zeros(total, jnp.int32).at[dest].set(
-            irow_s, unique_indices=True)
-        sv = jnp.zeros(total, jnp.float32).at[dest].set(
-            vals_s, unique_indices=True)
-        sw = jnp.zeros(total, jnp.float32).at[dest].set(
-            w_s, unique_indices=True)
+    """Copy bucket-sorted entries into the padded [k, k, bmax] layout and
+    compute the per-minibatch collision scales (both sides) on device.
 
-    def two_d(a):
-        return a.reshape(-1, mb)
+    The entries arrive bucket-sorted, so bucket ``b`` is one contiguous run
+    of them and one contiguous row of the layout: k² block copies, no
+    per-element scatter (``flat_s`` is implied by ``sizes`` and unread)."""
+    del flat_s
+    with jax.named_scope("layout/offsets"):
+        starts = jnp.cumsum(sizes) - sizes
+    with jax.named_scope("layout/copy"):
+        real = jnp.arange(bmax, dtype=jnp.int32)
+        # a run's window may reach past the last entry: pad, don't clamp
+        src = tuple(jnp.pad(a, (0, bmax))
+                    for a in (urow_s, irow_s, vals_s, w_s))
+
+        def copy_bucket(b):
+            return tuple(
+                jnp.where(real < sizes[b],
+                          jax.lax.dynamic_slice(a, (starts[b],), (bmax,)),
+                          0)
+                for a in src)
+
+        # [k², bmax] rows of whole minibatches -> the minibatch-major view
+        su, si, sv, sw = (a.reshape(-1, mb) for a in jax.lax.map(
+            copy_bucket, jnp.arange(k * k, dtype=jnp.int32)))
 
     if sort_side is not None:
         # intra-minibatch locality sort (≙ blocking.block_ratings
         # minibatch_sort): membership unchanged, math identical up to
-        # float reassociation
-        keyarr = su if sort_side == "user" else si
+        # float reassociation. The key is one of the columns, so one
+        # stable sort moves all four
         with jax.named_scope("layout/minibatch_sort"):
-            order = jnp.argsort(two_d(keyarr), axis=-1)
-
-        def apply(a):
-            return jnp.take_along_axis(two_d(a), order,
-                                       axis=-1).reshape(total)
-
-        with jax.named_scope("layout/minibatch_reorder"):
-            su, si, sv, sw = apply(su), apply(si), apply(sv), apply(sw)
+            if sort_side == "user":
+                su, si, sv, sw = jax.lax.sort(
+                    (su, si, sv, sw), dimension=-1, num_keys=1,
+                    is_stable=True)
+            else:
+                si, su, sv, sw = jax.lax.sort(
+                    (si, su, sv, sw), dimension=-1, num_keys=1,
+                    is_stable=True)
 
     with jax.named_scope("layout/inv_counts_u"):
-        icu = _inv_counts_2d(two_d(su), two_d(sw),
-                             presorted=sort_side == "user").reshape(total)
+        icu = _inv_counts_2d(su, sw, presorted=sort_side == "user")
     with jax.named_scope("layout/inv_counts_v"):
-        icv = _inv_counts_2d(two_d(si), two_d(sw),
-                             presorted=sort_side == "item").reshape(total)
+        icv = _inv_counts_2d(si, sw, presorted=sort_side == "item")
     shape = (k, k, bmax)
     return (su.reshape(shape), si.reshape(shape), sv.reshape(shape),
             sw.reshape(shape), icu.reshape(shape), icv.reshape(shape))
@@ -494,7 +538,7 @@ def device_block_problem(
     The only host↔device traffic is the 256-byte bucket-size vector (read
     back to fix the padded block size ``bmax``, which must be a static shape
     for XLA). Everything else — balanced row assignment, omegas, the
-    stratum-major scatter, per-minibatch collision scales — happens on chip.
+    stratum-major layout, per-minibatch collision scales — happens on chip.
 
     ``weights`` (float32, optional) marks weight-0 entries as padding: they
     keep layout slots (static shapes) but contribute nothing to counts,

@@ -447,3 +447,323 @@ class TestInvCountsPresorted:
         b = _inv_counts_2d(jnp.asarray(rows), jnp.asarray(w),
                            presorted=True)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# --------------------------------------------------------------------------
+# ISSUE 27: the layout is the same arrays, bit for bit, however it is moved
+# --------------------------------------------------------------------------
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(np.int32) if a.dtype == np.float32 else a
+
+
+def _assert_layout_equals_reference(p, u, i, r, nu, ni, *, k, mb, seed,
+                                    sort_side):
+    """``p`` against the benchmark's plain reference blocking of the same
+    input (scatter + argsort + gathers; it imports nothing of the program)."""
+    from benchmark.reference import dsgd_ref
+
+    ref = dsgd_ref.block_layout(
+        jnp.asarray(u, jnp.int32), jnp.asarray(i, jnp.int32),
+        jnp.asarray(r), num_users=nu, num_items=ni, k=k, minibatch=mb,
+        solver_seed=seed, sort_side=sort_side)
+    assert p.su.shape[-1] == ref["bmax"]
+    for name in ("su", "si", "sv", "sw"):
+        got = np.asarray(getattr(p, name)).reshape(-1, mb)
+        np.testing.assert_array_equal(_bits(got), _bits(ref[name]), name)
+    for name in ("omega_u", "omega_v", "row_of_user", "row_of_item",
+                 "id_of_user_row", "id_of_item_row"):
+        np.testing.assert_array_equal(
+            _bits(getattr(p, name)), _bits(ref[name]), name)
+
+
+def _one_empty_bucket(k=2, per=40):
+    """Every user rates the one item 0: only the k buckets of that item's
+    block hold entries, the other (k - 1) * k stay empty."""
+    nu, ni = 8 * k, 8 * k
+    u = np.repeat(np.arange(nu), per)
+    return u, np.zeros_like(u), nu, ni
+
+
+class TestLayoutBitForBit:
+    """(a) of ISSUE 27: ``device_block_problem`` against the benchmark's
+    plain reference blocking and against the gather/scatter oracle below."""
+
+    @pytest.mark.parametrize("skew", [None, 2.0])
+    @pytest.mark.parametrize("sort_side", [None, "user", "item"])
+    @pytest.mark.parametrize("k", [2, 4, 8])
+    def test_equals_reference_block_layout(self, k, sort_side, skew):
+        u, i, r, nu, ni = _toy(n=3000, nu=120, ni=90, seed=k, skew=skew)
+        mb = 32
+        p = device_blocking.device_block_problem(
+            u, i, r, nu, ni, num_blocks=k, minibatch_multiple=mb, seed=7,
+            minibatch_sort=sort_side)
+        _assert_layout_equals_reference(
+            p, u, i, r, nu, ni, k=k, mb=mb, seed=7, sort_side=sort_side)
+
+    @pytest.mark.parametrize("case", ["empty_bucket", "exactly_bmax",
+                                      "one_entry", "bmax_over_n"])
+    def test_edge_shapes_equal_reference(self, case):
+        k, mb = 2, 8
+        if case == "empty_bucket":
+            u, i, nu, ni = _one_empty_bucket(k)
+        elif case == "exactly_bmax":
+            # one user, one item: a single bucket of 3 * mb entries, so
+            # the fullest bucket fills its padded row to the last slot
+            nu, ni = 4, 4
+            u = np.zeros(3 * mb, np.int64)
+            i = np.zeros(3 * mb, np.int64)
+        elif case == "one_entry":
+            nu, ni = 5, 3
+            u, i = np.array([4]), np.array([2])
+        else:  # the padded block is longer than the whole input
+            nu, ni = 6, 6
+            u, i = np.array([0, 1, 2]), np.array([0, 0, 0])
+        r = np.linspace(-1, 1, len(u)).astype(np.float32)
+        p = device_blocking.device_block_problem(
+            u, i, r, nu, ni, num_blocks=k, minibatch_multiple=mb, seed=3,
+            minibatch_sort="item")
+        _assert_layout_equals_reference(
+            p, u, i, r, nu, ni, k=k, mb=mb, seed=3, sort_side="item")
+        sw = np.asarray(p.sw)
+        per_bucket = sw.reshape(k * k, -1).sum(axis=1)
+        if case == "empty_bucket":
+            assert (per_bucket == 0).any() and sw.sum() == len(u)
+        if case == "exactly_bmax":
+            assert per_bucket.max() == p.su.shape[-1] == 3 * mb
+
+    @pytest.mark.parametrize("sort_side", [None, "user", "item"])
+    def test_weight_zero_padding_same_real_entries_bit_for_bit(
+            self, sort_side):
+        """Padding entries carry w=0 and keep their slots; the real
+        entries' row maps, omegas and multiset equal the unpadded call's
+        (as ``test_weight_zero_padding_entries_are_noops``), and the
+        padded layout equals the gather/scatter oracle's bit for bit."""
+        u, i, r, nu, ni = _toy(n=1500, nu=60, ni=50, seed=6, skew=2.0)
+        n_pad = 77
+        up = np.concatenate([u, np.zeros(n_pad, np.int64)])
+        ip = np.concatenate([i, np.zeros(n_pad, np.int64)])
+        rp = np.concatenate([r, np.zeros(n_pad, np.float32)])
+        wp = np.concatenate([np.ones(len(u), np.float32),
+                             np.zeros(n_pad, np.float32)])
+        kw = dict(num_blocks=2, minibatch_multiple=32, seed=4,
+                  minibatch_sort=sort_side)
+        plain = device_blocking.device_block_problem(u, i, r, nu, ni, **kw)
+        padded = device_blocking.device_block_problem(
+            up, ip, rp, nu, ni, weights=wp, **kw)
+        for name in ("row_of_user", "row_of_item", "omega_u", "omega_v",
+                     "id_of_user_row", "id_of_item_row"):
+            np.testing.assert_array_equal(
+                _bits(getattr(plain, name)), _bits(getattr(padded, name)))
+
+        def real(p):
+            m = np.asarray(p.sw) > 0
+            return sorted(zip(np.asarray(p.su)[m].tolist(),
+                              np.asarray(p.si)[m].tolist(),
+                              _bits(p.sv)[m].tolist()))
+        assert real(plain) == real(padded)
+        assert int((np.asarray(padded.sw) > 0).sum()) == len(u)
+        oracle = _oracle_layout(up, ip, rp, wp, nu, ni, **kw)
+        for name, want in zip(("su", "si", "sv", "sw", "icu", "icv"),
+                              oracle):
+            np.testing.assert_array_equal(
+                _bits(getattr(padded, name)), _bits(want), name)
+
+
+# Today's bodies before ISSUE 27, kept as the oracles: an index vector
+# computed, then applied one element at a time.
+
+
+def _oracle_inv_counts_2d(rows, w, presorted=False):
+    mb = rows.shape[-1]
+    j = jnp.arange(mb, dtype=jnp.int32)[None, :]
+    if presorted:
+        sr, sw = rows, w
+    else:
+        sidx = jnp.argsort(rows, axis=-1)
+        sr = jnp.take_along_axis(rows, sidx, axis=-1)
+        sw = jnp.take_along_axis(w, sidx, axis=-1)
+    diff = sr[:, 1:] != sr[:, :-1]
+    ones = jnp.ones_like(sr[:, :1], bool)
+    new = jnp.concatenate([ones, diff], axis=-1)
+    last = jnp.concatenate([diff, ones], axis=-1)
+    start = jax.lax.cummax(jnp.where(new, j, -1), axis=1)
+    end_rev = jax.lax.cummax(
+        jnp.where(last, mb - 1 - j, -1)[:, ::-1], axis=1)[:, ::-1]
+    end = mb - 1 - end_rev
+    cumw = jnp.cumsum(sw, axis=-1)
+    W = (jnp.take_along_axis(cumw, end, axis=-1)
+         - jnp.take_along_axis(cumw, start, axis=-1)
+         + jnp.take_along_axis(sw, start, axis=-1))
+    inv_sorted = 1.0 / jnp.maximum(W, 1.0)
+    if presorted:
+        return inv_sorted
+    inv_back = jnp.argsort(sidx, axis=-1)
+    return jnp.take_along_axis(inv_sorted, inv_back, axis=-1)
+
+
+def _oracle_layout(u, i, r, w, nu, ni, *, num_blocks, minibatch_multiple,
+                   seed, minibatch_sort):
+    """``device_block_problem``'s six layout arrays by the gather/scatter
+    forms: ``perm[argsort(flat[perm])]`` applied by ``[order]``, the
+    ``dest`` scatter, argsort + ``take_along_axis``."""
+    k, mb = num_blocks, minibatch_multiple
+    u, i = jnp.asarray(u, jnp.int32), jnp.asarray(i, jnp.int32)
+    r, w = jnp.asarray(r, jnp.float32), jnp.asarray(w, jnp.float32)
+    base = jax.random.PRNGKey(seed)
+    rpb_u = device_blocking.rows_per_block(nu, k)
+    rpb_v = device_blocking.rows_per_block(ni, k)
+    cu, cv = device_blocking._weighted_counts(u, i, w, nu, ni)
+    row_of_u = device_blocking._assign_rows(
+        jax.random.fold_in(base, 10), cu, k, rpb_u, k * rpb_u)[0]
+    row_of_i = device_blocking._assign_rows(
+        jax.random.fold_in(base, 11), cv, k, rpb_v, k * rpb_v)[0]
+    urow, irow = row_of_u[u], row_of_i[i]
+    flat = (((irow // rpb_v - urow // rpb_u) % k) * k
+            + urow // rpb_u).astype(jnp.int32)
+    n = flat.shape[0]
+    flat = jnp.where(w > 0, flat, jnp.arange(n, dtype=jnp.int32) % (k * k))
+    sizes = jnp.zeros(k * k, jnp.int32).at[flat].add(1)
+    perm = jax.random.permutation(jax.random.fold_in(base, 12), n)
+    order = perm[jnp.argsort(flat[perm], stable=True)]
+    flat_s = flat[order]
+    bmax = -(-max(int(sizes.max()), 1) // mb) * mb
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                              jnp.cumsum(sizes)[:-1]])
+    dest = flat_s * bmax + jnp.arange(n, dtype=jnp.int32) - starts[flat_s]
+    cols = [jnp.zeros(k * k * bmax, a.dtype).at[dest].set(a[order])
+            .reshape(-1, mb) for a in (urow, irow, r, w)]
+    if minibatch_sort is not None:
+        o = jnp.argsort(cols[0 if minibatch_sort == "user" else 1], axis=-1)
+        cols = [jnp.take_along_axis(a, o, axis=-1) for a in cols]
+    su, si, sv, sw = cols
+    icu = _oracle_inv_counts_2d(su, sw, presorted=minibatch_sort == "user")
+    icv = _oracle_inv_counts_2d(si, sw, presorted=minibatch_sort == "item")
+    return [a.reshape(k, k, bmax) for a in (su, si, sv, sw, icu, icv)]
+
+
+def _inv_count_rows(case, rng, n_mb=6, mb=32):
+    if case == "one_run":  # a run spans the whole minibatch
+        rows = np.repeat(rng.integers(0, 9, (n_mb, 1)), mb, axis=1)
+    elif case == "runs_of_one":  # every row distinct
+        rows = np.stack([rng.permutation(mb) for _ in range(n_mb)])
+    elif case == "all_padding":
+        rows = np.zeros((n_mb, mb), np.int64)
+    else:  # mixed: long and short runs
+        rows = rng.integers(0, 7, (n_mb, mb))
+    if case == "all_padding":
+        w = np.zeros((n_mb, mb), np.float32)
+    elif case == "fractional":
+        w = rng.random((n_mb, mb)).astype(np.float32)
+    else:
+        w = (rng.random((n_mb, mb)) > 0.25).astype(np.float32)
+    return rows.astype(np.int32), w
+
+
+class TestInvCountsBitForBit:
+    """(b) of ISSUE 27: the scan form of ``_inv_counts_2d`` against the
+    gather form above, element for element."""
+
+    @pytest.mark.parametrize("presorted", [False, True])
+    @pytest.mark.parametrize("case", ["mixed", "one_run", "runs_of_one",
+                                      "all_padding", "fractional"])
+    def test_equals_gather_form(self, case, presorted):
+        rows, w = _inv_count_rows(case, np.random.default_rng(11))
+        if presorted:
+            order = np.argsort(rows, axis=-1, kind="stable")
+            rows = np.take_along_axis(rows, order, axis=-1)
+            w = np.take_along_axis(w, order, axis=-1)
+        got = device_blocking._inv_counts_2d(
+            jnp.asarray(rows), jnp.asarray(w), presorted=presorted)
+        want = _oracle_inv_counts_2d(
+            jnp.asarray(rows), jnp.asarray(w), presorted=presorted)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    @pytest.mark.parametrize("mb", [1, 2, 5, 64])
+    def test_recompute_inv_counts_equals_gather_form(self, mb):
+        u, i, r, nu, ni = _toy(n=900, nu=30, ni=20, seed=2, skew=2.0)
+        p = device_blocking.device_block_problem(
+            u, i, r, nu, ni, num_blocks=2, minibatch_multiple=320)
+        icu, icv = device_blocking.recompute_inv_counts(p, mb)
+        sw2 = p.sw.reshape(-1, mb)
+        for got, rows in ((icu, p.su), (icv, p.si)):
+            want = _oracle_inv_counts_2d(rows.reshape(-1, mb), sw2)
+            np.testing.assert_array_equal(
+                _bits(got).reshape(-1, mb), _bits(want))
+
+
+class TestNoPerElementIndexing:
+    """The mechanism of ISSUE 27, held structurally: at the ratings'
+    length the blocking programs move data by sorts, scans and slice
+    copies. A ``gather`` or ``scatter`` whose result is as long as the
+    entries or the layout is an ``x[order]`` put back."""
+
+    N, NU, NI, K, MB = 5000, 300, 200, 4, 64
+
+    @staticmethod
+    def _indexed_ops(lowered):
+        """(op, result element count) of every gather / scatter of a
+        lowered program's StableHLO module, nested regions included."""
+        found = []
+
+        def walk(op):
+            name = op.operation.name
+            if name in ("stablehlo.gather", "stablehlo.scatter"):
+                found.append((name.split(".")[1], max(
+                    int(np.prod(r.type.shape)) for r in op.results)))
+            for region in op.regions:
+                for block in region:
+                    for inner in block:
+                        walk(inner)
+
+        walk(lowered.compiler_ir(dialect="stablehlo").operation)
+        return found
+
+    def test_the_reader_sees_the_forms_it_forbids(self):
+        rows = jax.ShapeDtypeStruct((8, self.MB), jnp.int32)
+        w = jax.ShapeDtypeStruct((8, self.MB), jnp.float32)
+        ops = self._indexed_ops(jax.jit(_oracle_inv_counts_2d).lower(rows, w))
+        # (take_along_axis lowers to one private function a dtype: the
+        # module is walked whole, every function of it)
+        assert ops and set(ops) == {("gather", 8 * self.MB)}, ops
+        ops = self._indexed_ops(jax.jit(
+            lambda a, d: jnp.zeros(2 * self.N, a.dtype).at[d].set(a)).lower(
+                jax.ShapeDtypeStruct((self.N,), jnp.float32),
+                jax.ShapeDtypeStruct((self.N,), jnp.int32)))
+        assert ops == [("scatter", 2 * self.N)], ops
+
+    def _lowered(self):
+        k, n = self.K, self.N
+        rpb_u = device_blocking.rows_per_block(self.NU, k)
+        rpb_v = device_blocking.rows_per_block(self.NI, k)
+        i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
+        f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
+        bucket = device_blocking._bucket_entries.lower(
+            jax.ShapeDtypeStruct((2,), jnp.uint32), i32, i32, f32, f32,
+            jax.ShapeDtypeStruct((self.NU,), jnp.int32),
+            jax.ShapeDtypeStruct((self.NI,), jnp.int32), k, rpb_u, rpb_v)
+        bmax = 6 * self.MB
+        layouts = {
+            side: device_blocking._layout.lower(
+                i32, i32, i32, f32, f32,
+                jax.ShapeDtypeStruct((k * k,), jnp.int32), k, bmax,
+                self.MB, side)
+            for side in (None, "user", "item")}
+        return bucket, layouts, k * k * bmax
+
+    def test_bucket_entries_gathers_only_the_row_tables(self):
+        # all four steps of the issue are in: step 4 (the bucket phase) too
+        bucket, _, _ = self._lowered()
+        long_ops = [(op, sz) for op, sz in
+                    self._indexed_ops(bucket) if sz >= self.N]
+        assert long_ops == [("gather", self.N)] * 2, long_ops  # row_of_*[·]
+
+    @pytest.mark.parametrize("side", [None, "user", "item"])
+    def test_layout_has_no_gather_or_scatter_of_layout_length(self, side):
+        _, layouts, total = self._lowered()
+        ops = self._indexed_ops(layouts[side])
+        long_ops = [(op, sz) for op, sz in ops
+                    if sz >= min(self.N, total) // self.K ** 2]
+        assert long_ops == [], long_ops
