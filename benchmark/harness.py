@@ -7,6 +7,8 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import gc
+import importlib
+import importlib.util
 import json
 import os
 import shutil
@@ -18,7 +20,6 @@ from benchmark.peaks import load_peaks
 from benchmark.spans import Spans
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-HERE = os.path.join(ROOT, "benchmark")
 T_IMPORT = time.perf_counter()
 
 
@@ -53,6 +54,7 @@ class Cell:
     traffic: dict
     end_to_end: list      # manifest entries this cell reports
     per_layer: list
+    root: str = ROOT      # the checkout whose files the lookups read
 
 
 def resolve_cell(name: str, manifest: dict | None = None,
@@ -90,7 +92,44 @@ def resolve_cell(name: str, manifest: dict | None = None,
                 traffic_name=entry["traffic"], config=config,
                 traffic=traffic,
                 end_to_end=[m for m in manifest["end_to_end"] if mine(m)],
-                per_layer=[m for m in manifest["per_layer"] if mine(m)])
+                per_layer=[m for m in manifest["per_layer"] if mine(m)],
+                root=root)
+
+
+def load_file(root: str, rel: str, what: str):
+    """``<root>/benchmark/<rel>.py`` as a module: how a runner kind, a
+    solver and a reference are found, by the name a data file gives. One
+    that has no file ends the run, naming the file looked for. In this
+    checkout the module is the package's own (one copy, whoever imports
+    it); in another root it is loaded from the file."""
+    path = os.path.join(root, "benchmark", *rel.split("/")) + ".py"
+    if not os.path.isfile(path):
+        raise SystemExit(f"benchmark: {what} has no file {path}")
+    dotted = "benchmark." + rel.replace("/", ".")
+    if os.path.realpath(root) == os.path.realpath(ROOT):
+        return importlib.import_module(dotted)
+    spec = importlib.util.spec_from_file_location(
+        dotted.replace(".", "_") + "_of_another_root", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def runner_for(cell: Cell):
+    """The cell's runner kind, ``runners/<kind>.py`` (its contract:
+    ``benchmark/README.md``)."""
+    kind = cell.traffic["runner"]
+    runner = load_file(cell.root, "runners/" + kind, f"runner kind {kind!r}")
+    if not callable(getattr(runner, "run", None)):
+        raise SystemExit(f"benchmark: {runner.__file__} has no run()")
+    return runner
+
+
+def reference_for(cell: Cell, default: str):
+    """The plain reference the configuration names, ``reference/<name>.py``;
+    ``default`` (the runner kind's own) where it names none."""
+    name = cell.config.get("reference", default)
+    return load_file(cell.root, "reference/" + name, f"reference {name!r}")
 
 
 def require_chips(chips: int) -> dict:
@@ -246,10 +285,10 @@ def layer_metrics(cell: Cell, ctx: dict) -> dict:
     read. A reader that finds nothing returns None and the metric is left
     out of the line."""
     out = {}
+    directory = os.path.join(cell.root, "benchmark", "layer_metrics")
     for m in cell.per_layer:
-        spec = load_json(os.path.join(HERE, "layer_metrics",
-                                      m["name"] + ".json"))
-        value = readers.read(spec, ctx)
+        spec = load_json(os.path.join(directory, m["name"] + ".json"))
+        value = readers.read(spec, ctx, directory)
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out

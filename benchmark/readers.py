@@ -7,7 +7,9 @@ its own beside the JSON file (``read(ctx) -> float | None``).
 ``ctx`` is what a run collected: ``trace`` (``trace_reduce.reduce_trace``'s
 output, None without ``--trace 1``), ``series`` (named lists of numbers from
 the benchmark's spans), ``counters``, ``sizes`` (the numbers the counts
-need), ``peaks``, ``window_s`` (host wall of the window) and ``chips``.
+need), ``peaks``, ``window_s`` (host wall of the window), ``chips`` and, from
+the ``fit`` kind, ``sweep_flops`` (the FLOP one sweep of the cell's solver
+needs, by the solver file's own count).
 
 A reader that finds nothing to read returns None. It never returns 0 for a
 share of a roofline or of a peak.
@@ -152,7 +154,7 @@ def window_share(spec, ctx):
         done = ctx["counters"].get("sweeps_done")
         if not done:
             return None
-        work = done * counts.sweep_flops(sizes["nnz_train"], sizes["rank"])
+        work = done * ctx["sweep_flops"]
     elif spec["count"] == "serve_ops":
         users = ctx["counters"].get("users_answered")
         if not users:
@@ -170,10 +172,12 @@ KINDS = {f.__name__: f for f in (
     span_minus_device, roofline, window_share)}
 
 
-def read(metric: dict, ctx: dict):
+def read(metric: dict, ctx: dict,
+         directory: str = os.path.join(HERE, "layer_metrics")):
+    """``directory``: where the metric's file is, and so its own reader."""
     spec = metric["reader"]
     if spec["kind"] == "python":
-        path = os.path.join(HERE, "layer_metrics", spec["file"])
+        path = os.path.join(directory, spec["file"])
         module_spec = importlib.util.spec_from_file_location(
             "layer_metric_" + metric["name"].replace(".", "_"), path)
         module = importlib.util.module_from_spec(module_spec)

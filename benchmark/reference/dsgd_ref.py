@@ -172,8 +172,8 @@ def to_id_space(table, row_of_id):
 def fit(u, i, r, cfg: dict, sweeps: int, *, fault=None):
     """The reference fit: ``sweeps`` sweeps from its own init. Returns the
     initial tables and the tables after each sweep, all in ID space
-    (``[num_users, rank]``, ``[num_items, rank]``), and per side the mask
-    of ids seen in training."""
+    (``[num_users, rank]``, ``[num_items, rank]``), per side the mask of
+    ids seen in training, and what it notes for the result line."""
     lay = block_layout(
         u, i, r, num_users=cfg["num_users"], num_items=cfg["num_items"],
         k=cfg["num_blocks"], minibatch=cfg["minibatch_size"],
@@ -184,7 +184,7 @@ def fit(u, i, r, cfg: dict, sweeps: int, *, fault=None):
     ru, ri = lay["row_of_user"], lay["row_of_item"]
     out = {"init": (to_id_space(U, ru), to_id_space(V, ri)),
            "seen": (lay["omega_u"][ru] > 0, lay["omega_v"][ri] > 0),
-           "sweeps": [], "bmax": lay["bmax"]}
+           "sweeps": [], "notes": {"bmax": lay["bmax"]}}
     for s in range(1, sweeps + 1):
         U, V = sweep(U, V, lay["su"], lay["si"], lay["sv"], lay["sw"],
                      lay["omega_u"], lay["omega_v"],

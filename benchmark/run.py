@@ -4,9 +4,11 @@
 
 Resolves the cell to ``benchmark/configs/<config>.json`` and
 ``benchmark/traffic/<traffic>.json`` by the names in ``BENCHMARK.json``; the
-traffic file names the runner kind. Exits non-zero, printing no result,
-when JAX finds no TPU or fewer chips than the cell asks for, or when the
-program is not beside it. The last line of standard output is the result.
+traffic file names the runner kind, which is ``benchmark/runners/<kind>.py``
+(``README.md`` has the contract of its ``run``). Exits non-zero, printing no
+result, when the kind has no such file, when JAX finds no TPU or fewer chips
+than the cell asks for, or when the program is not beside it. The last
+line of standard output is the result.
 ``--control`` (not for the driver) puts the cell's low-precision control in
 the program's place, to show that the comparison fails it.
 """
@@ -21,9 +23,11 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-RUNNERS = {"fit": "benchmark.runners.fit",
-           "serve_closed": "benchmark.runners.serve",
-           "serve_open": "benchmark.runners.serve"}
+# what a runner kind's run(cell, seed, seconds, trace, device, control=None)
+# returns (README.md, "A runner kind", says what each holds)
+RESULT_KEYS = {"correct", "compared", "attempted", "failed", "fatal",
+               "values", "ctx", "memory_peak_bytes", "reduced",
+               "compiles_in_window", "notes"}
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
@@ -31,15 +35,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
              cell=None) -> tuple[str, dict]:
     """One run of one cell: ``(result line, everything collected)``.
     ``cell`` (tests): a ``harness.Cell`` built by hand, at a toy size."""
-    import importlib
-
     from benchmark import compare, harness
 
     cell = cell or harness.resolve_cell(workload)
+    runner = harness.runner_for(cell)
     device = (harness.start_on_chip(cell.chips) if require_tpu
               else harness.device_summary())
-    runner = importlib.import_module(RUNNERS[cell.traffic["runner"]])
     out = runner.run(cell, seed, seconds, trace, device, control=control)
+    missing = RESULT_KEYS - set(out)
+    if missing:
+        raise SystemExit(f"benchmark: {runner.__file__}: run() returned no "
+                         f"{sorted(missing)}")
     if out["compiles_in_window"]:
         print(f"benchmark: {out['compiles_in_window']} program(s) were "
               "lowered inside the measured window: the run is not correct",

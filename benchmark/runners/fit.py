@@ -3,11 +3,12 @@ one-sweep segments on planted ratings made from the seed, timed from the
 call to the end of the first sweep whose tables meet the configuration's
 holdout-RMSE target.
 
-The configuration names the solver: ``dsgd`` (``DSGD.fit_device`` on one
-chip; sweep ends are stamped through its ``evaluator.on_segment`` hook) or
-``mesh_dsgd`` (``MeshDSGD.fit_device`` over ``chips`` devices; it has no
-such hook, so the sweep ends come from a checkpoint manager of the
-benchmark's own, which stamps each one-sweep save and keeps the shards).
+The configuration names the solver, a file ``runners/solvers/<solver>.py``
+that builds the fit, and the plain reference it is compared with, a file
+``reference/<reference>.py`` (``dsgd_ref`` where it names none). What every
+fit shares is here: the data from the seed, the warm-up fit, the window,
+the stamps at the sweep ends, the tables in id space, the holdout RMSE and
+the target, the comparison and the result's keys.
 """
 
 from __future__ import annotations
@@ -18,7 +19,9 @@ import time
 import numpy as np
 
 from benchmark import compare, datagen, harness
-from benchmark.reference import dsgd_ref
+from benchmark.reference.dsgd_ref import holdout_rmse, to_id_space
+
+REFERENCE = "dsgd_ref"  # of a configuration that names none
 
 
 class SegmentStamps:
@@ -42,54 +45,22 @@ class SegmentStamps:
         self.on_segment(arrays["U"], arrays["V"], step=step)
 
 
-def solver_config(cfg: dict, iterations: int, **overrides) -> dict:
-    kw = dict(num_factors=cfg["num_factors"], lambda_=cfg["lambda"],
-              iterations=iterations, learning_rate=cfg["learning_rate"],
-              lr_schedule=cfg["lr_schedule"], seed=cfg["solver_seed"],
-              minibatch_size=cfg["minibatch_size"],
-              init_scale=cfg["init_scale"],
-              collision_mode=cfg["collision_mode"],
-              minibatch_sort=cfg["minibatch_sort"],
-              factor_dtype=cfg["factor_dtype"])
-    kw.update(overrides)
-    return kw
+def solver_for(cell):
+    """The solver the configuration names: ``runners/solvers/<solver>.py``
+    (its contract: ``benchmark/README.md``)."""
+    name = cell.config["solver"]
+    return harness.load_file(cell.root, "runners/solvers/" + name,
+                             f"solver {name!r}")
 
 
-def make_fit(cfg: dict, iterations: int, stamps, chips: int, **overrides):
-    """``fit(u, i, r) -> MFModel`` through the solver the configuration
-    names, one sweep per segment."""
-    nu, ni = cfg["num_users"], cfg["num_items"]
-    kw = solver_config(cfg, iterations, **overrides)
-    if cfg["solver"] == "dsgd":
-        from large_scale_recommendation_tpu.models.dsgd import (
-            DSGD,
-            DSGDConfig,
-        )
-
-        solver = DSGD(DSGDConfig(num_blocks=cfg["num_blocks"], **kw))
-        solver.evaluator = stamps
-        return lambda u, i, r: solver.fit_device(
-            u, i, r, nu, ni, checkpoint_every=1)
-    if cfg["solver"] == "mesh_dsgd":
-        import jax
-
-        from large_scale_recommendation_tpu.parallel import (
-            MeshDSGD,
-            Partitioner,
-        )
-        from large_scale_recommendation_tpu.parallel.dsgd_mesh import (
-            MeshDSGDConfig,
-        )
-
-        if cfg["num_blocks"] != chips:
-            raise ValueError("mesh_dsgd: the configuration's num_blocks is "
-                             f"{cfg['num_blocks']}, the cell has {chips} "
-                             "chips; the ring has one block per chip")
-        part = Partitioner(devices=jax.local_devices()[:chips])
-        solver = MeshDSGD(MeshDSGDConfig(**kw), partitioner=part)
-        return lambda u, i, r: solver.fit_device(
-            u, i, r, nu, ni, checkpoint_manager=stamps, checkpoint_every=1)
-    raise ValueError(f"unknown solver {cfg['solver']!r}")
+def control_overrides(solver, control: str | None) -> dict:
+    """What ``--control <name>`` overrides in this solver's configuration."""
+    if control is None:
+        return {}
+    if control not in solver.CONTROLS:
+        raise SystemExit(f"fit: {solver.__file__} has no control "
+                         f"{control!r}, only {sorted(solver.CONTROLS)}")
+    return dict(solver.CONTROLS[control])
 
 
 def sweeps_for(traffic: dict, seconds: float) -> int:
@@ -115,8 +86,8 @@ def id_space(model, tables, num_users: int, num_items: int):
 
     ru, su = side(model.users, num_users)
     ri, si = side(model.items, num_items)
-    out = [(dsgd_ref.to_id_space(jnp.asarray(U, jnp.float32), ru),
-            dsgd_ref.to_id_space(jnp.asarray(V, jnp.float32), ri))
+    out = [(to_id_space(jnp.asarray(U, jnp.float32), ru),
+            to_id_space(jnp.asarray(V, jnp.float32), ri))
            for U, V in tables]
     return out, (su, si)
 
@@ -136,9 +107,9 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     cfg, traffic = cell.config, cell.traffic
     on_chip = device["platform"] == "tpu"
     n_ref = int(traffic["reference_sweeps"])
-    overrides = {}
-    if control == "bf16":
-        overrides["factor_dtype"] = "bfloat16"
+    solver = solver_for(cell)
+    reference = harness.reference_for(cell, REFERENCE)
+    overrides = control_overrides(solver, control)
 
     # -- set-up: data from the seed, then every shape warmed by running it
     (u, i, r), (hu, hi, hr) = datagen.planted_ratings(
@@ -151,14 +122,14 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     window = harness.Window(trace, harness.trace_dir_for(cell.name),
                             strict=on_chip)
     warm = SegmentStamps(window.spans)
-    model = make_fit(cfg, 1, warm, cell.chips, **overrides)(u, i, r)
+    model = solver.make_fit(cfg, 1, warm, cell.chips, **overrides)(u, i, r)
     jax.block_until_ready((model.U, model.V))
     del model, warm
     gc.collect()
 
     # -- the window: one fit_device call
     stamps = SegmentStamps(window.spans)
-    fit = make_fit(cfg, sweeps, stamps, cell.chips, **overrides)
+    fit = solver.make_fit(cfg, sweeps, stamps, cell.chips, **overrides)
     with window.measure():
         with window.spans.span("fit/fit_device"):
             model = fit(u, i, r)
@@ -176,7 +147,7 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
                              cfg["num_items"])
     del model, fit, tables
     stamps.tables = []
-    rmse = [float(dsgd_ref.holdout_rmse(U, V, *seen, hu, hi, hr))
+    rmse = [float(holdout_rmse(U, V, *seen, hu, hi, hr))
             for U, V in prog_id]
     target = float(cfg["target_rmse"])
     hit = next((j for j, x in enumerate(rmse) if x <= target), None)
@@ -195,8 +166,8 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     prog_id = prog_id[:n_ref]
     gc.collect()
     t_ref = time.perf_counter()
-    ref = dsgd_ref.fit(u, i, r, cfg, min(n_ref, sweeps))
-    ref_rmse = [float(dsgd_ref.holdout_rmse(U, V, *ref["seen"], hu, hi, hr))
+    ref = reference.fit(u, i, r, cfg, min(n_ref, sweeps))
+    ref_rmse = [float(holdout_rmse(U, V, *ref["seen"], hu, hi, hr))
                 for U, V in ref["sweeps"]]
     numbers = compare.fit_numbers(prog_id, rmse, ref, ref_rmse)
     print(f"fit: reference {time.perf_counter() - t_ref:.1f}s, its RMSE "
@@ -209,16 +180,16 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
         "train_ratings_per_s": n_train * sweeps / wall,
         "setup_s": window.setup_s,
     }
+    sizes = {"nnz_train": n_train, "num_users": cfg["num_users"],
+             "num_items": cfg["num_items"], "rank": cfg["num_factors"],
+             **solver.sizes(cfg)}
     ctx = {
         "trace": reduced, "chips": cell.chips, "window_s": wall,
         "peaks": harness.peaks_for(device),
         "series": {}, "counters": {
             "sweeps_to_target": None if hit is None else hit + 1,
             "sweeps_done": sweeps},
-        "sizes": {"nnz_train": n_train, "num_users": cfg["num_users"],
-                  "num_items": cfg["num_items"],
-                  "rank": cfg["num_factors"],
-                  "num_blocks": cfg["num_blocks"]},
+        "sizes": sizes, "sweep_flops": solver.sweep_flops(sizes),
     }
     return {"correct": correct and window.compiles.count == 0,
             "compared": compared, "attempted": 1,
@@ -230,4 +201,4 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
             "notes": {"sweeps": sweeps, "window_cut": cut,
                       "holdout_rmse": rmse, "sweep_ends_s": ends,
                       "per_device_peak_bytes": per_device_peak,
-                      "bmax": ref["bmax"]}}
+                      **ref.get("notes", {})}}

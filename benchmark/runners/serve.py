@@ -6,7 +6,9 @@
 arrive on a schedule drawn from the seed, are submitted at their scheduled
 time (``ServingEngine.submit``) and flushed (``ServingEngine.flush``) when
 ``flush_rows`` rows are pending or the oldest has waited ``deadline_ms``;
-latency runs from the scheduled arrival.
+latency runs from the scheduled arrival. The answers are compared with the
+plain reference the configuration names, ``reference/<reference>.py``
+(``topk_ref`` where it names none).
 """
 
 from __future__ import annotations
@@ -17,7 +19,8 @@ import time
 import numpy as np
 
 from benchmark import compare, datagen, harness, loadgen
-from benchmark.reference import topk_ref
+
+REFERENCE = "topk_ref"  # of a configuration that names none
 
 
 def build_engine(cfg: dict, U, V):
@@ -95,6 +98,7 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
     cfg, traffic = cell.config, cell.traffic
     on_chip = device["platform"] == "tpu"
     nu, ni, rank = cfg["num_users"], cfg["num_items"], cfg["num_factors"]
+    reference = harness.reference_for(cell, REFERENCE)
 
     # -- set-up: factors on the device from the seed, engine, warm buckets
     U, V = datagen.serving_factors(seed, num_users=nu, num_items=ni,
@@ -178,8 +182,8 @@ def run(cell, seed: int, seconds: float, trace: bool, device: dict,
         raise SystemExit("serve: the window finished no request")
     s_users, s_ids, s_scores = sample
     if control == "int8":
-        s_ids, s_scores = topk_ref.int8_answers(U, V, s_users, cfg["k"])
-    ref_top, _, ref_at, ref_std = topk_ref.exact_topk(
+        s_ids, s_scores = reference.int8_answers(U, V, s_users, cfg["k"])
+    ref_top, _, ref_at, ref_std = reference.exact_topk(
         U, V, s_users, s_ids, cfg["k"])
     numbers = compare.topk_numbers(s_ids, s_scores, ref_top, ref_at,
                                    ref_std)

@@ -3,10 +3,12 @@ cell's own size, on several seeds in one process (set-up is long):
 
     python3 benchmark/tools/limits_fit.py --workload netflix100m-r128.fit --seeds 1,2,3
 
-Per seed it makes the data, runs the reference, then puts in the program's
-place (a) the program with ``factor_dtype="bfloat16"`` (the control: the
-nearest precision below the float32 the configuration states, a path the
-program has), (b) the reference with half of every minibatch left out, and
+The solver and the reference are the files the configuration names
+(``runners/solvers/<solver>.py``, ``reference/<reference>.py``). Per seed it
+makes the data, runs the reference, then puts in the program's place (a) the
+program under the solver's ``bf16`` control (``factor_dtype="bfloat16"``:
+the nearest precision below the float32 the configuration states, a path
+the program has), (b) the reference with half of every minibatch left out, and
 prints each compared number. A state left unchanged reads 1 by the measure
 and needs no run. The limits in the configuration file were set from these
 readings and from the runs' own (PERF.md, section 2).
@@ -33,11 +35,13 @@ def main() -> int:
     args = ap.parse_args()
 
     from benchmark import compare, datagen, harness
-    from benchmark.reference import dsgd_ref
     from benchmark.runners import fit as fit_runner
     from benchmark.spans import Spans
 
     cell = harness.resolve_cell(args.workload)
+    solver = fit_runner.solver_for(cell)
+    reference = harness.reference_for(cell, fit_runner.REFERENCE)
+    holdout_rmse = fit_runner.holdout_rmse
     harness.start_on_chip(cell.chips)
     cfg = cell.config
     n = args.sweeps or int(cell.traffic["reference_sweeps"])
@@ -46,13 +50,12 @@ def main() -> int:
             seed, num_users=cfg["num_users"], num_items=cfg["num_items"],
             nnz=cfg["nnz"], rank=cfg["planted_rank"], noise=cfg["noise"],
             skew_lam=cfg["skew_lam"])
-        ref = dsgd_ref.fit(u, i, r, cfg, n)
-        ref_rmse = [float(dsgd_ref.holdout_rmse(U, V, *ref["seen"],
-                                                hu, hi, hr))
+        ref = reference.fit(u, i, r, cfg, n)
+        ref_rmse = [float(holdout_rmse(U, V, *ref["seen"], hu, hi, hr))
                     for U, V in ref["sweeps"]]
 
         def report(kind, tables, seen):
-            rmse = [float(dsgd_ref.holdout_rmse(U, V, *seen, hu, hi, hr))
+            rmse = [float(holdout_rmse(U, V, *seen, hu, hi, hr))
                     for U, V in tables]
             numbers = compare.fit_numbers(tables, rmse, ref, ref_rmse)
             print(kind, json.dumps({"seed": seed, "rmse": rmse,
@@ -60,14 +63,15 @@ def main() -> int:
                   flush=True)
 
         stamps = fit_runner.SegmentStamps(Spans())
-        model = fit_runner.make_fit(cfg, n, stamps, cell.chips,
-                                    factor_dtype="bfloat16")(u, i, r)
+        model = solver.make_fit(
+            cfg, n, stamps, cell.chips,
+            **fit_runner.control_overrides(solver, "bf16"))(u, i, r)
         tables, seen = fit_runner.id_space(
             model, stamps.tables, cfg["num_users"], cfg["num_items"])
         report("control_bf16", tables, seen)
         del model, stamps, tables
         gc.collect()
-        fault = dsgd_ref.fit(u, i, r, cfg, n, fault="half_batch")
+        fault = reference.fit(u, i, r, cfg, n, fault="half_batch")
         report("fault_half_batch", fault["sweeps"], fault["seen"])
         del ref, fault, u, i, r, hu, hi, hr
         gc.collect()

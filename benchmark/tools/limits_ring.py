@@ -53,25 +53,27 @@ def exchange_left_out():
         dsgd_mesh._build_mesh_dsgd_step.cache_clear()
 
 
-def program_tables(cell, data, holdout, n: int, **overrides):
-    """``n`` one-sweep segments of the cell's program: the tables after
-    each in id space (on the host) and their holdout RMSEs."""
+def program_tables(cell, data, holdout, n: int, control=None):
+    """``n`` one-sweep segments of the cell's program (under one of its
+    solver's controls, if named): the tables after each in id space (on
+    the host) and their holdout RMSEs."""
     import numpy as np
 
-    from benchmark.reference import dsgd_ref
     from benchmark.runners import fit as fit_runner
     from benchmark.spans import Spans
 
     cfg = cell.config
+    solver = fit_runner.solver_for(cell)
     stamps = fit_runner.SegmentStamps(Spans())
-    model = fit_runner.make_fit(cfg, n, stamps, cell.chips,
-                                **overrides)(*data)
+    model = solver.make_fit(
+        cfg, n, stamps, cell.chips,
+        **fit_runner.control_overrides(solver, control))(*data)
     tables = stamps.tables
     if cell.chips > 1:
         tables = fit_runner.gather_to_one_device(tables)
     tables, seen = fit_runner.id_space(model, tables, cfg["num_users"],
                                        cfg["num_items"])
-    rmse = [float(dsgd_ref.holdout_rmse(U, V, *seen, *holdout))
+    rmse = [float(fit_runner.holdout_rmse(U, V, *seen, *holdout))
             for U, V in tables]
     return [tuple(np.asarray(x) for x in t) for t in tables], rmse
 
@@ -81,10 +83,12 @@ def readings(cell, seed: int, n: int, kinds=KINDS):
     their limits, the verdict, the RMSEs."""
     import jax.numpy as jnp
 
-    from benchmark import compare, datagen
-    from benchmark.reference import dsgd_ref
+    from benchmark import compare, datagen, harness
+    from benchmark.runners import fit as fit_runner
 
     cfg = cell.config
+    reference = harness.reference_for(cell, fit_runner.REFERENCE)
+    holdout_rmse = fit_runner.holdout_rmse
     data, holdout = datagen.planted_ratings(
         seed, num_users=cfg["num_users"], num_items=cfg["num_items"],
         nnz=cfg["nnz"], rank=cfg["planted_rank"], noise=cfg["noise"],
@@ -96,10 +100,10 @@ def readings(cell, seed: int, n: int, kinds=KINDS):
         gc.collect()
     if "control_bf16" in kinds:
         got["control_bf16"] = program_tables(cell, data, holdout, n,
-                                             factor_dtype="bfloat16")
+                                             control="bf16")
         gc.collect()
-    ref = dsgd_ref.fit(*data, cfg, n)
-    ref_rmse = [float(dsgd_ref.holdout_rmse(U, V, *ref["seen"], *holdout))
+    ref = reference.fit(*data, cfg, n)
+    ref_rmse = [float(holdout_rmse(U, V, *ref["seen"], *holdout))
                 for U, V in ref["sweeps"]]
 
     def judged(kind, tables, rmse):
@@ -113,8 +117,8 @@ def readings(cell, seed: int, n: int, kinds=KINDS):
         yield judged(kind, [tuple(jnp.asarray(x) for x in t)
                             for t in tables], rmse)
     if "fault_half_batch" in kinds:
-        fault = dsgd_ref.fit(*data, cfg, n, fault="half_batch")
-        rmse = [float(dsgd_ref.holdout_rmse(U, V, *fault["seen"], *holdout))
+        fault = reference.fit(*data, cfg, n, fault="half_batch")
+        rmse = [float(holdout_rmse(U, V, *fault["seen"], *holdout))
                 for U, V in fault["sweeps"]]
         yield judged("fault_half_batch", fault["sweeps"], rmse)
 

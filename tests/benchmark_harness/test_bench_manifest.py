@@ -3,6 +3,7 @@ shape rules, so that a later PR that adds a cell as files finds the harness
 ready for it."""
 
 import ast
+import inspect
 import json
 import os
 import re
@@ -11,6 +12,7 @@ import pytest
 
 import bench_testlib
 from benchmark import harness, readers
+from benchmark.runners import fit as fit_runner
 
 ROOT = bench_testlib.ROOT
 MANIFEST = harness.load_manifest()
@@ -42,8 +44,19 @@ def test_top_level_keys_and_command():
 @pytest.mark.parametrize("cell", CELLS + [RING])
 def test_cell_resolves_to_files_that_exist(cell):
     c = harness.resolve_cell(cell)
-    assert c.traffic["runner"] in ("fit", "serve_closed", "serve_open")
+    runner = harness.runner_for(c)  # runners/<kind>.py, and it has run()
     assert c.traffic["runner"] in c.config["runner_kinds"]
+    if c.traffic["runner"] == "fit":
+        solver = fit_runner.solver_for(c)
+        for name in ("make_fit", "sizes", "sweep_flops"):
+            assert callable(getattr(solver, name)), name
+        assert isinstance(solver.CONTROLS, dict)
+    default = getattr(inspect.getmodule(runner.run), "REFERENCE", None)
+    assert default or "reference" in c.config
+    reference = harness.reference_for(c, default)
+    assert os.path.dirname(reference.__file__) == os.path.join(
+        ROOT, "benchmark", "reference")
+    assert c.config["toy"], "no toy sizes for the CPU rehearsal"
     assert c.config["limits"], "the comparison has no limit to hold"
     assert {m["name"] for m in c.end_to_end} >= {"setup_s"}
     assert len(c.end_to_end) >= 2 and len(c.per_layer) >= 1

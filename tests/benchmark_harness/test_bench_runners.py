@@ -120,7 +120,8 @@ def test_bulk_rehearsal_is_correct_and_counts():
     assert line["correct"] is True and line["failed"] == 0
     assert set(line["metrics"]) == {"serve_users_per_s", "setup_s"}
     users = out["ctx"]["counters"]["users_answered"]
-    assert users == line["attempted"] * 1024
+    per_request = bench_testlib.toy_cell(BULK).traffic["request_users"]["fixed"]
+    assert users == line["attempted"] * per_request
     assert set(out["ctx"]["series"]["bucket_rows"]) == {256}
     assert out["compiles_in_window"] == 0
 

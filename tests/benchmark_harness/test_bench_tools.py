@@ -8,7 +8,7 @@ import numpy as np
 
 import bench_testlib  # noqa: F401  (puts the checkout on sys.path)
 from benchmark import loadgen
-from benchmark.tools import stall_watch
+from benchmark.tools import bulk_probe, stall_watch
 
 
 class SleepyEngine:
@@ -74,3 +74,20 @@ def test_gc_pauses_are_recorded_by_generation():
         gc.collect()
     assert pauses.pauses and pauses.pauses[-1][1] == 2
     assert pauses not in gc.callbacks
+
+
+def test_bulk_probe_counts_the_flushes_the_machine_stood_still_in():
+    wall = [147.0] * 40 + [147.4, 146.6, 230.0, 260.5]
+    got = bulk_probe.flush_summary(wall)
+    assert got["flushes"] == 44 and got["flush_p50_ms"] == 147.0
+    assert got["slow_flushes"] == 2 and got["slow_ms"] == [260.5, 230.0]
+    assert abs(got["slow_excess_ms"] - (83.0 + 113.5)) < 1e-9
+    assert abs(got["flush_sum_s"] * 1e3 - sum(wall)) < 1e-6
+
+
+def test_bulk_probe_reads_the_kernels_counters_where_there_are_any():
+    before = bulk_probe.kernel_counters()
+    sum(i * i for i in range(200_000))
+    after = bulk_probe.kernel_counters()
+    assert after["process.user_s"] >= before["process.user_s"]
+    assert all(isinstance(v, float) for v in after.values())
