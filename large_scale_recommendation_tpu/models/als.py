@@ -12,6 +12,13 @@ lists to power-of-2 buckets, so gram assembly is batched ``[rows, pad, k]``
 einsums and the solve is batched Cholesky — all MXU work, no scatter in the
 hot path (the ALX-style formulation, see PAPERS.md) rather than MLlib's
 block-routed LAPACK calls.
+
+Precision: float32 tables, and with ``gram_dtype=None`` the Gram matrices,
+right-hand sides and solves are float32 on a TPU as on a CPU — the
+contractions name ``Precision.HIGHEST`` (``ops.als.contraction_precision``),
+since a TPU's default multiplies float32 inputs in bfloat16.
+``gram_dtype="bf16"`` is the reduced path: bfloat16 gather and products,
+float32 accumulation and solve.
 """
 
 from __future__ import annotations
@@ -46,10 +53,12 @@ class ALSConfig:
     # iALS (≙ MLlib ALS.trainImplicit; the BASELINE Criteo-implicit config):
     # treat ratings as interaction strengths with confidence 1 + α·r
     implicit_alpha: float | None = None
-    # "bf16" halves the bytes of the hot-path fixed-side row gather (the
-    # measured ALS bottleneck, docs/PERF.md) and feeds the gram einsums
-    # native-MXU bf16 inputs; accumulation + solve stay f32 (ops.als).
-    # None = full f32 (the default; exact MLlib-style numerics).
+    # "bf16" halves the bytes of the hot-path fixed-side row gather and
+    # feeds the gram einsums native-MXU bf16 inputs; accumulation + solve
+    # stay f32 (ops.als). None = full f32 (the default; MLlib-style
+    # numerics): float32 products on a TPU too, at six bfloat16 passes a
+    # contraction (PERF.md at the root, Findings, PR 29, has both readings
+    # against the float32 reference and what the passes cost).
     gram_dtype: str | None = None
 
 
@@ -62,7 +71,8 @@ class ALS:
         # quality hook (obs.quality.OnlineEvaluator, same contract as
         # DSGD.evaluator): an attached evaluator with a row-space
         # holdout armed scores the fitted tables at the fit boundary
-        # (ALS runs one jitted segment). None = one pointer test.
+        # (``fit_device``: at every segment's end). None = one pointer
+        # test.
         self.evaluator = None
 
     def fit(self, ratings: Ratings) -> MFModel:
@@ -121,6 +131,7 @@ class ALS:
         r,
         num_users: int,
         num_items: int,
+        checkpoint_every: int | None = None,
     ) -> MFModel:
         """Fit via device-built solve plans (``ops.als.device_prepare_side``).
 
@@ -130,12 +141,25 @@ class ALS:
         chip, so the host never materializes the padded bucket expansion
         and only two ≤33-int size vectors cross the host↔device link.
         Arbitrary external ids go through ``fit`` (host planning).
+
+        ``checkpoint_every`` has the meaning it has in ``DSGD.fit_device``:
+        the iterations run in segments of that many sweeps, and at each
+        segment's end ``evaluator.on_segment(U, V, label=..., step=done)``
+        sees that segment's tables (a fit paid for by its time to a stated
+        quality is observable and stoppable at sweep ends). ``als_rounds``
+        is a Python loop over jitted half-steps, so k segments of one sweep
+        give bit for bit the tables of one segment of k sweeps. None = one
+        segment.
         """
         import jax.numpy as jnp
 
         from large_scale_recommendation_tpu.data.device_blocking import (
             validate_dense_ids,
         )
+        from large_scale_recommendation_tpu.obs.instrument import (
+            TrainSegmentTimer,
+        )
+        from large_scale_recommendation_tpu.obs.trace import get_tracer
 
         cfg = self.config
         # config/input validation first: the device plan build is the
@@ -148,49 +172,61 @@ class ALS:
         u = jnp.asarray(u, jnp.int32)
         i = jnp.asarray(i, jnp.int32)
         r = jnp.asarray(r, jnp.float32)
-
-        omega_u = jnp.zeros(num_users, jnp.int32).at[u].add(1)
-        omega_v = jnp.zeros(num_items, jnp.int32).at[i].add(1)
-        omu = (omega_u.astype(jnp.float32)
-               if cfg.reg_mode == "als_wr" else None)
-        omv = (omega_v.astype(jnp.float32)
-               if cfg.reg_mode == "als_wr" else None)
         k = cfg.num_factors
-        prep_u = als_ops.device_prepare_side(
-            u, i, r, num_users, omega=omu, min_pad=cfg.min_pad,
-            rank_for_chunking=k)
-        prep_v = als_ops.device_prepare_side(
-            i, u, r, num_items, omega=omv, min_pad=cfg.min_pad,
-            rank_for_chunking=k)
-        if cfg.implicit_alpha is not None:
-            prep_u = als_ops.implicit_prepared(prep_u, cfg.implicit_alpha)
-            prep_v = als_ops.implicit_prepared(prep_v, cfg.implicit_alpha)
+        seam = get_tracer().seam
 
-        init = PseudoRandomFactorInitializer(k, scale=cfg.init_scale)
-        # zero the unseen-id rows, matching the host path's zeroed padding
-        # rows: the implicit VᵀV term sums the WHOLE table, and the first
-        # half-step reads V's init directly (see _init_factors). Only V's
-        # init matters mathematically — the first half-step solves U.
-        V = init(np.arange(num_items, dtype=np.int32)) \
-            * (omega_v > 0)[:, None]
+        # both sides' plans, their class-size read-backs included
+        with seam("fit/als/plan"):
+            omega_u = jnp.zeros(num_users, jnp.int32).at[u].add(1)
+            omega_v = jnp.zeros(num_items, jnp.int32).at[i].add(1)
+            omu = (omega_u.astype(jnp.float32)
+                   if cfg.reg_mode == "als_wr" else None)
+            omv = (omega_v.astype(jnp.float32)
+                   if cfg.reg_mode == "als_wr" else None)
+            prep_u = als_ops.device_prepare_side(
+                u, i, r, num_users, omega=omu, min_pad=cfg.min_pad,
+                rank_for_chunking=k)
+            prep_v = als_ops.device_prepare_side(
+                i, u, r, num_items, omega=omv, min_pad=cfg.min_pad,
+                rank_for_chunking=k)
+            if cfg.implicit_alpha is not None:
+                prep_u = als_ops.implicit_prepared(prep_u,
+                                                   cfg.implicit_alpha)
+                prep_v = als_ops.implicit_prepared(prep_v,
+                                                   cfg.implicit_alpha)
+            n_ratings = int(np.shape(u)[0])
+            als_ops.publish_plan_sizes("user", prep_u, n_ratings)
+            als_ops.publish_plan_sizes("item", prep_v, n_ratings)
 
-        from large_scale_recommendation_tpu.obs.instrument import (
-            TrainSegmentTimer,
-        )
+        with seam("fit/als/init"):
+            init = PseudoRandomFactorInitializer(k, scale=cfg.init_scale)
+            # zero the unseen-id rows, matching the host path's zeroed
+            # padding rows: the implicit VᵀV term sums the WHOLE table, and
+            # the first half-step reads V's init directly (see
+            # _init_factors). Only V's init matters mathematically — the
+            # first half-step solves U.
+            V = init(np.arange(num_items, dtype=np.int32)) \
+                * (omega_v > 0)[:, None]
 
+        kind = "als_device_rounds"
         timer = TrainSegmentTimer(
-            "als", "als_device_rounds",
-            shape_key=((num_users, k), tuple(np.shape(V))))
-        with timer.segment(cfg.iterations) as h:
-            U, V = als_ops.als_rounds(
-                V, prep_u, prep_v, num_users, num_items, cfg.lambda_,
-                cfg.iterations, implicit=cfg.implicit_alpha is not None,
-                gram_dtype=gram_dtype)
-            h.out = (U, V)
-        timer.finish(int(np.shape(u)[0]))
-        if self.evaluator is not None:
-            self.evaluator.on_segment(U, V, label="als_device_rounds",
-                                      step=cfg.iterations)
+            "als", kind, shape_key=((num_users, k), tuple(np.shape(V))))
+        done = 0
+        segment = checkpoint_every or cfg.iterations
+        while done < cfg.iterations:
+            seg = min(segment, cfg.iterations - done)
+            with timer.segment(seg) as h:
+                U, V = als_ops.als_rounds(
+                    V, prep_u, prep_v, num_users, num_items, cfg.lambda_,
+                    seg, implicit=cfg.implicit_alpha is not None,
+                    gram_dtype=gram_dtype)
+                h.out = (U, V)
+            done += seg
+            # the host's time between sweeps
+            with seam("fit/als/after_segment"):
+                if self.evaluator is not None:
+                    self.evaluator.on_segment(U, V, label=kind, step=done)
+        timer.finish(n_ratings)
 
         # dense-vocab IdIndex pair with host-path semantics (ids unseen in
         # training stay unknown → predict 0, dropped from risk)

@@ -87,6 +87,7 @@ SEAMS = frozenset({
     "fit/dsgd/init", "fit/mesh_dsgd/init", "fit/mesh/place",
     "fit/dsgd/segment", "fit/mesh_dsgd/segment", "fit/als/segment",
     "fit/dsgd/after_segment", "fit/mesh_dsgd/after_segment",
+    "fit/als/plan", "fit/als/init", "fit/als/after_segment",
 })
 
 # span sequence numbers are PROCESS-unique (module-level, not

@@ -145,10 +145,16 @@ def _solve_bucket(
     def body(out, x):
         rows_c, oi, va, wi, sc = x
         x_c = _gram_solve_chunk(factors, oi, va, wi, sc, lambda_, G)
-        return out.at[rows_c].set(x_c, unique_indices=True), None
+        return _write_rows(out, rows_c, x_c), None
 
     out, _ = jax.lax.scan(body, out, (rows3, oidx3, vals3, w3, scale3))
     return out
+
+
+def _write_rows(out, rows_c, x_c):
+    """Set one chunk's solved rows (unique by construction)."""
+    with jax.named_scope("als/write"):
+        return out.at[rows_c].set(x_c, unique_indices=True)
 
 
 def _gram_solve_chunk(factors, oi, va, wi, sc, lambda_, G=None):
@@ -162,19 +168,41 @@ def _gram_solve_chunk(factors, oi, va, wi, sc, lambda_, G=None):
     (``solve_side(dtype=...)``) the latency-bound row gather moves half
     the bytes and the contractions are native-MXU bf16×bf16, while both
     einsums still ACCUMULATE in f32 (``preferred_element_type``) and the
-    normal-equation solve itself stays f32 end to end."""
-    g = factors[oi]
-    gw = g * wi[..., None].astype(g.dtype)
-    A = jnp.einsum("rpk,rpl->rkl", gw, g,
-                   preferred_element_type=jnp.float32)
-    if G is not None:
-        A = A + G
-    # b uses the RAW gathered rows: ``va`` is the per-entry b-weight
-    # (explicit: the already-masked rating, so Σ w·r·v as before;
-    # implicit: the masked confidence c = 1+α·r)
-    b = jnp.einsum("rpk,rp->rk", g, va.astype(g.dtype),
-                   preferred_element_type=jnp.float32)
-    return solve_normal_eq(A, b, lambda_, sc)
+    normal-equation solve itself stays f32 end to end. With a float32
+    table the contractions are float32 on every backend
+    (``contraction_precision``)."""
+    # the scopes are HLO metadata only (a device trace names each
+    # operation after its scope)
+    with jax.named_scope("als/gather"):
+        g = factors[oi]
+    with jax.named_scope("als/gram"):
+        precision = contraction_precision(g.dtype)
+        gw = g * wi[..., None].astype(g.dtype)
+        A = jnp.einsum("rpk,rpl->rkl", gw, g, precision=precision,
+                       preferred_element_type=jnp.float32)
+        if G is not None:
+            A = A + G
+        # b uses the RAW gathered rows: ``va`` is the per-entry b-weight
+        # (explicit: the already-masked rating, so Σ w·r·v as before;
+        # implicit: the masked confidence c = 1+α·r)
+        b = jnp.einsum("rpk,rp->rk", g, va.astype(g.dtype),
+                       precision=precision,
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("als/solve"):
+        return solve_normal_eq(A, b, lambda_, sc)
+
+
+def contraction_precision(dtype):
+    """The precision of a Gram or right-hand-side contraction whose inputs
+    have ``dtype``. On a TPU a float32 contraction at the default precision
+    multiplies in reduced precision: against a float32 reference the tables
+    then differ by 1.3e-3 of their change after two sweeps, where the
+    ``gram_dtype="bf16"`` path differs by 4.1e-3 and ``HIGHEST`` by 4e-7,
+    at no cost in time that shows (PERF.md, Findings, PR 29). So float32
+    inputs ask for ``HIGHEST`` (float32 products, six bfloat16 passes on
+    the MXU); bfloat16 inputs are the reduced path and are exact in one
+    pass."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _chunk_geometry(nb: int, pad: int, k: int,
@@ -246,42 +274,80 @@ def prepare_side(plan: SolvePlan, omega: np.ndarray | None, k: int,
 
 
 @partial(jax.jit, static_argnames=("num_out_rows", "n_pow2"))
-def _device_plan_keys(out_rows, other_rows, num_out_rows: int, n_pow2: int):
-    """Per-row counts, pad classes, and the two sort orders the device plan
-    build needs. Returns device arrays + the tiny per-class row-count vector
-    that gets read back to fix static shapes."""
-    counts = jnp.zeros(num_out_rows, jnp.int32).at[out_rows].add(1)
-    pow2s = jnp.int32(2) ** jnp.arange(n_pow2, dtype=jnp.int32)
-    # smallest pow2 ≥ count, exact integer logic (no float log2 edge cases);
-    # empty rows get a trailing pseudo-class that is sliced off
-    pclass = jnp.searchsorted(pow2s, counts, side="left").astype(jnp.int32)
-    pclass = jnp.where(counts == 0, n_pow2, pclass)
-    row_order = jnp.argsort(pclass, stable=True)  # rows grouped by class
-    rows_per_class = jnp.zeros(n_pow2 + 1, jnp.int32).at[pclass].add(1)
-    # lexsort by (out_row, other_row) as two stable passes (no 64-bit
-    # composite keys — int64 is emulated on TPU): row-contiguous runs with
-    # ascending partner indices inside each run, the same gather-locality
-    # lever as the host plan's np.lexsort (see build_solve_plan).
-    o1 = jnp.argsort(other_rows, stable=True)
-    entry_order = o1[jnp.argsort(out_rows[o1], stable=True)]
-    starts = jnp.cumsum(counts) - counts
-    return counts, row_order, rows_per_class, entry_order, starts
+def _device_plan_keys(out_rows, other_rows, values, num_out_rows: int,
+                      n_pow2: int):
+    """The two sorts the device plan build needs, each carrying its payload
+    (``lax.sort`` with several operands: nothing is gathered through an
+    order afterwards). Returns the rows grouped by pad class with each
+    row's rating count and first entry beside it, the tiny per-class
+    row-count vector that gets read back to fix static shapes, and the
+    partner indices and values in solve order."""
+    with jax.named_scope("plan/sort"):
+        counts = jnp.zeros(num_out_rows, jnp.int32).at[out_rows].add(1)
+        pow2s = jnp.int32(2) ** jnp.arange(n_pow2, dtype=jnp.int32)
+        # smallest pow2 ≥ count, exact integer logic (no float log2 edge
+        # cases); empty rows get a trailing pseudo-class that is sliced off
+        pclass = jnp.searchsorted(pow2s, counts,
+                                  side="left").astype(jnp.int32)
+        pclass = jnp.where(counts == 0, n_pow2, pclass)
+        starts = jnp.cumsum(counts) - counts
+        # rows grouped by class, ascending inside one
+        _, row_order, counts_o, starts_o = jax.lax.sort(
+            (pclass, jnp.arange(num_out_rows, dtype=jnp.int32), counts,
+             starts), num_keys=1, is_stable=True)
+        rows_per_class = jnp.zeros(n_pow2 + 1, jnp.int32).at[pclass].add(1)
+        # lexsort by (out_row, other_row), stable: row-contiguous runs
+        # with ascending partner indices inside each run, the same
+        # gather-locality lever as the host plan's np.lexsort (see
+        # build_solve_plan)
+        _, o_sorted, v_sorted = jax.lax.sort(
+            (out_rows, other_rows, values), num_keys=2, is_stable=True)
+        return (row_order, counts_o, starts_o, rows_per_class, o_sorted,
+                v_sorted)
 
 
-@partial(jax.jit, static_argnames=("pad", "offset", "nb"))
-def _device_bucket(row_order, counts, starts, o_sorted, v_sorted,
-                   pad: int, offset: int, nb: int):
-    """Materialize one pad-class bucket [nb, pad] on device (≙ the
-    where/clip gather in build_solve_plan, host path)."""
-    rows = jax.lax.dynamic_slice(row_order, (offset,), (nb,))
-    pos = starts[rows][:, None] + jnp.arange(pad, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(pad, dtype=jnp.int32)[None, :] < counts[rows][:, None]
-    e = o_sorted.shape[0]
-    pos = jnp.clip(pos, 0, max(e - 1, 0))
-    oidx = jnp.where(valid, o_sorted[pos], 0).astype(jnp.int32)
-    vals = jnp.where(valid, v_sorted[pos], 0.0).astype(jnp.float32)
-    w = valid.astype(jnp.float32)
-    return rows.astype(jnp.int32), oidx, vals, w
+@partial(jax.jit, static_argnames=("pad", "rc", "n_chunks", "num_rows"))
+def _device_bucket(row_order, counts_o, starts_o, o_sorted, v_sorted, offset,
+                   nb, pad: int, rc: int, n_chunks: int, num_rows: int):
+    """Materialize one pad-class bucket on device, in whole chunks:
+    ``[n_chunks * rc]`` rows and ``[n_chunks * rc, pad]`` slots (≙ the
+    where/clip gather in build_solve_plan, host path, plus the chunk
+    padding of ``_chunked_bucket``: rows past the class's ``nb`` point at
+    the dummy row ``num_rows`` with weight 0).
+
+    Block copies, no per-slot gather: the class is one run of
+    ``row_order`` and a row's ratings are one run of the sorted entries,
+    so a row's slots are ONE window of ``pad`` entries, masked past the
+    row's count. (Gathering each slot by its own index took 14 of the
+    plan's 27.5 s at 95.5M ratings, and its time moved by 15% from run to
+    run; the windows of both sides take 1.6 s, the same to a millisecond
+    every time: PERF.md, Findings, PR 29.) The program is specialised on
+    the pad class and the chunk geometry only: ``offset`` and ``nb`` are
+    traced.
+    ``o_sorted`` and ``v_sorted`` arrive padded by at least ``pad`` entries
+    (a window may reach past the last rating: pad, don't clamp)."""
+    with jax.named_scope("plan/bucket"):
+        n = n_chunks * rc
+        real = jnp.arange(n, dtype=jnp.int32) < nb
+
+        def run(a, fill):
+            return jnp.where(
+                real,
+                jax.lax.dynamic_slice(jnp.pad(a, (0, n)), (offset,), (n,)),
+                fill)
+
+        first = run(starts_o, 0)
+        valid = (jnp.arange(pad, dtype=jnp.int32)[None, :]
+                 < run(counts_o, 0)[:, None])
+
+        def windows(a):
+            return jax.vmap(
+                lambda s: jax.lax.dynamic_slice(a, (s,), (pad,)))(first)
+
+        return (run(row_order, num_rows),
+                jnp.where(valid, windows(o_sorted), 0),
+                jnp.where(valid, windows(v_sorted), 0.0),
+                valid.astype(jnp.float32))
 
 
 def device_prepare_side(
@@ -312,10 +378,8 @@ def device_prepare_side(
     values = jnp.asarray(values, jnp.float32)
     k = rank_for_chunking or 256
     n_pow2 = 31
-    counts, row_order, rows_per_class, entry_order, starts = \
-        _device_plan_keys(out_rows, other_rows, num_out_rows, n_pow2)
-    o_sorted = other_rows[entry_order]
-    v_sorted = values[entry_order]
+    row_order, counts_o, starts_o, rows_per_class, o_sorted, v_sorted = \
+        _device_plan_keys(out_rows, other_rows, values, num_out_rows, n_pow2)
 
     rpc = np.asarray(rows_per_class)  # the tiny readback
     offsets = np.concatenate([[0], np.cumsum(rpc)])
@@ -330,16 +394,41 @@ def device_prepare_side(
     groups = [(min_pad, 0, int(rpc[: m + 1].sum()))]
     groups += [(1 << cls, int(offsets[cls]), int(rpc[cls]))
                for cls in range(m + 1, n_pow2)]
+    # classes no row falls in go; the trailing one (empty rows) never came
+    groups = [g for g in groups if g[2]]
     om = None if omega is None else jnp.asarray(omega, jnp.float32)
+    # room for the widest class's window at the last rating
+    widest = max((pad for pad, _, _ in groups), default=0)
+    o_sorted = jnp.pad(o_sorted, (0, widest))
+    v_sorted = jnp.pad(v_sorted, (0, widest))
     prepared = []
-    for pad, offset, nb in groups:  # trailing class (empty rows) excluded
-        if nb == 0:
-            continue
-        bucket = _device_bucket(row_order, counts, starts, o_sorted,
-                                v_sorted, pad, offset, nb)
+    for pad, offset, nb in groups:
+        rc, n_chunks, _ = _chunk_geometry(nb, pad, k, target_bytes)
+        bucket = _device_bucket(row_order, counts_o, starts_o, o_sorted,
+                                v_sorted, jnp.int32(offset), jnp.int32(nb),
+                                pad, rc, n_chunks, num_out_rows)
+        # already whole chunks: _chunked_bucket finds nothing to pad
         prepared.append(_chunked_bucket(bucket, om, num_out_rows, k,
                                         target_bytes))
     return tuple(prepared)
+
+
+def publish_plan_sizes(side: str, prepared, n_ratings: int) -> None:
+    """One side's plan on the registry (``obs.enable()``; nothing
+    otherwise): its real ratings, its padded slots (their ratio is what a
+    plan change moves: every padded slot is a row gathered and a Gram term
+    computed), its buckets and its chunks."""
+    from large_scale_recommendation_tpu.obs.registry import get_registry
+
+    obs = get_registry()
+    if not obs.enabled:
+        return
+    obs.gauge("als_plan_ratings", side=side).set(int(n_ratings))
+    obs.gauge("als_plan_padded_slots", side=side).set(
+        sum(int(np.prod(b[1].shape)) for b in prepared))
+    obs.gauge("als_plan_buckets", side=side).set(len(prepared))
+    obs.gauge("als_plan_chunks", side=side).set(
+        sum(int(b[1].shape[0]) for b in prepared))
 
 
 @jax.jit
@@ -499,7 +588,7 @@ def solve_side_local(
             rows_c, oi, va, wi = x
             sc = None if omega_ext is None else omega_ext[rows_c]
             x_c = _gram_solve_chunk(factors_full, oi, va, wi, sc, lambda_, G)
-            return out.at[rows_c].set(x_c, unique_indices=True), None
+            return _write_rows(out, rows_c, x_c), None
 
         out, _ = jax.lax.scan(body, out, (rows3, oidx3, vals3, w3))
     return out[:rows_per_shard]
@@ -508,6 +597,7 @@ def solve_side_local(
 @jax.jit
 def _full_gram(F):
     return jnp.einsum("nk,nl->kl", F, F,
+                      precision=contraction_precision(F.dtype),
                       preferred_element_type=jnp.float32)
 
 

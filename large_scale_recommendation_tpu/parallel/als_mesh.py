@@ -134,7 +134,9 @@ def _build_mesh_als_step(
             # psum over 'model' (the ISSUE 16 reduction collective; rows
             # are zero-padded to a multiple of m, and zero rows contribute
             # exactly nothing to FᵀF, so the only deviation from the
-            # replicated result is fp reduction reordering).
+            # replicated result is fp reduction reordering). float32 on a
+            # TPU too, as the single-chip ``_full_gram``.
+            precision = als_ops.contraction_precision(F.dtype)
             if rank_sharded:
                 n = F.shape[0]
                 n_pad = -(-n // m) * m
@@ -142,10 +144,10 @@ def _build_mesh_als_step(
                 chunk = n_pad // m
                 Fc = jax.lax.dynamic_slice_in_dim(
                     Fp, jax.lax.axis_index(model_axis) * chunk, chunk, 0)
-                G = jnp.einsum("nk,nl->kl", Fc, Fc,
+                G = jnp.einsum("nk,nl->kl", Fc, Fc, precision=precision,
                                preferred_element_type=jnp.float32)
                 return jax.lax.psum(G, model_axis)
-            return jnp.einsum("nk,nl->kl", F, F,
+            return jnp.einsum("nk,nl->kl", F, F, precision=precision,
                               preferred_element_type=jnp.float32)
 
         # explicit path: cast the LOCAL shard before the all_gather —

@@ -299,6 +299,79 @@ class TestDevicePreparedPlans:
         assert np.abs(out[0]).sum() > 0 and np.abs(out[2]).sum() > 0
 
 
+    @pytest.mark.parametrize("seed,min_pad,n_rows", [(3, 8, 60), (4, 2, 7),
+                                                     (5, 16, 300)])
+    def test_prepared_arrays_equal_the_host_plans(self, seed, min_pad,
+                                                  n_rows):
+        """The payload sorts and window copies build what the host plan
+        builds, bit for bit: the same buckets and chunks, and every row's
+        slots, mask and ridge scale (duplicate pairs keep their order; the
+        last row's window reaches past the last rating)."""
+        out_rows, other, vals, F, _ = self._problem(seed=seed,
+                                                    n_rows=n_rows)
+        other[:40] = other[40:80]  # duplicate (row, partner) pairs,
+        out_rows[:40] = out_rows[40:80]  # other values
+        k = F.shape[1]
+        omega = np.bincount(out_rows, minlength=n_rows).astype(np.float32)
+        host = als_ops.prepare_side(
+            als_ops.build_solve_plan(out_rows, other, vals, n_rows,
+                                     min_pad=min_pad), omega, k)
+        dev = als_ops.device_prepare_side(
+            out_rows, other, vals, n_rows, omega=omega, min_pad=min_pad,
+            rank_for_chunking=k)
+        def by_row(prepared):
+            # row -> its padded slots, mask and ridge scale (the device
+            # plan orders a merged small-pad bucket by class, then row)
+            out = {}
+            for rows3, oidx3, vals3, w3, sc3 in prepared:
+                pad = oidx3.shape[-1]
+                flat = [np.asarray(a).reshape(-1, *a.shape[2:])
+                        for a in (rows3, oidx3, vals3, w3, sc3)]
+                for j in np.nonzero(flat[0] < n_rows)[0]:
+                    assert flat[0][j] not in out
+                    out[int(flat[0][j])] = (pad,) + tuple(
+                        a[j].tobytes() for a in flat[1:])
+            return out
+
+        assert [b[1].shape for b in host] == [b[1].shape for b in dev]
+        want, got = by_row(host), by_row(dev)
+        assert set(want) == set(np.nonzero(omega)[0])
+        assert got == want
+
+    def test_plan_moves_no_rating_by_its_own_index(self):
+        """What PR 29's repair of the plan is: the sorts carry their
+        payload and a row's slots are one window, so no program of the
+        plan gathers a rating or a slot by an index of its own (24 ns an
+        element at 95.5M ratings, and a time that moved from run to run:
+        PERF.md, Findings, PR 29)."""
+        e, n_rows, pad, n = 4096, 50, 64, 32
+        i32 = jax.ShapeDtypeStruct((e,), jnp.int32)
+        f32 = jax.ShapeDtypeStruct((e,), jnp.float32)
+        keys = jax.make_jaxpr(
+            lambda a, b, v: als_ops._device_plan_keys(a, b, v, n_rows, 31)
+        )(i32, i32, f32)
+        rows = jax.ShapeDtypeStruct((n_rows,), jnp.int32)
+        scalar = jax.ShapeDtypeStruct((), jnp.int32)
+        bucket = jax.make_jaxpr(
+            lambda ro, c, s, o, v, off, nb: als_ops._device_bucket(
+                ro, c, s, o, v, off, nb, pad, n, 1, n_rows)
+        )(rows, rows, rows, i32, f32, scalar, scalar)
+
+        def gathers(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "gather":
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from gathers(sub)
+
+        # the keys: only searchsorted's lookups in the 31 powers of two
+        assert all(g.invars[0].aval.shape == (31,)
+                   for g in gathers(keys.jaxpr))
+        windows = [g for g in gathers(bucket.jaxpr)]
+        assert len(windows) == 2  # partner indices, values
+        assert all(g.params["slice_sizes"] == (pad,) for g in windows)
+
+
 class TestALSFitDevice:
     """ALS.fit_device: device-built plans behind the standard model
     surface — must converge like fit on dense-id data."""
@@ -327,6 +400,54 @@ class TestALSFitDevice:
         with pytest.raises(ValueError, match="dense ids"):
             ALS(cfg).fit_device(np.array([0, 120]), np.array([0, 0]),
                                 np.ones(2, np.float32), 120, 90)
+
+    @pytest.mark.parametrize("implicit_alpha", [None, 2.0],
+                             ids=["explicit", "implicit"])
+    def test_one_sweep_segments_equal_one_call_bit_for_bit(
+            self, implicit_alpha):
+        """``checkpoint_every=1``: k segments of one sweep give the tables
+        of one call of k sweeps bit for bit, and ``on_segment`` sees every
+        segment's tables with a rising ``step``."""
+
+        class Hook:
+            def __init__(self):
+                self.calls = []
+
+            def on_segment(self, U, V, label="segment", step=None):
+                self.calls.append((np.asarray(U).copy(),
+                                   np.asarray(V).copy(), label, step))
+
+        gen = SyntheticMFGenerator(num_users=120, num_items=90, rank=4,
+                                   noise=0.05, seed=3)
+        ru, ri, rv, _ = gen.generate(12_000).to_numpy()
+        k = 3
+        cfg = ALSConfig(num_factors=8, lambda_=0.05, iterations=k, seed=0,
+                        reg_mode="als_wr", implicit_alpha=implicit_alpha)
+        whole, parts = ALS(cfg), ALS(cfg)
+        whole.evaluator, parts.evaluator = Hook(), Hook()
+        one = whole.fit_device(ru, ri, rv, 120, 90)
+        seg = parts.fit_device(ru, ri, rv, 120, 90, checkpoint_every=1)
+        np.testing.assert_array_equal(np.asarray(one.U), np.asarray(seg.U))
+        np.testing.assert_array_equal(np.asarray(one.V), np.asarray(seg.V))
+        # without the argument: one segment, one call at the end, as before
+        assert [c[3] for c in whole.evaluator.calls] == [k]
+        assert [c[3] for c in parts.evaluator.calls] == list(range(1, k + 1))
+        assert {c[2] for c in parts.evaluator.calls} == {"als_device_rounds"}
+        np.testing.assert_array_equal(parts.evaluator.calls[-1][0],
+                                      np.asarray(seg.U))
+        assert not np.array_equal(parts.evaluator.calls[0][1],
+                                  parts.evaluator.calls[1][1])
+        # segment j's tables are those of a fit of j sweeps
+        two = ALS(ALSConfig(**{**cfg.__dict__, "iterations": 2})).fit_device(
+            ru, ri, rv, 120, 90)
+        np.testing.assert_array_equal(parts.evaluator.calls[1][1],
+                                      np.asarray(two.V))
+        # a segment length that does not divide the iterations: 2 + 1
+        uneven = ALS(cfg)
+        uneven.evaluator = Hook()
+        last = uneven.fit_device(ru, ri, rv, 120, 90, checkpoint_every=2)
+        assert [c[3] for c in uneven.evaluator.calls] == [2, 3]
+        np.testing.assert_array_equal(np.asarray(last.V), np.asarray(one.V))
 
     def test_implicit_mode_matches_host_fit_ranking(self):
         """Same planted-propensity setup as the host iALS ranking test:
@@ -763,6 +884,40 @@ class TestBF16Gram:
             ru, ri, rv, 100, 70)
         assert model.rmse(te) < 0.12
 
+    @pytest.mark.parametrize("dtype,want", [(jnp.float32, "HIGHEST"),
+                                             (jnp.bfloat16, None)])
+    def test_float32_contractions_ask_for_float32_products(self, dtype,
+                                                           want):
+        """A CPU product is float32 whatever is asked; a TPU's default
+        multiplies float32 inputs in bfloat16. So the float32 path names
+        its precision on every Gram and right-hand-side contraction, and
+        the bf16 path (the benchmark's control) does not."""
+        F = jnp.ones((6, 4), dtype)
+        oi = jnp.zeros((2, 3), jnp.int32)
+        ones = jnp.ones((2, 3), jnp.float32)
+        chunk = jax.make_jaxpr(als_ops._gram_solve_chunk)(
+            F, oi, ones, ones, jnp.ones(2, jnp.float32), 0.1)
+        full = jax.make_jaxpr(als_ops._full_gram)(F)
+
+        def eqns(jaxpr):  # through the jit of _full_gram and the solves
+            for e in jaxpr.eqns:
+                yield e
+                for v in e.params.values():
+                    inner = getattr(v, "jaxpr", None)
+                    if inner is not None:
+                        yield from eqns(inner)
+
+        for jaxpr, n in ((chunk, 2), (full, 1)):
+            dots = [e for e in eqns(jaxpr.jaxpr)
+                    if e.primitive.name == "dot_general"
+                    and e.invars[0].aval.dtype == dtype]
+            assert len(dots) >= n
+            for e in dots[:n]:
+                p = e.params["precision"]
+                got = None if p is None else {str(x).rsplit(".", 1)[-1]
+                                              for x in np.ravel(p)}
+                assert got == (None if want is None else {want}), (e, p)
+
     def test_bad_gram_dtype_rejected(self):
         with pytest.raises(ValueError, match="gram_dtype"):
             ALS(ALSConfig(gram_dtype="fp8")).fit(
@@ -932,3 +1087,100 @@ class TestRecommend:
             np.array([0, 424242]), k=3, return_mask=True)
         assert seen.tolist() == [True, False]
         assert (ids[1] == -1).all() and (ids[0] >= 0).all()
+
+
+class TestAgainstPlainReference:
+    """The program against the benchmark's plain ALS reference
+    (``benchmark/reference/als_ref.py``: its own sort, its own row blocks,
+    no padding classes, ``highest``), at a small size on seeded data; and
+    the reference against the scatter-add form ``gram_stats`` +
+    ``solve_normal_eq``: two independent plain forms agree."""
+
+    NU, NI, RANK, NNZ = 150, 110, 8, 9000
+
+    def _data(self, seed=11):
+        rng = np.random.default_rng(seed)
+        # a skew, a user and an item never rated
+        u = np.minimum((rng.exponential(0.4, self.NNZ) * (self.NU - 1)
+                        ).astype(np.int32), self.NU - 2)
+        i = np.minimum((rng.exponential(0.4, self.NNZ) * (self.NI - 1)
+                        ).astype(np.int32), self.NI - 2)
+        r = rng.normal(0, 0.25, self.NNZ).astype(np.float32)
+        return jnp.asarray(u), jnp.asarray(i), jnp.asarray(r)
+
+    def _cfg(self, reg_mode, lam):
+        return {"num_users": self.NU, "num_items": self.NI,
+                "num_factors": self.RANK, "lambda": lam,
+                "reg_mode": reg_mode, "init_scale": 0.1}
+
+    @pytest.mark.parametrize("reg_mode,lam", [("als_wr", 0.02),
+                                              ("direct", 0.5)])
+    def test_fit_device_matches_the_reference(self, reg_mode, lam):
+        from benchmark.reference import als_ref
+
+        u, i, r = self._data()
+        sweeps = 3
+
+        class Keep:
+            def __init__(self):
+                self.tables = []
+
+            def on_segment(self, U, V, label="segment", step=None):
+                self.tables.append((np.asarray(U), np.asarray(V)))
+
+        solver = ALS(ALSConfig(num_factors=self.RANK, lambda_=lam,
+                               iterations=sweeps, reg_mode=reg_mode,
+                               seed=0, init_scale=0.1))
+        solver.evaluator = Keep()
+        solver.fit_device(u, i, r, self.NU, self.NI, checkpoint_every=1)
+        ref = als_ref.fit(u, i, r, self._cfg(reg_mode, lam), sweeps)
+        seen_u, seen_i = (np.asarray(m) for m in ref["seen"])
+        assert not seen_u[-1] and not seen_i[-1] and seen_u[0]
+        for (U, V), (Ur, Vr) in zip(solver.evaluator.tables, ref["sweeps"]):
+            Ur, Vr = np.asarray(Ur), np.asarray(Vr)
+            scale = np.abs(Ur).max()
+            np.testing.assert_allclose(U, Ur, atol=2e-4 * scale, rtol=0)
+            np.testing.assert_allclose(V, Vr, atol=2e-4 * np.abs(Vr).max(),
+                                       rtol=0)
+            # rows never rated stay zero on both sides
+            assert not U[~seen_u].any() and not Ur[~seen_u].any()
+            assert not V[~seen_i].any() and not Vr[~seen_i].any()
+        # the reference's initial V is the program's seed rule
+        from large_scale_recommendation_tpu.core.initializers import (
+            PseudoRandomFactorInitializer,
+        )
+        init = np.asarray(PseudoRandomFactorInitializer(
+            self.RANK, scale=0.1)(np.arange(self.NI, dtype=np.int32)))
+        np.testing.assert_array_equal(np.asarray(ref["init"][1]),
+                                      init * seen_i[:, None])
+        assert not np.asarray(ref["init"][0]).any()
+
+    @pytest.mark.parametrize("reg_mode", ["als_wr", "direct"])
+    def test_reference_half_step_matches_gram_stats_and_solve(self,
+                                                              reg_mode):
+        from benchmark.reference import als_ref
+
+        u, i, r = self._data(seed=12)
+        lam = 0.05
+        V = jnp.asarray(np.random.default_rng(0).normal(
+            0, 0.3, (self.NI, self.RANK)).astype(np.float32))
+        solve, seen = als_ref._side(u, i, r, self.NU,
+                                    self._cfg(reg_mode, lam))
+        got = np.asarray(solve(V))
+        A, b = als_ops.gram_stats(V, u, i, r, jnp.ones_like(r), self.NU,
+                                  chunk=self.NNZ // 4)
+        counts = np.bincount(np.asarray(u), minlength=self.NU)
+        scale = (jnp.asarray(counts, jnp.float32)
+                 if reg_mode == "als_wr" else None)
+        want = np.asarray(als_ops.solve_normal_eq(A, b, lam, scale))
+        want = want * (counts > 0)[:, None]
+        np.testing.assert_allclose(got, want, atol=2e-4 * np.abs(want).max(),
+                                   rtol=0)
+        assert counts.max() > 2 * als_ref._WINDOW  # several windows a row
+        np.testing.assert_array_equal(np.asarray(seen), counts > 0)
+        # the fault leaves every second rating of each row out: a row with
+        # one rating keeps it, the others change
+        faulty = np.asarray(solve(V, fault="half_batch"))
+        assert np.abs(faulty - got).max() > 1e-2 * np.abs(got).max()
+        np.testing.assert_allclose(faulty[counts == 1], got[counts == 1],
+                                   atol=1e-6)

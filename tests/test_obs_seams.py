@@ -107,6 +107,10 @@ def _drive(annotate):
     with annotate(CALLER["fit"]):
         ALS(ALSConfig(num_factors=RANK, lambda_=0.05,
                       iterations=1)).fit(train)
+    with annotate(CALLER["fit"]):
+        ALS(ALSConfig(num_factors=RANK, lambda_=0.05,
+                      iterations=SEGMENTS)).fit_device(
+            ru, ri, rv, NU, NI, checkpoint_every=1)
 
 
 @pytest.fixture(scope="module")
@@ -147,7 +151,7 @@ def test_no_seam_has_a_name_the_benchmark_emits(capture):
     # only events of those names are the ones _drive opened
     counts = {n: sum(1 for e in capture if e[0] == n)
               for n in CALLER.values()}
-    assert counts == {"serving/flush": 2, "fit/fit_device": 3}
+    assert counts == {"serving/flush": 2, "fit/fit_device": 4}
 
 
 def test_every_seam_is_a_row_of_the_docs_table():
@@ -164,6 +168,39 @@ def test_segment_seams_are_one_per_segment(capture):
         for what in ("segment", "after_segment"):
             n = sum(1 for e in capture if e[0] == f"fit/{label}/{what}")
             assert n == SEGMENTS, (label, what, n)
+
+
+def test_als_fit_device_opens_its_seams_in_order(capture):
+    """plan (both sides, the read-backs included), init, then one segment
+    and one after_segment a sweep, none overlapping: ``ALS.fit`` (host
+    plans, one segment) opened the first ``fit/als/segment`` before."""
+    mine = sorted((s, s + d, n) for n, s, d in capture
+                  if n.startswith("fit/als/"))
+    assert [n for _, _, n in mine] == (
+        ["fit/als/segment", "fit/als/plan", "fit/als/init"]
+        + ["fit/als/segment", "fit/als/after_segment"] * SEGMENTS)
+    for (_, end, _), (start, _, _) in zip(mine, mine[1:]):
+        assert end <= start
+
+
+def test_als_plan_sizes_reach_the_registry(null_obs):
+    registry, _ = obs.enable()
+    try:
+        _, (ru, ri, rv) = _ratings()
+        ALS(ALSConfig(num_factors=RANK, lambda_=0.05,
+                      iterations=1)).fit_device(ru, ri, rv, NU, NI)
+        got = {(m["name"], m["labels"]["side"]): m["value"]
+               for m in registry.snapshot()["metrics"]
+               if m["name"].startswith("als_plan_")}
+    finally:
+        obs.disable()
+    for side in ("user", "item"):
+        assert got[("als_plan_ratings", side)] == len(ru)
+        # every rating has a slot; the padding is what the pow2 classes add
+        assert (len(ru) <= got[("als_plan_padded_slots", side)]
+                < 4 * len(ru))
+        assert 1 <= got[("als_plan_buckets", side)] <= got[
+            ("als_plan_chunks", side)]
 
 
 def test_unknown_seam_is_refused():
