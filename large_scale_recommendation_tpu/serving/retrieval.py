@@ -64,6 +64,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from large_scale_recommendation_tpu.obs.registry import get_registry
 from large_scale_recommendation_tpu.obs.trace import get_tracer
 from large_scale_recommendation_tpu.parallel.serving import catalog_version
 from large_scale_recommendation_tpu.utils.metrics import DEAD_SLOT_OFFSET
@@ -533,13 +534,95 @@ def build_quantized_catalog(V, item_mask=None,
 # --------------------------------------------------------------------------
 
 
+_LANES = 128  # columns of one TPU tile: the least group, one lane row
+_SUBLANES = 8  # float32 rows of one TPU tile
+
+
+def select_groups(n: int, k: int) -> tuple[int, int] | None:
+    """The shape rule of ``exact_top_k``: ``(S, G)``, ``G`` contiguous
+    groups of ``S`` scores a row, or ``None`` where the row goes to
+    ``lax.top_k`` whole.
+
+    The two levels read ``G`` group maxima and ``k * S`` gathered
+    scores where the whole-row top-k reads ``n``; ``G + k * S`` is
+    least at ``S = sqrt(n / k)``, rounded up here to a power-of-two
+    number of lane rows. Below ``G = 4 * k`` (a catalog of some 20K
+    rows at ``k = 40``) the detour's two small top-ks and its gather
+    cost what the one whole-row top-k costs (0.5 ms each on a v5e:
+    PERF.md, Findings, PR 30), so the row stays whole."""
+    S = _LANES
+    while S * S * k < n:
+        S *= 2
+    G = -(-n // S)
+    return (S, G) if G >= 4 * k else None
+
+
+def exact_top_k(scores, k: int):
+    """``lax.top_k(scores, k)`` of ``f32[b, n]`` rows, values and
+    positions, ties included, without a top-k over ``n`` scores a row
+    where ``select_groups`` says the detour pays:
+
+    1. the row is ``G`` contiguous groups of ``S`` scores (the tail
+       padded with ``-inf``); each group's maximum, ``[b, G]``;
+    2. ``lax.top_k`` of the maxima, ``k`` groups a row, their ids sorted
+       ascending;
+    3. those groups gathered as ``b * k`` whole rows of ``S`` floats
+       (never per element) and ``lax.top_k`` of the ``k * S`` scores;
+    4. positions mapped back, ``gid * S + offset``.
+
+    Exact: ``lax.top_k`` orders by (value descending, position
+    ascending). An element outside the ``k`` groups that lead by
+    (maximum descending, id ascending) has ``k`` elements ahead of it in
+    that order, one in each leading group — a greater maximum, or an
+    equal one in an earlier, hence lower-positioned, group — so it is
+    not among the top ``k``; and since the gathered groups are in id
+    order, position order inside the candidates is position order in
+    the row, so step 3 breaks ties as the whole-row top-k would. A
+    ``-inf`` pad is the row's last position and is chosen only after
+    every real score.
+
+    Both reads of the ``[b, n]`` matrix go through one view, ``[b / 8,
+    G, 8, S]``: the order in which a TPU holds ``f32[b, n]`` (tiles of 8
+    rows by 128 columns, row-tile major), so that view costs nothing
+    there, where ``scores.reshape(b, G, S)`` is a copy of the whole
+    matrix; the maxima reduce over its last axis and the gather takes
+    whole rows of its ``[b * G, S]`` flattening. A ``b`` that 8 does not
+    divide takes the same steps over ``[b, G, 1, S]``."""
+    b, n = scores.shape
+    groups = select_groups(n, k)
+    if groups is None:
+        with jax.named_scope("stage1/top_k"):
+            return jax.lax.top_k(scores, k)
+    S, G = groups
+    if G * S > n:
+        scores = jnp.pad(scores, ((0, 0), (0, G * S - n)),
+                         constant_values=-jnp.inf)
+    r = _SUBLANES if b % _SUBLANES == 0 else 1
+    tiles = scores.reshape(b // r, r, G, S).transpose(0, 2, 1, 3)
+    with jax.named_scope("stage1/group_max"):
+        gmax = tiles.max(axis=-1).transpose(0, 2, 1).reshape(b, G)
+    with jax.named_scope("stage1/group_top_k"):
+        _, gid = jax.lax.top_k(gmax, k)
+        gid = jnp.sort(gid, axis=1)
+    with jax.named_scope("stage1/gather"):
+        row = jnp.arange(b, dtype=jnp.int32)[:, None]
+        at = ((row // r) * G + gid) * r + row % r  # [b, k]
+        cand = tiles.reshape(b * G, S)[at].reshape(b, k * S)
+    with jax.named_scope("stage1/top_k"):
+        v, pos = jax.lax.top_k(cand, k)
+        return v, jnp.take_along_axis(gid, pos // S, axis=1) * S + pos % S
+
+
 @partial(jax.jit, static_argnames=("kc",))
 def _stage1_flat(qU, u_scale, Q, scale, item_w,
                  excl_rows, excl_cols, excl_w, *, kc):
     """Flat int8 stage 1: one int8×int8→int32 matmul over the whole
     quantized catalog, dequantized by the outer product of scales, the
     exact path's additive mask offset and scatter-min exclusions
-    applied, top-``kc`` candidates out."""
+    applied, top-``kc`` candidates out: the ``kc`` that ``lax.top_k``
+    over the whole row would give, chosen by ``exact_top_k`` (on a wide
+    catalog through group maxima, so that no top-k runs over a million
+    scores a row)."""
     # named scopes: HLO metadata only (the device trace can then name
     # the phases the fusion numbers hide); no arithmetic moves
     with jax.named_scope("stage1/int8_dot"):
@@ -550,8 +633,7 @@ def _stage1_flat(qU, u_scale, Q, scale, item_w,
         scores = scores * (u_scale[:, None] * scale[None, :])
         scores = scores + item_w[None, :]
         scores = scores.at[excl_rows, excl_cols].min(excl_w)
-    with jax.named_scope("stage1/top_k"):
-        return jax.lax.top_k(scores, kc)
+    return exact_top_k(scores, kc)
 
 
 @partial(jax.jit, static_argnames=("kc", "n_probe"))
@@ -654,7 +736,15 @@ class TwoStageRetriever:
     """One catalog build's fast path: quantized stage-1 structure +
     full-precision rescore table, with per-chunk ``topk`` the engine's
     micro-batch loop calls. Rebuilt by ``ServingEngine._refresh`` on a
-    full swap; patched in place by ``apply_delta`` on a delta swap."""
+    full swap; patched in place by ``apply_delta`` on a delta swap.
+
+    The flat stage 1 hands stage 2 the ``k · overfetch`` best int8
+    scores of the whole row, exactly those ``lax.top_k`` over the row
+    would: ``exact_top_k`` finds them through the maxima of contiguous
+    groups on a catalog wide enough for that to pay (``select_groups``,
+    read off the catalog's height and the budget; no option), and the
+    counter ``serving_stage1_select_total{path}`` says which way each
+    call went."""
 
     def __init__(self, V, item_mask=None,
                  config: RetrievalConfig | None = None,
@@ -671,6 +761,13 @@ class TwoStageRetriever:
             # f32 candidate einsum into a partial contraction + all-reduce
             self.V = self.partitioner.shard(self.V, None, "rank")
         self.buckets_seen: set[tuple] = set()  # compile-shape evidence
+        # flat stage-1 calls by how exact_top_k picks the candidates,
+        # counted from the shape rule it traces with (bound here, as the
+        # engine binds its own: no-ops under the default null registry)
+        obs = get_registry()
+        self._m_select = {
+            path: obs.counter("serving_stage1_select_total", path=path)
+            for path in ("two_level", "full")}
 
     def nbytes_per_device(self) -> int:
         """Stage-1 catalog + stage-2 rescore table bytes per device (the
@@ -749,6 +846,9 @@ class TwoStageRetriever:
                 # only the flat int8×int8 dot consumes quantized queries
                 qU, u_scale = _quantize_rows(U_chunk)
                 self.buckets_seen.add(("flat", U_chunk.shape[0], kc))
+                self._m_select[
+                    "full" if select_groups(cat.n_rows, kc) is None
+                    else "two_level"].inc()
                 cand_v, cand_rows = _stage1_flat(
                     qU, u_scale, cat.q, cat.scale, cat.item_w,
                     excl_rows, excl_cols, excl_w, kc=kc)

@@ -12,9 +12,12 @@ the int8 catalog, and through ``ServingEngine.apply_delta`` +
 ``StreamingDriver.refresh_serving``.
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from large_scale_recommendation_tpu.data.blocking import flat_index
@@ -27,9 +30,20 @@ from large_scale_recommendation_tpu.serving import (
     quantize_rows,
     recall_at_k,
 )
-from large_scale_recommendation_tpu.serving.retrieval import (
-    dequantize_rows,
+from large_scale_recommendation_tpu.obs.registry import (
+    MetricsRegistry,
+    get_registry,
+    set_registry,
 )
+from large_scale_recommendation_tpu.parallel.partitioner import Partitioner
+from large_scale_recommendation_tpu.serving import retrieval
+from large_scale_recommendation_tpu.serving.retrieval import (
+    TwoStageRetriever,
+    dequantize_rows,
+    exact_top_k,
+    select_groups,
+)
+from large_scale_recommendation_tpu.utils.metrics import DEAD_SLOT_OFFSET
 
 
 def random_model(num_users, num_items, rank, seed=0, structured=False,
@@ -166,6 +180,153 @@ class TestTwoStageRecall:
         assert sorted(real.tolist()) == list(range(1000))
         stats = cat.stats
         assert stats["max_cluster"] <= stats["capacity_cap"] == m
+
+
+def _score_rows(kind, b, n, k, seed):
+    """``f32[b, n]`` rows for ``exact_top_k``, by what they stress."""
+    rng = np.random.default_rng(seed)
+    if kind == "tie_free":
+        return rng.permuted(np.tile(np.arange(n, dtype=np.float32),
+                                    (b, 1)), axis=1)
+    s = rng.integers(-3, 4, (b, n)).astype(np.float32)  # ties everywhere
+    if kind == "dead":  # masked rows and train-seen exclusions
+        dead = rng.random((b, n))
+        s[dead < 0.3] = -np.inf
+        s[dead > 0.8] = DEAD_SLOT_OFFSET
+    elif kind == "few_live":  # fewer live scores than k, in late groups
+        live = rng.random((b, n)) < (k // 4) / n
+        live[:, -1] = True
+        s = np.where(live, s, -np.inf).astype(np.float32)
+    return s
+
+
+class TestExactTopK:
+    """Stage 1's candidate selection is ``lax.top_k`` of the whole row,
+    values and positions, however the row is cut into groups."""
+
+    K = 40  # the benchmark's k * overfetch
+
+    @pytest.mark.parametrize("kind,b,n", [
+        ("tie_free", 8, 49152),
+        ("few_values", 8, 49152),
+        ("dead", 8, 49152),
+        ("few_live", 8, 49152),
+        ("dead", 8, 50001),        # n not a multiple of S: -inf pads
+        ("few_live", 8, 50001),
+        ("few_values", 5, 49152),  # a bucket 8 does not divide
+        ("dead", 8, 1 << 20),      # wide enough for S = 256
+        ("dead", 8, 4096),         # too small: lax.top_k as before
+        *[("dead", b, 49152) for b in (16, 32, 64, 128, 256)],
+    ])
+    def test_equals_lax_top_k(self, kind, b, n):
+        groups = select_groups(n, self.K)
+        assert (groups is None) == (n == 4096)
+        if groups is not None:
+            S, G = groups
+            assert (S == 256) == (n == 1 << 20)
+            assert G * S >= n > (G - 1) * S and G >= 4 * self.K
+        scores = jnp.asarray(_score_rows(kind, b, n, self.K, seed=b + n))
+        want_v, want_at = jax.lax.top_k(scores, self.K)
+        got_v, got_at = jax.jit(partial(exact_top_k, k=self.K))(scores)
+        np.testing.assert_array_equal(np.asarray(got_v),
+                                      np.asarray(want_v))
+        np.testing.assert_array_equal(np.asarray(got_at),
+                                      np.asarray(want_at))
+
+    @pytest.mark.parametrize("sharded", [False, True],
+                             ids=["one_device", "rank_sharded"])
+    def test_retriever_answers_unchanged(self, sharded, monkeypatch):
+        """On a catalog wide enough to engage the two levels,
+        ``TwoStageRetriever.topk`` hands stage 2 the candidates a
+        whole-row ``lax.top_k`` would, masked rows and train-seen
+        exclusions among them, and so returns the same answers."""
+        rng = np.random.default_rng(11)
+        n, rank, b, k = 50000, 16, 32, 10
+        V = rng.normal(size=(n, rank)).astype(np.float32)
+        U = jnp.asarray(rng.normal(size=(b, rank)).astype(np.float32))
+        mask = rng.random(n) > 0.2
+        e = 256
+        excl = (rng.integers(0, b, e).astype(np.int32),
+                rng.integers(0, n, e).astype(np.int32),
+                np.full(e, DEAD_SLOT_OFFSET, np.float32))
+        part = (Partitioner(num_devices=8, model_parallel=2)
+                if sharded else None)
+        ret = TwoStageRetriever(V, item_mask=mask, partitioner=part)
+        kc = ret.candidate_count(k)
+        assert select_groups(n, kc) is not None
+        got_v, got_rows = ret.topk(U, excl, k=k)
+
+        cat = ret.catalog
+        excl = tuple(jnp.asarray(x) for x in excl)
+        if sharded:  # as topk replicates them onto the mesh
+            U = part.shard(U)
+            excl = tuple(part.shard(x) for x in excl)
+        qU, u_scale = retrieval.quantize_rows(U)
+        stage1 = (qU, u_scale, cat.q, cat.scale, cat.item_w, *excl)
+        cand = retrieval._stage1_flat(*stage1, kc=kc)
+        monkeypatch.setattr(retrieval, "select_groups", lambda n, k: None)
+        whole = retrieval._stage1_flat.__wrapped__(*stage1, kc=kc)
+        for got, want in zip(cand, whole):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+        want_v, want_rows = retrieval._stage2(
+            U, ret.V, cat.item_w, *whole, *excl, k=k, exact=True)
+        np.testing.assert_array_equal(np.asarray(got_rows),
+                                      np.asarray(want_rows))
+        np.testing.assert_array_equal(np.asarray(got_v),
+                                      np.asarray(want_v))
+
+    @staticmethod
+    def _top_k_widths(jaxpr):
+        """Operand widths of every ``top_k`` in a jaxpr, nested ones
+        included."""
+        widths = []
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "top_k":
+                widths.append(eqn.invars[0].aval.shape[-1])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                widths.extend(TestExactTopK._top_k_widths(sub))
+        return widths
+
+    @pytest.mark.parametrize("n,path", [(1 << 20, "two_level"),
+                                        (4096, "full")])
+    def test_shape_rule_at_the_benchmarks_size(self, n, path):
+        """Shapes only: at ``[256, 1048576]`` and 40 candidates
+        ``_stage1_flat`` holds no top-k wider than the group maxima or
+        the gathered groups, the toy catalog keeps its whole-row top-k,
+        and the retriever's counter names the path from the same rule."""
+        b, rank, kc = 256, 512, self.K
+        f32, i32 = jnp.float32, jnp.int32
+        sds = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(partial(retrieval._stage1_flat, kc=kc))(
+            sds((b, rank), jnp.int8), sds((b,), f32),
+            sds((n, rank), jnp.int8), sds((n,), f32), sds((n,), f32),
+            sds((8,), i32), sds((8,), i32), sds((8,), f32))
+        widths = self._top_k_widths(jaxpr.jaxpr)
+        if path == "two_level":
+            S, G = select_groups(n, kc)
+            assert sorted(widths) == sorted([G, kc * S])
+        else:
+            assert widths == [n]
+
+        prev = get_registry()
+        reg = MetricsRegistry()
+        set_registry(reg)
+        try:
+            rng = np.random.default_rng(3)
+            ret = TwoStageRetriever(
+                rng.normal(size=(n, 4)).astype(np.float32),
+                config=RetrievalConfig(overfetch=4))
+        finally:
+            set_registry(prev)
+        excl = (np.zeros(8, np.int32), np.zeros(8, np.int32),
+                np.full(8, np.inf, np.float32))
+        ret.topk(jnp.asarray(rng.normal(size=(8, 4)).astype(np.float32)),
+                 excl, k=10)
+        counts = {dict(c.labels)["path"]: c.value
+                  for c in reg.find("serving_stage1_select_total")}
+        assert counts == {path: 1, "full" if path == "two_level"
+                          else "two_level": 0}
 
 
 class TestDeltaSwaps:
