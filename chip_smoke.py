@@ -3,8 +3,8 @@ the system still starts there.
 
 ONE process drives one TPU chip (or all four of a host) through the entry
 points a user calls, at the full width of the flagship — the ML-25M shape,
-162,541 x 59,047 at rank 128, the bench's own workload and
-hyper-parameters (bench.py ``run_child``), random weights from a seed:
+162,541 x 59,047 at rank 128, with the fit cells' settings
+(``dsgd_config``), random weights from a seed:
 
 1. train   ``DSGD.fit_device`` for a few sweeps; holdout RMSE is finite and
            lower after the last sweep than after the first.
@@ -68,7 +68,7 @@ class Sizes:
     nnz: int = 25_000_095
     rank: int = 128
     sweeps: int = 4
-    blocks: int = 8                # bench.py: k = 8, minibatch 32768
+    blocks: int = 8                # the fit cells': k = 8, minibatch 32768
     minibatch: int = 32768
     pallas_blocks: int = 32        # the AOT gate's ML-25M geometries
     pipelined_minibatch: int = 1024   # stratum_sweep k32_rank128_mb1024_f32
@@ -90,8 +90,8 @@ def check(cond, what: str) -> None:
 
 
 def dsgd_config(s: Sizes, **overrides):
-    """bench.py's DSGD hyper-parameters (``run_child``), the path every
-    bench line uses."""
+    """The fit cells' settings (``benchmark/configs/netflix100m-r128*.json``;
+    tests/test_chip_smoke.py holds the two to each other)."""
     from large_scale_recommendation_tpu.models.dsgd import DSGDConfig
 
     kw = dict(num_factors=s.rank, lambda_=0.1, iterations=s.sweeps,

@@ -59,9 +59,8 @@ def load_ml25m(path: str) -> Ratings:
 def load_ratings_file(path: str) -> Ratings:
     """Load a ratings file, sniffing the format: MovieLens-25M
     ``ratings.csv`` (comma-separated, ``userId,movieId,...`` header) or
-    MovieLens-100K ``u.data`` (tab-separated, no header). The BENCH_DATA
-    entry point — a real-data bench run should accept either format
-    without the caller naming it."""
+    MovieLens-100K ``u.data`` (tab-separated, no header): a real-data
+    run should accept either format without the caller naming it."""
     if os.path.isdir(path):
         for cand in ("ratings.csv", "u.data"):
             p = os.path.join(path, cand)
@@ -116,10 +115,10 @@ _SHAPES = {
 
 def vocab_overrides_from_env() -> tuple[int | None, int | None]:
     """BENCH_USERS/BENCH_ITEMS → (num_users, num_items) overrides, the ONE
-    copy of the bench/probe env contract: reduced-nnz runs must shrink the
+    copy of the probes' env contract: reduced-nnz runs must shrink the
     vocab along with nnz, or the workload degenerates (DSGD: obs/row below
     the recoverable regime; ALS: mostly-empty normal equations). Used by
-    bench.py and the scripts/ probes so the parse cannot drift."""
+    the scripts/ probes so the parse cannot drift."""
     nu = os.environ.get("BENCH_USERS")
     ni = os.environ.get("BENCH_ITEMS")
     return (int(nu) if nu else None, int(ni) if ni else None)

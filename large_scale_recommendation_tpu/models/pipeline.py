@@ -8,8 +8,8 @@ fit-time parameter overlays (MatrixFactorization.scala:58 and the
 surface itself — the one residual the round-4 verdict listed as an
 "acceptable collapse" — with TPU-native stages instead of a framework
 cosplay: the two transformers shipped here are exactly the real-data
-preprocessing every entry point otherwise hand-rolls (bench.py's
-BENCH_DATA route: parse → dense-id compaction → mean-centering → fit).
+preprocessing every entry point otherwise hand-rolls (parse →
+dense-id compaction → mean-centering → fit).
 
 Contracts (duck-typed, no registry):
 
@@ -96,8 +96,8 @@ class MeanCenterer:
 
     The plain bilinear model has no bias terms, so raw star ratings
     (~3.5 mean) otherwise cost the first sweeps learning the offset —
-    or diverge at bench step sizes (measured, bench.py BENCH_DATA
-    route). Predictions for unseen pairs become the train mean: score 0
+    or diverge at bench step sizes (measured). Predictions for unseen
+    pairs become the train mean: score 0
     ("no information") + mean — the calibrated default."""
 
     def fit(self, ratings: Ratings) -> "FittedMeanCenterer":

@@ -7,9 +7,8 @@ is the cost of that blindness: ``als_implicit_ndcg=0.003`` shipped
 across five bench rounds before anyone noticed the implicit path ranks
 randomly. This module is the model-quality plane:
 
-- ``sampled_ranking_metrics`` — THE shared ranking-metric kernel
-  (``bench.py`` and the evaluator both import it; one copy so the bench
-  gate and the online eval can never drift): each held-out positive is
+- ``sampled_ranking_metrics`` — THE ranking-metric kernel
+  (the evaluator's; one copy): each held-out positive is
   ranked against ``num_negatives`` sampled negatives with train-seen
   items masked out of the negative pool — the protocol whose floor
   (random model → HR ≈ k/(n+1)) and ceiling (planted structure → ≈ 1)

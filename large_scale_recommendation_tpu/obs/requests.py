@@ -475,7 +475,6 @@ class RequestTelemetry:
 
     def stage_quantiles(self, qs=(0.50, 0.99)) -> dict:
         """Per-stage window quantiles ``{stage: {"p50": s, "p99": s}}``
-        — the round-extras stamp ``scripts/serving_bench.py`` commits
         (nearest-rank over the wall window, same rule as the p99 the
         snapshot reports)."""
         with self._lock:

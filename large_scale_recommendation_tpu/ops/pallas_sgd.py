@@ -864,9 +864,8 @@ def probe_variants(rank: int = 128, mb: int = 2048, rpb_u: int = 5080,
                                       "pallas_loop")) -> dict:
     """Measure the XLA kernel vs both Pallas gather variants on ONE
     realistic (stratum, block) visit on the CURRENT device; returns
-    ``{variant: ratings_per_s | "FAILED <err>"}``. Shared by
-    scripts/pallas_probe.py and the bench extras (BENCH_PALLAS) so the
-    experiment runs whenever the bench device is a TPU — a Mosaic lowering
+    ``{variant: ratings_per_s | "FAILED <err>"}``. Run by
+    scripts/pallas_probe.py — a Mosaic lowering
     failure is recorded as a measured negative, not hidden. All inputs
     are generated on device: only the PRNG key crosses the link.
     Defaults model one ML-25M block visit at k=32 — the production

@@ -34,8 +34,8 @@ def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
     """Bytes of HBM traffic one full DSGD sweep moves PER DEVICE, per kernel.
 
     The shared roofline model behind every ``effective_hbm_gbs`` number
-    (bench.py headline, the probe variants, and the ``train_hbm_gbs``
-    obs gauge) — one copy so the accounting cannot drift between them.
+    (the probe variants and the ``train_hbm_gbs`` obs gauge) — one copy
+    so the accounting cannot drift between them.
 
     - ``kernel="xla"`` (the gather path): every rating pays ~4 row
       transactions (read+write of a u row and a v row) of
@@ -97,9 +97,7 @@ def dsgd_flops_per_sweep(nnz: int, rank: int) -> int:
     """FLOPs one full DSGD sweep computes: ~6·rank per rating visit
     (2·rank for the prediction dot, ~4·rank for the error broadcast and
     the two factor deltas). The FLOP twin of ``dsgd_bytes_per_sweep`` —
-    the ONE hand model behind bench.py's ``effective_tflops`` and the
-    ``/rooflinez`` model column, so the accounting cannot drift between
-    them."""
+    the ONE hand model behind the ``/rooflinez`` model column."""
     return int(nnz * 6 * rank)
 
 

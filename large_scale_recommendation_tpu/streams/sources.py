@@ -369,7 +369,7 @@ class GeneratorSource:
 
 class CSVSource:
     """Chop a ratings file (ML-25M ``ratings.csv`` / ML-100K ``u.data``
-    — same sniffing as the bench's BENCH_DATA route) into offset-stamped
+    — ``load_ratings_file``'s sniffing) into offset-stamped
     micro-batches; offsets are row indices within the file."""
 
     def __init__(self, path: str, batch_records: int = 4096,

@@ -449,7 +449,7 @@ def profile(log_dir: str | None) -> Iterator[None]:
     capture paths can never race the profiler singleton. New code
     should call ``obs.introspect.profile_trace`` /
     ``obs.capture_profile`` directly; this surface stays only for
-    existing callers (``bench.py``'s ``BENCH_PROFILE``) and warns."""
+    existing callers and warns."""
     if log_dir is None:
         yield
         return

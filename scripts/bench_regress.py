@@ -101,7 +101,7 @@ MULTICHIP_KEYS: dict[str, float] = {
     "layout_mb": 10.0,
 }
 
-# watched keys for the SERVING_r*.json trajectory (the serving_bench
+# watched keys for the SERVING_r*.json trajectory (the
 # traffic-simulator rounds, ISSUE 8): fast-path/exact throughput, the
 # fast-vs-exact ratio, QPS-at-SLO and recall are higher-is-better;
 # p99 latencies are LOWER-is-better — a p99 blowup under the overload
@@ -139,7 +139,7 @@ QUALITY_KEYS: dict[str, float] = {
     "rmse_final": 30.0,
 }
 
-# watched keys for the INGEST_r*.json trajectory (the streams_bench
+# watched keys for the INGEST_r*.json trajectory (the
 # N_CONSUMERS rounds, ISSUE 13): aggregate/per-N ingest rates and the
 # scaling efficiency (rate_N / (N·rate_1)) are higher-is-better;
 # recovery-after-kill wall and the per-partition duplicate window are
@@ -160,8 +160,8 @@ INGEST_KEYS: dict[str, float] = {
 # per-family round-file prefix + default watch set. The quality family
 # reads the BENCH rounds — quality keys ride inside the bench extras,
 # they just gate under their own watch set (and direction rules).
-# watched keys for the TIERED_r*.json trajectory (the streams_bench
-# tiered-store mode, ISSUE 17): the tiered ingest rate and its
+# watched keys for the TIERED_r*.json trajectory (the
+# tiered-store rounds, ISSUE 17): the tiered ingest rate and its
 # fraction of the all-HBM baseline regress when they DROP; the Zipfian
 # hit rate is near-deterministic (same trace, same slot budget), so
 # tight; prefetch stall time and eviction count regress UP — a rising

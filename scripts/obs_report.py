@@ -65,9 +65,8 @@ or a fleet ``/budgetz`` pod aggregate.
 (consumers, efficiency, Karp–Flatt serial fraction, projected speedup
 at 2N), the contended-lock table, and per-partition busy/blocked
 shares joined with their ``streams_*`` gauges. ``src`` is a
-``/contentionz`` URL, a dumped snapshot JSON (the streams_bench
-sustained pass writes one), a bundle ``contention.json``, or a fleet
-``/contentionz`` pod aggregate.
+``/contentionz`` URL, a dumped snapshot JSON, a bundle
+``contention.json``, or a fleet ``/contentionz`` pod aggregate.
 
 Input is a single-snapshot JSON file, a JSONL metrics log
 (``MetricsRegistry.append_jsonl``), or — live mode — an HTTP URL to a

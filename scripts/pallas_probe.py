@@ -96,10 +96,9 @@ def main() -> None:
             summary[f"{label}_ratings_per_s"] = val
             summary[f"{label}_effective_hbm_gbs"] = gbs
 
-    # machine-readable contract (same as bench.py::_emit_final): flush
-    # stderr FIRST so a 2>&1-merging wrapper still sees the JSON summary
-    # as the genuinely last line, diffable across rounds like BENCH
-    # artifacts
+    # machine-readable contract: flush stderr FIRST so a 2>&1-merging
+    # wrapper still sees the JSON summary as the genuinely last line,
+    # diffable across rounds like BENCH artifacts
     sys.stderr.flush()
     print(json.dumps(summary), flush=True)
 

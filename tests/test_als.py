@@ -602,8 +602,7 @@ class TestALSConvergenceAtScale:
         ill-posed, docs/PERF.md) — must descend monotonically-ish and reach
         the scaled RMSE target on a reduced-vocab workload held in the
         recoverable regime (~116 obs/user, the same scaling rule as the
-        bench fallback). Mirrors bench.py's als_rank32_time_to_rmse_s line
-        so the recorded number has a suite-pinned twin."""
+        bench fallback)."""
         from large_scale_recommendation_tpu.data.device_blocking import (
             synthetic_like_device,
         )

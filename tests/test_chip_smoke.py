@@ -77,6 +77,32 @@ def test_flagship_sizes_are_full_width():
         0.1, 0.3, "warm_boost", 0.08, "item")
 
 
+@pytest.mark.parametrize("cell", ["netflix100m-r128",
+                                  "netflix100m-r128-ring4"])
+def test_fit_cells_train_with_the_smokes_settings(cell):
+    """``dsgd_config`` is the one statement of the DSGD settings in the
+    tree: the fit cells' config files must say the same."""
+    import chip_smoke
+
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           f"{cell}.json")) as f:
+        conf = json.load(f)
+    cfg = chip_smoke.dsgd_config(chip_smoke.Sizes())
+    assert {k: conf[k] for k in (
+        "lambda", "learning_rate", "lr_schedule", "minibatch_size",
+        "minibatch_sort", "collision_mode", "init_scale", "solver_seed",
+        "num_blocks")} == {
+        "lambda": cfg.lambda_, "learning_rate": cfg.learning_rate,
+        "lr_schedule": cfg.lr_schedule,
+        "minibatch_size": cfg.minibatch_size,
+        "minibatch_sort": cfg.minibatch_sort,
+        "collision_mode": cfg.collision_mode,
+        "init_scale": cfg.init_scale, "solver_seed": cfg.seed,
+        # one chip takes the smoke's blocks; the ring's are its chips
+        "num_blocks": cfg.num_blocks if conf["chips"] == 1 else 4,
+    }
+
+
 def test_legs_rehearse_on_cpu_at_toy_size():
     """The legs against today's entry points, on virtual CPU devices at a
     toy size with the Pallas kernels explicitly interpreted: control flow

@@ -26,7 +26,7 @@ def _bench_doc(value=1000.0, extra=None):
 
 
 def _wrapper(parsed=None, tail=""):
-    return {"n": 1, "cmd": "python bench.py", "rc": 0, "tail": tail,
+    return {"n": 1, "cmd": "python harness.py", "rc": 0, "tail": tail,
             "parsed": parsed}
 
 
@@ -467,7 +467,7 @@ class TestServingFamily:
         extra = dict(self.BASE, **over)
         value = extra.pop("value", extra["fast_users_per_s"])
         p = tmp_path / name
-        p.write_text(json.dumps(  # the real serving_bench line shape
+        p.write_text(json.dumps(  # the SERVING rounds' line shape
             {"metric": "two-stage serving users/s", "value": value,
              "unit": "users/s", "vs_baseline": extra["fast_vs_exact"],
              "extra": extra}))
@@ -653,7 +653,7 @@ class TestIngestFamily:
         extra = dict(self.BASE, **over)
         value = extra.pop("value", extra["ingest_n4_ratings_per_s"])
         p = tmp_path / name
-        p.write_text(json.dumps(  # the real streams_bench line shape
+        p.write_text(json.dumps(  # the round's line shape
             {"metric": "parallel ingest ratings/s", "value": value,
              "unit": "ratings/s", "vs_baseline": 3.0, "extra": extra}))
         return str(p)
@@ -862,7 +862,7 @@ class TestTierFamily:
         extra = dict(self.BASE, **over)
         value = extra.pop("value", 400_000.0)
         p = tmp_path / name
-        p.write_text(json.dumps(  # the real streams_bench line shape
+        p.write_text(json.dumps(  # the round's line shape
             {"metric": "tiered ingest ratings/s", "value": value,
              "unit": "ratings/s", "vs_baseline": 1.0, "extra": extra}))
         return str(p)

@@ -514,8 +514,7 @@ def test_bf16_block_sweep_dtype_and_accumulation():
 
 def test_probe_script_emits_json_last_line():
     """scripts/pallas_probe.py ends with a machine-readable JSON summary
-    as the genuinely LAST line even in a 2>&1-merged stream (the
-    bench.py::_emit_final contract), carrying per-variant ratings/s and
+    as the genuinely LAST line even in a 2>&1-merged stream, carrying per-variant ratings/s and
     effective_hbm_gbs."""
     import json
     import os

