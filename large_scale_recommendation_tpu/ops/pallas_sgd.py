@@ -1023,10 +1023,9 @@ def dsgd_train_pallas(
     same p = 0..k−1 visit order; only the copy/compute overlap differs.
 
     Visit order: for each sweep, strata s = 0..k-1; within a stratum the
-    k disjoint blocks run sequentially p = 0..k-1. Because the blocked
-    layout deals each stratum's entries block-major, this is IDENTICAL to
-    the flat stratum order of ``dsgd_train`` for every ``minibatch`` that
-    divides the block size — pinned by tests at ``minibatch == b`` and
+    k disjoint blocks run sequentially p = 0..k-1 — the block visits of
+    ``dsgd_train``, one for one, for every ``minibatch`` that divides the
+    block size — pinned by tests at ``minibatch == b`` and
     ``minibatch < b``.
 
     ``schedule`` (static, same callables as ``core.updaters``) and ``t0``

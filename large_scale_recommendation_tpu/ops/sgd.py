@@ -280,8 +280,20 @@ def dsgd_train(
     ``step_idx // k + 1`` (≙ superstep/numBlocks then +1,
     DSGDforMF.scala:383-386,476).
 
-    On one device the k blocks of a stratum are disjoint in both users and
-    items, so the whole stratum is swept as one flat block.
+    On one device a sweep is ``k²`` block visits under one ``lax.scan``,
+    strata ``s = 0..k-1`` and within a stratum blocks ``p = 0..k-1`` (the
+    order of ``ops.pallas_sgd.dsgd_train_pallas`` and, block for block, of
+    the mesh ring). Block ``p``'s users are the contiguous rows
+    ``[p·rpb_u, (p+1)·rpb_u)`` and its items in stratum ``s`` the rows of
+    item block ``(p+s) mod k``, so a visit slices those two row ranges (and
+    their omegas), runs ``sgd_block_sweep`` against them with block-local
+    rows, and writes them back: the scatter-adds land in a table a ``k``-th
+    as high as the whole one, which the TPU keeps in on-chip memory across
+    the minibatch scan where the whole U does not fit (a ninth of the
+    time a rating, PERF.md Findings, PR 32). Both blocking paths pad
+    every block to whole minibatches and every table to ``k`` equal row
+    blocks; a hand-built layout that breaks either raises ``ValueError``
+    at trace time.
 
     bf16 factor storage (ISSUE 6, the ALX recipe): ``U``/``V`` may arrive
     as ``bfloat16`` tables — the whole sweep then runs on ONE f32 upcast
@@ -289,9 +301,10 @@ def dsgd_train(
     semantics stay exact f32) and the result is rounded back to the
     storage dtype on exit, all inside this jitted computation. The
     tables at rest (HBM between segments, checkpoints, host↔device
-    transfers) are half-width; XLA cannot express the per-block-visit
-    staging the Pallas kernel uses, so this is the fallback's honest
-    share of the optimization.
+    transfers) are half-width. The upcast stays one per call, not one
+    per block visit as the Pallas kernel stages it: rounding a block back
+    after every visit would round each row ``k`` times a sweep, another
+    arithmetic than this configuration's.
     """
     store_dtype = U.dtype
     if store_dtype != jnp.float32:
@@ -299,28 +312,48 @@ def dsgd_train(
         V = V.astype(jnp.float32)
     k = num_blocks
     b = su.shape[-1]
-    flat = (k, k * b)
-    su_f, si_f = su.reshape(flat), si.reshape(flat)
-    sv_f, sw_f = sv.reshape(flat), sw.reshape(flat)
-    icu_f = None if inv_cu is None else inv_cu.reshape(flat)
-    icv_f = None if inv_cv is None else inv_cv.reshape(flat)
+    (rows_u, rank), rows_v = U.shape, V.shape[0]
+    if rows_u % k or rows_v % k:
+        raise ValueError(
+            f"table rows ({rows_u}, {rows_v}) must be divisible by "
+            f"num_blocks={k} — use the data.blocking / "
+            "data.device_blocking layouts")
+    if b % minibatch:
+        # a minibatch that spans two blocks would touch two row ranges
+        raise ValueError(
+            f"block size {b} must be a multiple of minibatch={minibatch} "
+            "— block with minibatch_multiple=minibatch")
+    rpb_u, rpb_v = rows_u // k, rows_v // k
+    # global rows -> block-local, once per call. Weight-0 padding entries
+    # carry global row 0, outside block p > 0: ``%`` lands them on local
+    # row 0, where their exactly-zero deltas change nothing
+    su_l = su % rpb_u
+    si_l = si % rpb_v
 
-    def step(carry, step_idx):
+    def visit(carry, v_idx):
         U, V = carry
-        s = step_idx % k
-        t = step_idx // k + 1 + jnp.asarray(t0, jnp.int32)
-        U, V = sgd_block_sweep(
-            U, V,
-            su_f[s], si_f[s], sv_f[s], sw_f[s],
-            omega_u, omega_v,
+        s = (v_idx // k) % k
+        p = v_idx % k
+        q = (p + s) % k
+        t = v_idx // (k * k) + 1 + jnp.asarray(t0, jnp.int32)
+        U_blk = jax.lax.dynamic_slice(U, (p * rpb_u, 0), (rpb_u, rank))
+        V_blk = jax.lax.dynamic_slice(V, (q * rpb_v, 0), (rpb_v, rank))
+        ou_blk = jax.lax.dynamic_slice(omega_u, (p * rpb_u,), (rpb_u,))
+        ov_blk = jax.lax.dynamic_slice(omega_v, (q * rpb_v,), (rpb_v,))
+        U_blk, V_blk = sgd_block_sweep(
+            U_blk, V_blk,
+            su_l[s, p], si_l[s, p], sv[s, p], sw[s, p],
+            ou_blk, ov_blk,
             updater, t, minibatch, collision,
-            None if icu_f is None else icu_f[s],
-            None if icv_f is None else icv_f[s],
+            None if inv_cu is None else inv_cu[s, p],
+            None if inv_cv is None else inv_cv[s, p],
         )
+        U = jax.lax.dynamic_update_slice(U, U_blk, (p * rpb_u, 0))
+        V = jax.lax.dynamic_update_slice(V, V_blk, (q * rpb_v, 0))
         return (U, V), None
 
     (U, V), _ = jax.lax.scan(
-        step, (U, V), jnp.arange(iterations * k, dtype=jnp.int32)
+        visit, (U, V), jnp.arange(iterations * k * k, dtype=jnp.int32)
     )
     if store_dtype != jnp.float32:
         U = U.astype(store_dtype)

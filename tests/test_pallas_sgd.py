@@ -208,12 +208,11 @@ def _full_training_pair(minibatch_divisor: int, schedule, iters: int = 3,
 @pytest.mark.parametrize("divisor", [1, 4])
 def test_full_training_matches_dsgd_train(divisor, gather):
     """dsgd_train_pallas (all strata × blocks × sweeps under one scan)
-    must equal ops.sgd.dsgd_train — at minibatch == block size (divisor
-    1: flat-stratum minibatches coincide with per-block visits) AND at
-    minibatch < block size (divisor 4: the stratum-major layout deals
-    entries block-major, so the flat chunk order still matches the
-    per-block minibatch order) — on both gather paths (loop is the
-    production path; take awaits a Mosaic that can gather across vregs)."""
+    must equal ops.sgd.dsgd_train, which makes the same block visits in
+    the same order — at minibatch == block size (divisor 1) AND at
+    minibatch < block size (divisor 4) — on both gather paths (loop is
+    the production path; take awaits a Mosaic that can gather across
+    vregs)."""
     (Uref, Vref), (Up, Vp) = _full_training_pair(divisor, constant_lr,
                                                  gather=gather)
     np.testing.assert_allclose(np.asarray(Up), np.asarray(Uref),

@@ -59,3 +59,57 @@ def test_stage1_flat_holds_the_scores_once(one_chip, b):
         shape = re.search(r"= f32\[([\d,]+)\]\S* copy\(", line)
         if shape:
             assert math.prod(map(int, shape[1].split(","))) < b * n, line
+
+
+def _inside_loops(hlo: str) -> str:
+    """The text of every computation a ``while`` of the module reaches
+    (its body and condition, and what they call, fuse or loop over)."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}",
+                            hlo, re.S | re.M))
+    calls = re.compile(
+        r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+    todo = [c for body in comps.values() for line in body.splitlines()
+            if " while(" in line for c in calls.findall(line)]
+    seen = set()
+    while todo:
+        name = todo.pop()
+        if name not in seen and name in comps:
+            seen.add(name)
+            todo += calls.findall(comps[name])
+    assert seen, "no loop found in the compiled program"
+    return "\n".join(comps[name] for name in seen)
+
+
+def test_dsgd_train_scatters_into_the_visited_blocks(one_chip):
+    """``ops.sgd.dsgd_train`` at the fit cell's widths (U ``f32[480192,
+    128]``, V ``f32[17792,128]``, ``k = 8``, minibatch 32768; two
+    minibatches a block are enough for the program's shape): both
+    scatter-adds land in one row block (a k-th of the table), and the
+    block's slice and write-back do not bring a copy of the whole U into
+    the loops. On the chip the scatter into the whole 246 MB table cost
+    nine times what it costs into a shard (PERF.md Findings, PR 32)."""
+    from large_scale_recommendation_tpu.core.updaters import (
+        RegularizedSGDUpdater,
+        warm_boost_lr,
+    )
+    from large_scale_recommendation_tpu.ops.sgd import dsgd_train
+
+    k, mb, rank, nu, nv = 8, 32768, 128, 480192, 17792
+    f32, i32 = jnp.float32, jnp.int32
+    sds = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    blocks = (k, k, 2 * mb)
+    compiled = dsgd_train.lower(
+        sds((nu, rank), f32), sds((nv, rank), f32),
+        sds(blocks, i32), sds(blocks, i32), sds(blocks, f32),
+        sds(blocks, f32), sds((nu,), f32), sds((nv,), f32),
+        sds(blocks, f32), sds(blocks, f32),
+        updater=RegularizedSGDUpdater(learning_rate=0.3, lambda_=0.1,
+                                      schedule=warm_boost_lr(0.75, 2)),
+        minibatch=mb, num_blocks=k, iterations=1).compile()
+    hlo = compiled.as_text()
+    scatters = re.findall(r"= (\w+\[[\d,]*\])\S* scatter\(", hlo)
+    assert sorted(set(scatters)) == [f"f32[{nv // k},{rank}]",
+                                     f"f32[{nu // k},{rank}]"], scatters
+    loops = _inside_loops(hlo)
+    assert " scatter(" in loops
+    assert not re.search(rf"= f32\[{nu},{rank}\]\S* copy\(", loops)
