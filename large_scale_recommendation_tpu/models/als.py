@@ -159,6 +159,7 @@ class ALS:
         from large_scale_recommendation_tpu.obs.instrument import (
             TrainSegmentTimer,
         )
+        from large_scale_recommendation_tpu.obs.registry import get_registry
         from large_scale_recommendation_tpu.obs.trace import get_tracer
 
         cfg = self.config
@@ -222,6 +223,8 @@ class ALS:
                     gram_dtype=gram_dtype)
                 h.out = (U, V)
             done += seg
+            if cfg.implicit_alpha is not None:
+                get_registry().counter("als_implicit_sweeps_total").inc(seg)
             # the host's time between sweeps
             with seam("fit/als/after_segment"):
                 if self.evaluator is not None:

@@ -210,6 +210,7 @@ from large_scale_recommendation_tpu.obs.lineage import (
 )
 from large_scale_recommendation_tpu.obs.quality import (
     OnlineEvaluator,
+    PercentileRankEvaluator,
     catalog_coverage,
     sampled_ranking_metrics,
 )
@@ -305,6 +306,7 @@ __all__ = [
     "DataQualityCheck",
     "DataQualityInspector",
     "OnlineEvaluator",
+    "PercentileRankEvaluator",
     "sampled_ranking_metrics",
     "catalog_coverage",
     "LineageJournal",

@@ -37,6 +37,10 @@ randomly. This module is the model-quality plane:
   batch retrain's quality trajectory lands in the same gauges/series as
   the online path's.
 
+- ``PercentileRankEvaluator`` — the same hook for implicit feedback:
+  expected percentile rank (Hu et al., eq. 8) of a held-out set of
+  interactions over the whole catalog, at each segment's end.
+
 Zero-cost when unused — the package discipline: everything here is
 opt-in (``StreamingDriver(evaluator=...)``, ``solver.evaluator = ...``)
 and every hook in the hot paths is one ``is not None`` test.
@@ -514,3 +518,32 @@ class OnlineEvaluator:
                 "rows_seen": seen,
                 "evaluations": self.evaluations,
                 "last_metrics": dict(self.last_metrics)}
+
+
+class PercentileRankEvaluator:
+    """Segment-boundary hook (``solver.evaluator = ...``) for an implicit
+    fit: the expected percentile rank of held-out interactions
+    (``utils.metrics.expected_percentile_rank``: the whole catalog ranked
+    for every pair, on the device) after each segment. ``history`` keeps
+    ``(step, rank)``; with a live registry the newest reading is the gauge
+    ``eval_percentile_rank{source=<segment label>}``. Row-space ids, as
+    ``OnlineEvaluator.set_offline_holdout``."""
+
+    def __init__(self, u_rows, i_rows, counts=None, item_mask=None):
+        self.u_rows = np.asarray(u_rows, np.int32)
+        self.i_rows = np.asarray(i_rows, np.int32)
+        self.counts = None if counts is None else np.asarray(counts)
+        self.item_mask = item_mask
+        self.history: list[tuple[int | None, float]] = []
+
+    def on_segment(self, U, V, label: str = "segment",
+                   step: int | None = None) -> float:
+        from large_scale_recommendation_tpu.utils.metrics import (
+            expected_percentile_rank,
+        )
+
+        rank = expected_percentile_rank(U, V, self.u_rows, self.i_rows,
+                                        self.counts, self.item_mask)
+        self.history.append((step, rank))
+        get_registry().gauge("eval_percentile_rank", source=label).set(rank)
+        return rank

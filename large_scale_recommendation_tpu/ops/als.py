@@ -205,13 +205,26 @@ def contraction_precision(dtype):
     return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
+# The most bytes of [rc, k, k] Gram matrices a chunk solves as one batch:
+# 512 rows at rank 128, where it was measured; a lower rank keeps its larger
+# batches, a higher one their bytes. A bucket is padded to whole chunks and
+# a padding row costs a whole Cholesky and two triangular solves, whose
+# cost a row is flat from 512-row batches up at rank 128 (14.0 + 10.2 us in
+# batches of 512 to 4096: PERF.md, Findings, PR 33). At the 4096 rows that
+# ``target_bytes`` alone allows there, a class whose size straddles a
+# multiple of 4096 moved a sweep by 0.11 s (0.7%) from one seed's data to
+# the next; at 512 the same flip is an eighth of that.
+GRAM_CHUNK_BYTES = 32 << 20
+
+
 def _chunk_geometry(nb: int, pad: int, k: int,
                     target_bytes: int) -> tuple[int, int, int]:
     """Row-chunk size for one bucket: pow2 ``rc`` (bounded compile
-    variants) such that both the [rc, pad, k] gather AND the [rc, k, k]
-    gram tensor stay ≤ target_bytes. Returns (rc, n_chunks, padded_nb)."""
+    variants) such that the [rc, pad, k] gather stays ≤ target_bytes and
+    the [rc, k, k] gram tensor ≤ min(target_bytes, ``GRAM_CHUNK_BYTES``).
+    Returns (rc, n_chunks, padded_nb)."""
     rc = max(1, min(target_bytes // (pad * k * 4),
-                    target_bytes // (k * k * 4)))
+                    min(target_bytes, GRAM_CHUNK_BYTES) // (k * k * 4)))
     rc = 1 << (rc.bit_length() - 1)  # floor pow2
     rc = min(rc, 1 << (max(nb - 1, 1)).bit_length())  # don't exceed ~nb
     n_chunks = -(-nb // rc)
@@ -415,17 +428,20 @@ def device_prepare_side(
 
 def publish_plan_sizes(side: str, prepared, n_ratings: int) -> None:
     """One side's plan on the registry (``obs.enable()``; nothing
-    otherwise): its real ratings, its padded slots (their ratio is what a
-    plan change moves: every padded slot is a row gathered and a Gram term
-    computed), its buckets and its chunks."""
+    otherwise): its real ratings, its padded slots and their ratio
+    ``als_plan_pad_ratio`` (what a plan change moves: every padded slot is
+    a row gathered and a Gram term computed; short power-law rows pad
+    more), its buckets and its chunks."""
     from large_scale_recommendation_tpu.obs.registry import get_registry
 
     obs = get_registry()
     if not obs.enabled:
         return
+    slots = sum(int(np.prod(b[1].shape)) for b in prepared)
     obs.gauge("als_plan_ratings", side=side).set(int(n_ratings))
-    obs.gauge("als_plan_padded_slots", side=side).set(
-        sum(int(np.prod(b[1].shape)) for b in prepared))
+    obs.gauge("als_plan_padded_slots", side=side).set(slots)
+    obs.gauge("als_plan_pad_ratio", side=side).set(
+        slots / max(int(n_ratings), 1))
     obs.gauge("als_plan_buckets", side=side).set(len(prepared))
     obs.gauge("als_plan_chunks", side=side).set(
         sum(int(b[1].shape[0]) for b in prepared))
@@ -596,9 +612,13 @@ def solve_side_local(
 
 @jax.jit
 def _full_gram(F):
-    return jnp.einsum("nk,nl->kl", F, F,
-                      precision=contraction_precision(F.dtype),
-                      preferred_element_type=jnp.float32)
+    """The fixed side's whole ``FᵀF``, shared by every row of an implicit
+    half-step (float32 products for a float32 table, as the per-row
+    contractions: ``contraction_precision``)."""
+    with jax.named_scope("als/shared_gram"):
+        return jnp.einsum("nk,nl->kl", F, F,
+                          precision=contraction_precision(F.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def als_rounds(V, prep_u, prep_v, num_u: int, num_v: int, lambda_: float,

@@ -365,6 +365,73 @@ def ranking_metrics(U, V, eval_u, eval_i, k: int = 10,
     return {"hr": hits / n, "ndcg": ndcg / n, "n": n}
 
 
+_PERCENTILE_KERNEL = None
+
+
+def _percentile_kernel():
+    """Jitted per-chunk percentile ranks (lazy, as ``_rank_kernel``)."""
+    global _PERCENTILE_KERNEL
+    if _PERCENTILE_KERNEL is None:
+        import jax
+        import jax.numpy as jnp
+
+        @jax.jit
+        def kern(U, V, eu, ei, item_ok):
+            # float32 products on a TPU too (its default multiplies float32
+            # in bfloat16, and near-ties of a converged model then flip)
+            scores = jnp.matmul(U[eu], V.T,
+                                precision=jax.lax.Precision.HIGHEST)
+            own = jnp.take_along_axis(scores, ei[:, None], axis=1)
+            above = jnp.sum((scores > own) & item_ok[None, :], axis=1)
+            ties = jnp.sum((scores == own) & item_ok[None, :], axis=1) - 1
+            return ((above + 0.5 * ties.astype(jnp.float32))
+                    / jnp.maximum(jnp.sum(item_ok) - 1, 1))
+
+        _PERCENTILE_KERNEL = kern
+    return _PERCENTILE_KERNEL
+
+
+def expected_percentile_rank(U, V, eval_u, eval_i, weights=None,
+                             item_mask=None, chunk: int = 2048) -> float:
+    """Expected percentile rank of held-out interactions (Hu, Koren and
+    Volinsky, ICDM 2008, eq. 8): over the pairs ``(eval_u, eval_i)``,
+    weighted by ``weights`` (the held-out counts ``r_ui``; None = 1), the
+    share of the catalog that the user scores above the held-out item.
+    0 is a perfect ranking, 0.5 what a random model gives; lower is
+    better. A tie counts half, so a user whose scores are all equal (a
+    zero row) reads 0.5 and not 0. Nothing is excluded from the ranked
+    list but the rows ``item_mask`` marks False (padding rows); the
+    held-out item must be a row it marks True.
+
+    Row-space ids into the tables, as ``ranking_metrics``; one
+    ``[chunk, n_items]`` score matrix at a time, on the device, so the
+    whole catalog is ranked for every pair."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    eval_u = np.asarray(eval_u, np.int32)
+    eval_i = np.asarray(eval_i, np.int32)
+    n = len(eval_u)
+    w = (np.ones(n, np.float64) if weights is None
+         else np.asarray(weights, np.float64))
+    if n == 0 or w.sum() <= 0:
+        return float("nan")
+    item_ok = jnp.asarray(np.ones(int(V.shape[0]), bool) if item_mask is None
+                          else np.asarray(item_mask, bool))
+    U, V = jnp.asarray(U, jnp.float32), jnp.asarray(V, jnp.float32)
+    kern = _percentile_kernel()
+    chunk = min(chunk, n)
+    total = 0.0
+    for c0 in range(0, n, chunk):
+        c = min(chunk, n - c0)
+        pad = chunk - c  # the tail chunk keeps the one compiled shape
+        ranks = kern(U, V, jnp.asarray(np.pad(eval_u[c0:c0 + c], (0, pad))),
+                     jnp.asarray(np.pad(eval_i[c0:c0 + c], (0, pad))),
+                     item_ok)
+        total += float(np.asarray(ranks, np.float64)[:c] @ w[c0:c0 + c])
+    return total / float(w.sum())
+
+
 _TOPK_KERNEL = None
 
 

@@ -226,6 +226,57 @@ class TestSolvePlan:
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+class TestChunkGeometry:
+    """A bucket is padded to whole chunks, and a padding row is a whole
+    solve: a chunk's Gram matrices hold at most ``GRAM_CHUNK_BYTES``, so
+    that the padding does not move a sweep from one seed's data to the
+    next, and the row count follows from the rank."""
+
+    @pytest.mark.parametrize("nb,pad,rc", [
+        (286_712, 64, 512),     # one row under 70 x 4096
+        (286_721, 64, 512),     # and one over: 561 chunks, not a 71st of 4096
+        (8_193, 256, 512),
+        (20, 65_536, 8),        # the gather's bytes still bound a wide class
+        (3, 8, 4)])             # and a small class is not padded past ~nb
+    def test_rows_of_padding_stay_under_a_chunk_of_512(self, nb, pad, rc):
+        got, n_chunks, padded = als_ops._chunk_geometry(nb, pad, 128,
+                                                        256 << 20)
+        assert got == rc and padded == n_chunks * rc
+        assert nb <= padded < nb + min(rc, 512)
+        assert got * pad * 128 * 4 <= 256 << 20
+
+    @pytest.mark.parametrize("k,target,rc", [
+        (16, 256 << 20, 32_768),  # a low rank keeps large batches
+        (64, 256 << 20, 2_048),
+        (128, 256 << 20, 512),
+        (128, 64 << 20, 512),     # the mesh plan's target
+        (256, 256 << 20, 128),
+        (128, 8 << 20, 128)])     # a smaller target still binds
+    def test_the_rows_of_a_chunk_follow_from_the_rank(self, k, target, rc):
+        got, _, _ = als_ops._chunk_geometry(1 << 20, 8, k, target)
+        assert got == rc
+        assert got * k * k * 4 <= min(target, als_ops.GRAM_CHUNK_BYTES)
+
+    def test_the_chunk_size_does_not_change_a_solved_row(self, monkeypatch):
+        rng = np.random.default_rng(5)
+        rows = rng.integers(0, 700, 9000)
+        other = rng.integers(0, 50, 9000)
+        vals = rng.normal(size=9000).astype(np.float32)
+        F = jnp.asarray(rng.normal(size=(50, 8)).astype(np.float32))
+
+        def solved():
+            prep = als_ops.device_prepare_side(rows, other, vals, 700,
+                                               rank_for_chunking=8)
+            return (np.asarray(als_ops.solve_side(F, prep, 700, 0.1)),
+                    max(b[0].shape[1] for b in prep))
+
+        small, rc_small = solved()
+        monkeypatch.setattr(als_ops, "GRAM_CHUNK_BYTES", 64 * 8 * 8 * 4)
+        smaller, rc_smaller = solved()
+        assert rc_small > rc_smaller == 64
+        np.testing.assert_allclose(smaller, small, rtol=0, atol=1e-5)
+
+
 class TestDevicePreparedPlans:
     """On-device plan build (``device_prepare_side``) must solve to the
     same per-row answers as the host build — bucket organization is
@@ -713,6 +764,45 @@ class TestRankingQuality:
         assert m6["ndcg"] >= m1["ndcg"] - 0.02, (m1, m6)
         assert m6["hr"] > floor["hr"] + 0.1, (m6, floor)
 
+    def test_expected_percentile_rank_matches_a_numpy_oracle(self):
+        """Hu et al.'s eq. 8 over the whole catalog: weighted by the
+        held-out counts, ties half a place, masked rows off the list, the
+        tail chunk padded to the one compiled shape."""
+        from large_scale_recommendation_tpu.utils.metrics import (
+            expected_percentile_rank,
+        )
+
+        rng = np.random.default_rng(4)
+        nu, ni, r = 30, 45, 5
+        U = rng.normal(size=(nu, r)).astype(np.float32)
+        V = rng.normal(size=(ni, r)).astype(np.float32)
+        V[7] = V[3]  # a tie in every user's list
+        mask = np.ones(ni, bool)
+        mask[-5:] = False
+        eu = rng.integers(0, nu, 300)
+        ei = rng.integers(0, ni - 5, 300)
+        w = rng.integers(1, 9, 300).astype(np.float32)
+        S = (U.astype(np.float64) @ V.astype(np.float64).T)
+        S32 = (U @ V.T)
+
+        def oracle(mask, w):
+            num = 0.0
+            for u, i, c in zip(eu, ei, w):
+                row = S32[u]
+                above = ((row > row[i]) & mask).sum()
+                ties = ((row == row[i]) & mask).sum() - 1
+                num += c * (above + 0.5 * ties) / (mask.sum() - 1)
+            return num / w.sum()
+
+        assert np.abs(S - S32).max() < 1e-5
+        for m, ww, chunk in ((mask, w, 128), (None, None, 2048),
+                             (mask, None, 7)):
+            got = expected_percentile_rank(U, V, eu, ei, ww, m, chunk=chunk)
+            want = oracle(np.ones(ni, bool) if m is None else m,
+                          np.ones(300) if ww is None else ww)
+            assert got == pytest.approx(want, abs=1e-6)
+        assert np.isnan(expected_percentile_rank(U, V, [], []))
+
     def test_padding_rows_never_rank(self):
         """Block-padded factor tables hold random-init rows with no item
         behind them — they must be masked out of the ranked catalog
@@ -1183,3 +1273,194 @@ class TestAgainstPlainReference:
         assert np.abs(faulty - got).max() > 1e-2 * np.abs(got).max()
         np.testing.assert_allclose(faulty[counts == 1], got[counts == 1],
                                    atol=1e-6)
+
+
+class TestImplicitAgainstPlainReference:
+    """The implicit path (``implicit_alpha`` set: weighted ALS of Hu, Koren
+    and Volinsky) against the benchmark's plain reference
+    (``benchmark/reference/ials_ref.py``: its own sort, its own row blocks,
+    no padding classes, ``highest``) on seeded power-law interactions: a
+    few hot items, many short user rows, a user and an item never seen."""
+
+    NU, NI, RANK = 260, 90, 8
+    ALPHA, LAM = 6.0, 0.4
+
+    def _data(self, seed=21):
+        rng = np.random.default_rng(seed)
+        deg = np.minimum(3 + rng.zipf(1.7, self.NU), self.NI // 2)
+        deg[-1] = 0  # a user never seen
+        items = np.arange(self.NI - 1)  # the last item: never seen
+        pop = 1.0 / (items + 2.0)
+        u, i = [], []
+        for user, d in enumerate(deg):
+            # two tastes: a user prefers the items of its own parity
+            p = pop * np.where(items % 2 == user % 2, 8.0, 1.0)
+            u += [user] * d
+            i += rng.choice(items, d, replace=False, p=p / p.sum()).tolist()
+        r = np.minimum(np.floor(rng.pareto(1.5, len(u)) + 1.0), 50.0)
+        perm = rng.permutation(len(u))
+        return (np.asarray(u, np.int32)[perm], np.asarray(i, np.int32)[perm],
+                r.astype(np.float32)[perm])
+
+    def _split(self, seed=21):
+        u, i, r = self._data(seed)
+        cut = int(0.9 * len(u))
+        return (u[:cut], i[:cut], r[:cut]), (u[cut:], i[cut:], r[cut:])
+
+    def _cfg(self):
+        return {"num_users": self.NU, "num_items": self.NI,
+                "num_factors": self.RANK, "alpha": self.ALPHA,
+                "lambda": self.LAM, "init_scale": 0.1}
+
+    def _als(self, iterations, **kw):
+        return ALS(ALSConfig(num_factors=self.RANK, lambda_=self.LAM,
+                             iterations=iterations, reg_mode="direct",
+                             implicit_alpha=self.ALPHA, seed=0,
+                             init_scale=0.1, **kw))
+
+    def test_tables_and_rank_after_one_and_two_sweeps(self):
+        from benchmark.reference import ials_ref
+        from large_scale_recommendation_tpu.obs.quality import (
+            PercentileRankEvaluator,
+        )
+
+        (u, i, r), (hu, hi, hr) = self._split()
+        solver = self._als(2)
+        solver.evaluator = PercentileRankEvaluator(hu, hi, hr)
+        kept = []
+        on_segment = solver.evaluator.on_segment
+
+        def hook(U, V, label="segment", step=None):
+            kept.append((np.asarray(U), np.asarray(V)))
+            return on_segment(U, V, label=label, step=step)
+
+        solver.evaluator.on_segment = hook
+        solver.fit_device(u, i, r, self.NU, self.NI, checkpoint_every=1)
+        ref = ials_ref.fit(jnp.asarray(u), jnp.asarray(i), jnp.asarray(r),
+                           self._cfg(), 2)
+        assert not bool(ref["seen"][0][-1]) and not bool(ref["seen"][1][-1])
+        for (U, V), (RU, RV) in zip(kept, ref["sweeps"]):
+            for got, want in ((U, np.asarray(RU)), (V, np.asarray(RV))):
+                np.testing.assert_allclose(
+                    got, want, rtol=0, atol=2e-4 * np.abs(want).max())
+            assert not U[-1].any() and not V[-1].any()  # never seen: zero
+        # the program's rank (row space, its own kernel) is the reference's
+        ranks = [ials_ref.expected_percentile_rank(
+            RU, RV, *ref["seen"], jnp.asarray(hu), jnp.asarray(hi),
+            jnp.asarray(hr)) for RU, RV in ref["sweeps"]]
+        seen = np.asarray(ref["seen"][0])[hu] & np.asarray(ref["seen"][1])[hi]
+        mine = PercentileRankEvaluator(hu[seen], hi[seen], hr[seen])
+        for (U, V), want in zip(kept, ranks):
+            assert mine.on_segment(U, V) == pytest.approx(want, abs=2e-4)
+        assert [s for s, _ in solver.evaluator.history] == [1, 2]
+        assert ranks[1] < 0.4  # and it ranks: chance is 0.5
+
+    def test_hu_objective_does_not_rise_over_any_half_step(self):
+        """sum over ALL pairs of c_ui (p_ui - x_u.y_i)^2 + lambda (|X|^2 +
+        |Y|^2), dense in float64, after each of six half-steps."""
+        u, i, r = self._data(seed=22)
+        k = self.RANK
+        C = np.ones((self.NU, self.NI))
+        P = np.zeros((self.NU, self.NI))
+        C[u, i] += self.ALPHA * r
+        P[u, i] = 1.0
+
+        def objective(X, Y):
+            X, Y = np.asarray(X, np.float64), np.asarray(Y, np.float64)
+            return float((C * (P - X @ Y.T) ** 2).sum()
+                         + self.LAM * ((X ** 2).sum() + (Y ** 2).sum()))
+
+        prep_u = als_ops.implicit_prepared(als_ops.device_prepare_side(
+            u, i, r, self.NU, rank_for_chunking=k), self.ALPHA)
+        prep_v = als_ops.implicit_prepared(als_ops.device_prepare_side(
+            i, u, r, self.NI, rank_for_chunking=k), self.ALPHA)
+        rng = np.random.default_rng(0)
+        X = jnp.asarray(rng.uniform(0, 0.1, (self.NU, k)).astype(np.float32))
+        Y = jnp.asarray(rng.uniform(0, 0.1, (self.NI, k)).astype(np.float32))
+        seen = [objective(X, Y)]
+        for _ in range(3):
+            X = als_ops.solve_side(Y, prep_u, self.NU, self.LAM,
+                                   als_ops._full_gram(Y))
+            seen.append(objective(X, Y))
+            Y = als_ops.solve_side(X, prep_v, self.NI, self.LAM,
+                                   als_ops._full_gram(X))
+            seen.append(objective(X, Y))
+        for before, after in zip(seen, seen[1:]):
+            assert after <= before * (1 + 1e-6), seen
+        assert seen[-1] < 0.5 * seen[0]
+
+    def test_one_sweep_segments_equal_one_call_on_power_law_rows(self):
+        u, i, r = self._data(seed=23)
+        one = self._als(3).fit_device(u, i, r, self.NU, self.NI)
+        seg = self._als(3).fit_device(u, i, r, self.NU, self.NI,
+                                      checkpoint_every=1)
+        np.testing.assert_array_equal(np.asarray(one.U), np.asarray(seg.U))
+        np.testing.assert_array_equal(np.asarray(one.V), np.asarray(seg.V))
+
+    @pytest.mark.parametrize("side", ["user", "item"])
+    def test_device_plan_reweighted_is_the_host_plan_bit_for_bit(self, side):
+        """``implicit_prepared`` over ``device_prepare_side`` against
+        ``prepare_side(..., implicit_alpha=)`` over ``build_solve_plan``:
+        the same pad classes, rows, partners, confidences and Gram weights,
+        on rows from 4 entries to half of the other side."""
+        u, i, r = self._data(seed=24)
+        rows, other, n = ((u, i, self.NU) if side == "user"
+                          else (i, u, self.NI))
+        k = self.RANK
+        host = als_ops.prepare_side(
+            als_ops.build_solve_plan(rows, other, r, n), None, k,
+            implicit_alpha=self.ALPHA)
+        device = als_ops.implicit_prepared(
+            als_ops.device_prepare_side(rows, other, r, n,
+                                        rank_for_chunking=k), self.ALPHA)
+        assert len(host) == len(device) >= 3  # several pad classes
+
+        def by_row(bucket):
+            # the smallest classes share the min_pad bucket: the host plan
+            # lists its rows by id, the device plan class by class
+            rows, *slots = (np.asarray(a) for a in bucket)
+            order = np.argsort(rows.reshape(-1), kind="stable")
+            return [rows.reshape(-1)[order]] + [
+                a.reshape(rows.size, -1)[order] for a in slots]
+
+        for bh, bd in zip(host, device):
+            assert bh[1].shape == bd[1].shape  # the same chunk geometry
+            for ah, ad in zip(by_row(bh), by_row(bd)):
+                np.testing.assert_array_equal(ad, ah)
+
+    def test_implicit_half_step_asks_for_float32_products(self):
+        """The shared Gram, the confidence-weighted Gram and the
+        confidence-weighted right-hand side of an implicit half-step name
+        ``HIGHEST`` for float32 inputs (a CPU run cannot see the chip's
+        default precision); the re-weighting itself has no product of two
+        tables."""
+        F = jnp.ones((6, 4), jnp.float32)
+        ones = jnp.ones((1, 2, 3), jnp.float32)
+        step = jax.make_jaxpr(
+            lambda F, out, rows, oi, va, wi, sc: als_ops._solve_bucket(
+                F, out, rows, oi, va, wi, sc, jnp.float32(0.1),
+                als_ops._full_gram(F)))(
+            F, jnp.zeros((7, 4), jnp.float32), jnp.zeros((1, 2), jnp.int32),
+            jnp.zeros((1, 2, 3), jnp.int32), ones, ones,
+            jnp.ones((1, 2), jnp.float32))
+
+        def eqns(jaxpr):
+            for e in jaxpr.eqns:
+                yield e
+                for v in e.params.values():
+                    inner = getattr(v, "jaxpr", None)
+                    if inner is not None:
+                        yield from eqns(getattr(inner, "jaxpr", inner))
+
+        dots = [e for e in eqns(step.jaxpr)
+                if e.primitive.name == "dot_general"]
+        # F^T F, the weighted Gram and the weighted right-hand side
+        assert [tuple(v.aval.shape for v in e.invars) for e in dots] == [
+            ((6, 4), (6, 4)), ((2, 3, 4), (2, 3, 4)), ((2, 3, 4), (2, 3))]
+        for e in dots:
+            assert {str(x).rsplit(".", 1)[-1]
+                    for x in np.ravel(e.params["precision"])} == {"HIGHEST"}
+        assert not [e for e in eqns(jax.make_jaxpr(als_ops._implicit_bucket)(
+            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1, 2, 3), jnp.int32),
+            ones, ones, jnp.ones((1, 2)), jnp.float32(2.0)).jaxpr)
+            if e.primitive.name == "dot_general"]

@@ -203,6 +203,79 @@ def test_als_plan_sizes_reach_the_registry(null_obs):
             ("als_plan_chunks", side)]
 
 
+def test_implicit_fit_publishes_its_pad_ratio_and_its_sweeps(null_obs):
+    """``als_plan_pad_ratio{side}`` (padded slots over real entries) from
+    any ``fit_device``; ``als_implicit_sweeps_total`` only with
+    ``implicit_alpha`` set, one a sweep whatever the segment length."""
+    _, (ru, ri, rv) = _ratings()
+    strength = np.abs(rv) + 1.0
+
+    def fitted(**kw):
+        registry, _ = obs.enable()
+        try:
+            ALS(ALSConfig(num_factors=RANK, lambda_=0.05, iterations=3,
+                          **kw)).fit_device(ru, ri, strength, NU, NI,
+                                            checkpoint_every=2)
+            return registry.snapshot()["metrics"]
+        finally:
+            obs.disable()
+
+    implicit, explicit = fitted(implicit_alpha=4.0), fitted()
+    for got in (implicit, explicit):
+        by = {(m["name"], m["labels"].get("side")): m.get("value")
+              for m in got}
+        for side in ("user", "item"):
+            assert by[("als_plan_pad_ratio", side)] == pytest.approx(
+                by[("als_plan_padded_slots", side)]
+                / by[("als_plan_ratings", side)])
+            assert 1.0 <= by[("als_plan_pad_ratio", side)] < 4.0
+    assert [m["value"] for m in implicit
+            if m["name"] == "als_implicit_sweeps_total"] == [3.0]
+    assert not [m for m in explicit
+                if m["name"] == "als_implicit_sweeps_total"]
+
+
+def test_the_shared_gram_is_a_scope_of_the_implicit_half_step():
+    """``als/shared_gram`` is HLO metadata inside ``_full_gram`` (a named
+    scope, not a seam: ``SEAMS`` keeps host spans), and the implicit
+    ``als_rounds`` runs that program twice a sweep."""
+    import jax.numpy as jnp
+
+    from large_scale_recommendation_tpu.ops import als as als_ops
+
+    assert not any("shared_gram" in name for name in SEAMS)
+    text = als_ops._full_gram.lower(jnp.ones((6, 4))).as_text(
+        debug_info=True)
+    assert "als/shared_gram" in text
+    calls = []
+    real = als_ops._full_gram
+    try:
+        als_ops._full_gram = lambda F: calls.append(F.shape) or real(F)
+        _, (ru, ri, rv) = _ratings()
+        prep_u = als_ops.device_prepare_side(ru, ri, rv, NU,
+                                             rank_for_chunking=RANK)
+        prep_v = als_ops.device_prepare_side(ri, ru, rv, NI,
+                                             rank_for_chunking=RANK)
+        V = jnp.ones((NI, RANK), jnp.float32)
+        als_ops.als_rounds(V, prep_u, prep_v, NU, NI, 0.1, 2, implicit=True)
+        assert calls == [(NI, RANK), (NU, RANK)] * 2
+        als_ops.als_rounds(V, prep_u, prep_v, NU, NI, 0.1, 1)
+        assert len(calls) == 4  # the explicit objective runs none
+    finally:
+        als_ops._full_gram = real
+
+
+def test_the_docs_list_the_scope_the_gauge_and_the_counter():
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "docs", "OBSERVABILITY.md")) as f:
+        text = f.read()
+    rows = [line for line in text.splitlines() if line.startswith("| `")]
+    for name in ("als_plan_pad_ratio{side}", "als_implicit_sweeps_total",
+                 "eval_percentile_rank{source=}"):
+        assert any(f"`{name}`" in r.split("|")[1] for r in rows), name
+    assert "`als/shared_gram`" in text
+
+
 def test_unknown_seam_is_refused():
     with pytest.raises(ValueError, match="not a seam"):
         get_tracer().seam("serving/flush")
