@@ -9,8 +9,8 @@ second offline algorithm behind the same fit/predict surface as DSGD.
 The solver uses the bucketed-matmul formulation (``ops.als``): a one-time
 host plan sorts each orientation by output row and pads per-row rating
 lists to power-of-2 buckets, so gram assembly is batched ``[rows, pad, k]``
-einsums and the solve is batched Cholesky — all MXU work, no scatter in the
-hot path (the ALX-style formulation, see PAPERS.md) rather than MLlib's
+einsums (MXU work) and the solve is a batched float32 Cholesky (on a TPU the
+lanes kernel of ``ops.pallas_als``, vector work) — no scatter in the hot path (the ALX-style formulation, see PAPERS.md) rather than MLlib's
 block-routed LAPACK calls.
 
 Precision: float32 tables, and with ``gram_dtype=None`` the Gram matrices,

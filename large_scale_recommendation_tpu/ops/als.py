@@ -18,8 +18,15 @@ handful of jitted device calls shaped for the MXU:
                         (TPU scatter with duplicate indices is latency-bound;
                         round 2's chunked scatter-add of outer products ran
                         at ~0.004% MFU — VERDICT r2 weak #2),
-    solve               (A + λ·s·I) x = b for ALL rows at once — batched
-                        Cholesky + triangular solves, k×k systems on the MXU.
+    solve               (A + λ·s·I) x = b for a chunk's rows at once: a
+                        float32 Cholesky factorization and two
+                        substitutions. On a TPU one system is one lane of
+                        a Pallas kernel (``ops.pallas_als``: every step of
+                        the recurrence is a vector operation over 128
+                        systems, 0.4 us a system at rank 128); elsewhere
+                        XLA's ``cholesky`` + ``triangular_solve``
+                        (``solve_normal_eq`` picks, ``solve_path`` says
+                        which).
 
 Regularization modes:
 - ``"direct"``: s_u = 1 (plain λ·I — MLlib ``ALS.train``'s regParam
@@ -53,6 +60,11 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from large_scale_recommendation_tpu.ops.pallas_als import (
+    lanes_fits,
+    solve_lanes,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -136,8 +148,9 @@ def _solve_bucket(
 
     Per chunk: gather the fixed side's rows ``[rc, pad, k]``, batch-contract
     the per-row grams (two einsums — real MXU matmuls), Cholesky-solve the
-    chunk, and set the solved rows (unique by construction; chunk-padding
-    dummies target the extra last row of ``out``). Peak memory is one
+    chunk (``solve_normal_eq``), and set the solved rows (unique by
+    construction; chunk-padding dummies target the extra last row of
+    ``out``). Peak memory is one
     chunk's gather, not the [num_rows, k, k] gram tensor — which at rank
     256 would not even fit in HBM.
     """
@@ -208,12 +221,12 @@ def contraction_precision(dtype):
 # The most bytes of [rc, k, k] Gram matrices a chunk solves as one batch:
 # 512 rows at rank 128, where it was measured; a lower rank keeps its larger
 # batches, a higher one their bytes. A bucket is padded to whole chunks and
-# a padding row costs a whole Cholesky and two triangular solves, whose
-# cost a row is flat from 512-row batches up at rank 128 (14.0 + 10.2 us in
-# batches of 512 to 4096: PERF.md, Findings, PR 33). At the 4096 rows that
-# ``target_bytes`` alone allows there, a class whose size straddles a
-# multiple of 4096 moved a sweep by 0.11 s (0.7%) from one seed's data to
-# the next; at 512 the same flip is an eighth of that.
+# a padding row is gathered, multiplied into a Gram matrix and solved like a
+# real one. At the 4096 rows that ``target_bytes`` alone allows at rank 128,
+# a class whose size straddles a multiple of 4096 moved a sweep by 0.11 s
+# from one seed's data to the next while XLA's solve cost 24 us a row
+# (PERF.md, Findings, PR 33); at 512 the same flip is an eighth of that.
+# The lanes kernel solves four tiles of 128 systems a chunk at rank 128.
 GRAM_CHUNK_BYTES = 32 << 20
 
 
@@ -493,6 +506,7 @@ def solve_side(
     lam = jnp.float32(lambda_)
     for chunked in prepared:
         out = _solve_bucket(factors_other, out, *chunked, lam, G)
+    count_solves(k, next(iter(out.devices())).platform, len(prepared))
     return out[:num_rows]
 
 
@@ -718,20 +732,37 @@ def gram_stats(
     return A, b
 
 
-def solve_normal_eq(
-    A: jax.Array,  # float32[n, k, k]
-    b: jax.Array,  # float32[n, k]
-    lambda_: jax.Array | float,
-    reg_scale: jax.Array | None = None,  # float32[n]; None → 1 (direct λ)
-) -> jax.Array:
-    """Solve (A + λ·s·I) x = b for every row — batched Cholesky."""
-    k = A.shape[-1]
-    s = jnp.ones(A.shape[0], jnp.float32) if reg_scale is None else reg_scale
-    # empty rows (s could be 0 under als_wr): keep the system PD with λ·I
-    s = jnp.maximum(s, 1.0)
-    ridge = (jnp.float32(lambda_) * s)[:, None, None] * jnp.eye(k, dtype=jnp.float32)
-    L = jnp.linalg.cholesky(A + ridge)
-    # two batched triangular solves: L y = b ; Lᵀ x = y
+def solve_path(k: int, platform: str, vma_checked: bool = False) -> str:
+    """Which way ``solve_normal_eq`` solves systems of rank ``k`` on
+    ``platform``, read off what it sees itself (the label of
+    ``als_solve_total{path}``): ``lanes`` is the Pallas kernel of
+    ``ops.pallas_als`` (a TPU, and a tile of 128 systems at this rank
+    inside that kernel's VMEM budget), ``xla`` the compiler's own
+    ``cholesky`` and ``triangular_solve``. ``vma_checked``: the solve is
+    traced inside a ``shard_map`` that types its values by mesh axis
+    (``MeshALS`` without rank sharding), where this JAX cannot type a
+    kernel's scratch memory: XLA's routine there too."""
+    return ("lanes" if platform == "tpu" and lanes_fits(k)
+            and not vma_checked else "xla")
+
+
+def count_solves(k: int, platform: str, buckets: int,
+                 vma_checked: bool = False) -> None:
+    """``buckets`` bucket solves on the live registry (``obs.enable()``;
+    nothing otherwise), under the path they took. Called from the host
+    beside a half-step's dispatch, never from a traced function."""
+    from large_scale_recommendation_tpu.obs.registry import get_registry
+
+    get_registry().counter(
+        "als_solve_total",
+        path=solve_path(k, platform, vma_checked)).inc(buckets)
+
+
+def _solve_xla(M, b):
+    """XLA's own batched Cholesky and two triangular solves: L y = b,
+    then Lᵀ x = y. On a TPU its expansion walks the batch one matrix
+    after another (24 us a system at rank 128, whatever the batch)."""
+    L = jnp.linalg.cholesky(M)
     y = jax.lax.linalg.triangular_solve(
         L, b[..., None], left_side=True, lower=True
     )
@@ -739,6 +770,33 @@ def solve_normal_eq(
         L, y, left_side=True, lower=True, transpose_a=True
     )
     return x[..., 0]
+
+
+def solve_normal_eq(
+    A: jax.Array,  # float32[n, k, k]
+    b: jax.Array,  # float32[n, k]
+    lambda_: jax.Array | float,
+    reg_scale: jax.Array | None = None,  # float32[n]; None → 1 (direct λ)
+) -> jax.Array:
+    """Solve (A + λ·s·I) x = b for every row: a float32 Cholesky
+    factorization and two substitutions. The one entry, two routines
+    (``solve_path``): on a TPU, at a rank whose tile fits the kernel's
+    VMEM budget, ``pallas_als.solve_lanes`` (one system a lane, every step
+    of the recurrence a vector operation over 128 systems); XLA's batched
+    ``cholesky`` + ``triangular_solve`` everywhere else, and where ``A``
+    is typed as varying over a mesh axis. The platform is the one the
+    program is lowered for (``lax.platform_dependent``), so a compile for
+    a described TPU takes the TPU's branch."""
+    k = A.shape[-1]
+    s = jnp.ones(A.shape[0], jnp.float32) if reg_scale is None else reg_scale
+    # empty rows (s could be 0 under als_wr): keep the system PD with λ·I
+    s = jnp.maximum(s, 1.0)
+    ridge = (jnp.float32(lambda_) * s)[:, None, None] * jnp.eye(k, dtype=jnp.float32)
+    M = A + ridge
+    if solve_path(k, "tpu", bool(jax.typeof(A).vma)) == "xla":
+        return _solve_xla(M, b)  # what a TPU would take as well
+    return jax.lax.platform_dependent(M, b, tpu=solve_lanes,
+                                      default=_solve_xla)
 
 
 # NOTE: the single-jit scatter-add ``als_train`` that round 2 shipped is

@@ -304,5 +304,11 @@ class MeshALS:
             *(part.place(a, "ratings") for b in user_plan for a in b),
             *(part.place(a, "ratings") for b in item_plan for a in b),
         )
+        # a shard's bucket solves, by the routine solve_normal_eq takes
+        # inside this step's shard_map
+        als_ops.count_solves(
+            cfg.num_factors, part.mesh.devices.flat[0].platform,
+            cfg.iterations * (len(user_plan) + len(item_plan)),
+            vma_checked=part.model_parallel == 1)
         self.model = MFModel(U=U, V=V, users=users, items=items)
         return self.model

@@ -77,6 +77,133 @@ class TestGramAndSolve:
         np.testing.assert_array_equal(np.asarray(x), 0.0)
 
 
+def _lanes(A, b, lam, reg_scale=None):
+    """``solve_normal_eq`` with its TPU branch taken and the kernel in
+    Pallas's interpreter: the steering is the test's (the program picks
+    its branch from the platform it is lowered for, and has no option)."""
+    from unittest import mock
+
+    from large_scale_recommendation_tpu.ops import pallas_als
+
+    with mock.patch.object(
+            jax.lax, "platform_dependent",
+            lambda *a, tpu, default: pallas_als.solve_lanes(
+                *a, interpret=True)):
+        return np.asarray(als_ops.solve_normal_eq(
+            jnp.asarray(A), jnp.asarray(b), lam, reg_scale))
+
+
+def _xla(A, b, lam, reg_scale=None):
+    return np.asarray(als_ops.solve_normal_eq(
+        jnp.asarray(A), jnp.asarray(b), lam, reg_scale))
+
+
+def _worst(x, want):
+    """Worst system's error, relative to that system's largest entry."""
+    return float((np.abs(x - want).max(axis=1)
+                  / np.abs(want).max(axis=1)).max())
+
+
+class TestSolveLanes:
+    """The kernel with the batch along the lanes against
+    ``numpy.linalg.solve`` in float64 and against XLA's routine on the
+    same inputs. ``FACTOR``: the kernel may read this many times XLA's
+    error and no more (on the chip at ranks 32 to 128 it read 1.9 to 2.3
+    times XLA's, both at float32 rounding: PERF.md, Findings, PR 34)."""
+
+    FACTOR = 4.0
+
+    @staticmethod
+    def _systems(n, k, entries, seed):
+        rng = np.random.default_rng(seed)
+        g = rng.normal(0, 0.3, (n, entries, k)).astype(np.float32)
+        A = np.einsum("npk,npl->nkl", g, g).astype(np.float32)
+        b = np.einsum("npk,np->nk", g, rng.normal(
+            size=(n, entries)).astype(np.float32)).astype(np.float32)
+        return A, b
+
+    @pytest.mark.parametrize("scaled", [False, True],
+                             ids=["direct", "reg_scale"])
+    @pytest.mark.parametrize("n", [1, 127, 512, 513])
+    @pytest.mark.parametrize("k", [16, 64, 128])
+    def test_matches_float64(self, k, n, scaled):
+        A, b = self._systems(n, k, 2 * k, seed=k + n)
+        lam = 0.05
+        scale = (np.random.default_rng(n).integers(0, 40, n)
+                 .astype(np.float32) if scaled else None)
+        s = np.ones(n) if scale is None else np.maximum(scale, 1.0)
+        want = np.linalg.solve(
+            A.astype(np.float64) + (lam * s)[:, None, None] * np.eye(k),
+            b.astype(np.float64)[..., None])[..., 0]
+        js = None if scale is None else jnp.asarray(scale)
+        got, xla = _lanes(A, b, lam, js), _xla(A, b, lam, js)
+        assert got.shape == (n, k)
+        assert _worst(got, want) <= max(self.FACTOR * _worst(xla, want),
+                                        2e-6)
+        np.testing.assert_allclose(got, xla, rtol=0,
+                                   atol=2e-5 * np.abs(want).max())
+
+    @pytest.mark.parametrize("k", [16, 64, 128])
+    def test_few_ratings_and_a_small_ridge(self, k):
+        """Gram matrices of 8 ratings (rank 8 of ``k``) held up by a ridge
+        of 0.005 alone, the condition of a short row in the ALS cell."""
+        A, b = self._systems(130, k, 8, seed=7)
+        lam = 0.005
+        want = np.linalg.solve(A.astype(np.float64) + lam * np.eye(k),
+                               b.astype(np.float64)[..., None])[..., 0]
+        got, xla = _lanes(A, b, lam), _xla(A, b, lam)
+        assert _worst(got, want) <= self.FACTOR * _worst(xla, want)
+
+    @pytest.mark.parametrize("k,n", [(16, 3), (64, 130)])
+    def test_zero_rows_solve_to_exactly_zero(self, k, n):
+        """The padding-row contract: ``A = 0``, ``b = 0`` gives ``x = 0``
+        with no masking, beside rows that solve to something."""
+        A, b = self._systems(n, k, k, seed=1)
+        A[::2], b[::2] = 0.0, 0.0
+        got = _lanes(A, b, 0.1, jnp.zeros(n))
+        assert not got[::2].any() and not np.signbit(got[::2]).any()
+        assert np.abs(got[1::2]).min(axis=1).max() > 0
+
+    def test_the_path_is_read_off_rank_platform_and_typing(self):
+        from large_scale_recommendation_tpu.ops import pallas_als
+
+        assert als_ops.solve_path(128, "tpu") == "lanes"
+        assert als_ops.solve_path(16, "tpu") == "lanes"
+        assert als_ops.solve_path(128, "cpu") == "xla"
+        assert als_ops.solve_path(128, "tpu", vma_checked=True) == "xla"
+        assert als_ops.solve_path(12, "tpu") == "xla"  # half a sublane group
+        assert als_ops.solve_path(256, "tpu") == "xla"  # 96 MiB a tile
+        assert (pallas_als.lanes_vmem_bytes(128)
+                <= pallas_als.LANES_VMEM_BUDGET
+                < pallas_als.lanes_vmem_bytes(256))
+        with pytest.raises(ValueError, match="outside the lanes kernel"):
+            pallas_als.solve_lanes(jnp.zeros((2, 12, 12)), jnp.zeros((2, 12)),
+                                   interpret=True)
+
+    def test_a_fit_counts_its_bucket_solves_by_path(self):
+        """``als_solve_total{path}``: one a bucket a half-step, on the
+        live registry, under the path of this platform (``xla`` here)."""
+        from large_scale_recommendation_tpu import obs
+
+        rng = np.random.default_rng(5)
+        u, i = rng.integers(0, 60, 900), rng.integers(0, 40, 900)
+        r = rng.normal(size=900).astype(np.float32)
+        prep_u = als_ops.device_prepare_side(u, i, r, 60,
+                                             rank_for_chunking=8)
+        prep_v = als_ops.device_prepare_side(i, u, r, 40,
+                                             rank_for_chunking=8)
+        registry, _ = obs.enable()
+        try:
+            als_ops.als_rounds(jnp.ones((40, 8)), prep_u, prep_v, 60, 40,
+                               0.1, 2)
+            got = {m["labels"]["path"]: m["value"]
+                   for m in registry.snapshot()["metrics"]
+                   if m["name"] == "als_solve_total"}
+        finally:
+            obs.disable()
+        assert got == {"xla": 2 * (len(prep_u) + len(prep_v))}
+
+
 class TestALS:
     def test_one_iteration_matches_numpy_oracle(self):
         """One full ALS round equals the sequential numpy normal-equation

@@ -113,3 +113,37 @@ def test_dsgd_train_scatters_into_the_visited_blocks(one_chip):
     loops = _inside_loops(hlo)
     assert " scatter(" in loops
     assert not re.search(rf"= f32\[{nu},{rank}\]\S* copy\(", loops)
+
+
+@pytest.mark.parametrize("shared_gram", [False, True],
+                         ids=["explicit", "implicit"])
+def test_solve_bucket_solves_in_the_lanes_kernel(one_chip, shared_gram):
+    """``ops.als._solve_bucket`` at the ALS cells' chunk (512 rows at rank
+    128, the 256-slot class; three chunks are enough for the program's
+    shape), with and without the implicit objective's shared Gram term:
+    lowered for the TPU, ``solve_normal_eq`` takes the Pallas kernel, and
+    XLA's per-matrix ``Cholesky`` and ``InvertDiagBlocksLowerTriangular``
+    (46.5 s of the explicit cell's 65.8 s window: PERF.md, Findings, PR
+    34) are not in the program. Its temporaries stay under one chunk's
+    gather (64 MiB) plus its Gram matrices (32 MiB) plus 1 MiB for the
+    right-hand side, the solution and the write: the relayout to one
+    system a lane holds no second copy of the Gram matrices (the program
+    reads 64.1 MiB here, as it did with XLA's solve)."""
+    from large_scale_recommendation_tpu.ops import als as als_ops
+
+    k, rc, pad, chunks, n_other, n_rows = 128, 512, 256, 3, 17792, 480192
+    f32, i32 = jnp.float32, jnp.int32
+    sds = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    slots = (chunks, rc, pad)
+    compiled = als_ops._solve_bucket.lower(
+        sds((n_other, k), f32), sds((n_rows + 1, k), f32),
+        sds((chunks, rc), i32), sds(slots, i32), sds(slots, f32),
+        sds(slots, f32), sds((chunks, rc), f32), sds((), f32),
+        sds((k, k), f32) if shared_gram else None).compile()
+    hlo = compiled.as_text()
+    calls = set(re.findall(r'custom_call_target="([^"]+)"', hlo))
+    assert "tpu_custom_call" in calls and "als_solve_lanes" in hlo
+    assert not calls & {"Cholesky", "InvertDiagBlocksLowerTriangular"}
+    gather, gram = rc * pad * k * 4, rc * k * k * 4
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < gather + gram + (1 << 20))
