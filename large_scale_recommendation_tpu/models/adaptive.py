@@ -400,8 +400,6 @@ class AdaptiveMF:
         but absent from the history snapshot survives with its online
         vectors.
         """
-        import jax.numpy as jnp
-
         wd = self.online.watchdog
         if wd is not None:
             # the retrain ran from history on a separate code path — a
@@ -424,9 +422,7 @@ class AdaptiveMF:
             real = index.ids >= 0
             ids = index.ids[real]
             rows = table.ensure(ids)
-            table.array = table.array.at[jnp.asarray(rows)].set(
-                jnp.asarray(T[real])
-            )
+            table.load_rows(rows, T[real])
         # the swap is only COMPLETE once the serving layer sees it:
         # every live engine rebinds to a fresh snapshot (new catalog
         # version, O(1), no recompile — serving.engine.refresh). The

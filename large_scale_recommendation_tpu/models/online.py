@@ -17,7 +17,12 @@ TPU-native rebuild of the reference's two online paths:
 Architecture here: the micro-batch form is the TPU-native one — a host ingest
 queue chops the stream into micro-batches; each batch is ONE jitted
 gather→update→scatter computation (``ops.sgd.online_train``) on growable
-device tables (``data.tables.GrowableFactorTable``). Synchronous jitted
+device tables (``data.tables.GrowableFactorTable``). The tables are
+DONATED to the update (``online_train_inplace``): a micro-batch rewrites
+the rows it touches where they lie and copies nothing else, which is
+what lets 7.27 GB of tables live on a 16 GB chip. The live arrays never
+leave the tables: a poller's ``table.array`` is a copy made at the read
+(``GrowableFactorTable.array_copy``). Synchronous jitted
 micro-batches make the reference's per-key lock/queue machinery (C15)
 unnecessary by construction: all updates in a batch are applied in one
 deterministic step, so there is no in-flight asynchrony to serialize.
@@ -73,6 +78,10 @@ class OnlineMFConfig:
     learning_rate: float = 0.01
     iterations_per_batch: int = 1
     minibatch_size: int = 256
+    # rows each table starts with, rounded by ``data.tables.capacity_for``:
+    # up to the next power of two while that table is at most
+    # ``STEP_BYTES`` (64 MiB), else up to the 8-row sublane tile. Both
+    # tables take it; a table registered past it grows by the same rule.
     init_capacity: int = 1024
     init_scale: float = 0.1
     collision_mode: str = "mean"  # minibatch row-collision handling (ops.sgd)
@@ -234,6 +243,9 @@ class OnlineMF:
         self._m_batch_s = obs.histogram("online_batch_s")
         self._m_batches = obs.counter("online_batches_total")
         self._m_ratings = obs.counter("online_ratings_total")
+        self._m_table_bytes = {
+            side: obs.gauge("online_table_bytes", side=side)
+            for side in ("users", "items")}
 
     # -- training ----------------------------------------------------------
 
@@ -265,7 +277,8 @@ class OnlineMF:
 
         ``emit_updates=False`` skips materializing the updates-only output
         (returns ``None``): pure-ingest mode for callers that poll the model
-        instead (``self.users.array`` / ``self.items.array`` snapshots).
+        instead (``self.users.array`` / ``self.items.array`` snapshots:
+        each read copies the table; ``borrowed()`` reads it in place).
         The per-batch device→host row pull is the dominant cost of a
         high-rate stream on narrow host links; polling amortizes it.
 
@@ -280,103 +293,130 @@ class OnlineMF:
                 batch, iterations=iterations, emit_updates=emit_updates,
                 offset=offset)
         cfg = self.config
-        ru, ri, rv, rw = batch.to_numpy()
-        real = rw > 0
-        ru, ri, rv = ru[real], ri[real], rv[real]
-        if len(ru) == 0:
-            if offset is not None:  # position advanced even when empty
-                self.consumed_offsets[int(offset[0])] = int(offset[1])
-            return (BatchUpdates([], [], rank=cfg.num_factors)
-                    if emit_updates else None)
+        seam = self._trace.seam
+        # the serial path's four seams (obs.trace.SEAMS): "prepare" is
+        # the host's part before the device can start (ids to rows,
+        # padding, staging), "update" the dispatch and the install,
+        # "stamp" what follows; the driver's "source" is the wait for
+        # the next batch. They lie end to end and never nest.
+        with seam("fit/online/prepare"):
+            ru, ri, rv, rw = batch.to_numpy()
+            real = rw > 0
+            if not real.all():
+                ru, ri, rv = ru[real], ri[real], rv[real]
+            if len(ru) == 0:
+                if offset is not None:  # position advanced even when empty
+                    self.consumed_offsets[int(offset[0])] = int(offset[1])
+                return (BatchUpdates([], [], rank=cfg.num_factors)
+                        if emit_updates else None)
 
-        t0 = time.perf_counter() if self._obs_on else 0.0
-        ev = self._events
-        if ev is not None:  # growth detection costs two attr reads,
-            cap_u = self.users.capacity  # journaled runs only
-            cap_i = self.items.capacity
-        # acquire_rows (data/tables.py tiering seam): a plain table's
-        # acquire IS ensure + no-op release — byte-identical to the
-        # historical path. A TieredFactorStore faults the batch's rows
-        # into its device slot pool, PINS them against eviction for the
-        # train→install window, and returns slot indices; the kernels
-        # below are tier-blind either way.
-        u_rows = self.users.acquire_rows(ru)
-        i_rows = self.items.acquire_rows(ri)
-        if ev is not None and (self.users.capacity != cap_u
-                               or self.items.capacity != cap_i):
-            # capacity doubling is rare and operationally loud (it
-            # recompiles the update kernels at the new table shape) —
-            # exactly the discrete lead-up marker a postmortem wants
-            ev.emit("online.table_growth", step=self.step,
-                    users_capacity=int(self.users.capacity),
-                    items_capacity=int(self.items.capacity))
-
+            t0 = time.perf_counter() if self._obs_on else 0.0
+            ev = self._events
+            if ev is not None:  # growth detection costs two attr reads,
+                cap_u = self.users.capacity  # journaled runs only
+                cap_i = self.items.capacity
+            # acquire_rows (data/tables.py tiering seam): a plain table's
+            # acquire IS ensure + no-op release — byte-identical to the
+            # historical path. A TieredFactorStore faults the batch's
+            # rows into its device slot pool, PINS them against eviction
+            # for the train→install window, and returns slot indices;
+            # the kernels below are tier-blind either way.
+            u_rows = self.users.acquire_rows(ru)
+            i_rows = self.items.acquire_rows(ri)
+            try:
+                ur, ir, vals, w = sgd_ops.pad_minibatches(
+                    u_rows, i_rows, rv, cfg.minibatch_size,
+                )
+                staged = (jnp.asarray(ur), jnp.asarray(ir),
+                          jnp.asarray(vals), jnp.asarray(w))
+            except BaseException:
+                self.users.release_rows(u_rows)
+                self.items.release_rows(i_rows)
+                raise
         try:
-            ur, ir, vals, w = sgd_ops.pad_minibatches(
-                u_rows, i_rows, rv, cfg.minibatch_size,
-            )
-            ledger = get_transfers()
-            if ledger is not None:
-                # the staged minibatch rides the async dispatch: bytes
-                # counted, wait 0.0 (the caller never blocks on it);
-                # the signature record is what a later retrace diffs
-                ledger.note_transfer("online.minibatch_stage", "h2d",
-                                     int(ur.nbytes + ir.nbytes
-                                         + vals.nbytes + w.nbytes))
-                ledger.observe_call("online_train", self.users.array,
-                                    self.items.array, ur, ir, vals, w)
+            if ev is not None and (self.users.capacity != cap_u
+                                   or self.items.capacity != cap_i):
+                # capacity growth is rare and operationally loud (it
+                # recompiles the update kernels at the new table shape)
+                # — exactly the discrete lead-up marker a postmortem
+                # wants
+                ev.emit("online.table_growth", step=self.step,
+                        users_capacity=int(self.users.capacity),
+                        items_capacity=int(self.items.capacity))
+            with seam("fit/online/update"), \
+                    self.users.updating() as U0, \
+                    self.items.updating() as V0:
+                # the tables are DONATED to the update: it rewrites the
+                # touched rows where they lie (a tiered store hands over
+                # a copy of its shared slot pool)
+                ledger = get_transfers()
+                if ledger is not None:
+                    # the staged minibatch rides the async dispatch:
+                    # bytes counted, wait 0.0 (the caller never blocks
+                    # on it); the signature record is what a later
+                    # retrace diffs
+                    ledger.note_transfer("online.minibatch_stage", "h2d",
+                                         int(ur.nbytes + ir.nbytes
+                                             + vals.nbytes + w.nbytes))
+                    ledger.observe_call("online_train", U0, V0,
+                                        ur, ir, vals, w)
 
-            # compile-keyed span: each pow2-padded batch length compiles
-            # its own online_train variant — the trace labels that first
-            # batch "compile", steady-state batches "execute"
-            with self._trace.span("online/partial_fit",
-                                  key=("online_train", len(ur)),
-                                  records=len(ru)) as sp:
-                # armed in debug/CI, shared null context otherwise:
-                # every crossing in the apply body must be an explicit
-                # device_put (the jnp.asarray ships above/below)
-                with guard_scope("online.partial_fit"):
-                    U, V = sgd_ops.online_train(
-                        self.users.array, self.items.array,
-                        jnp.asarray(ur), jnp.asarray(ir),
-                        jnp.asarray(vals), jnp.asarray(w),
-                        updater=self.updater,
-                        minibatch=cfg.minibatch_size,
-                        iterations=(iterations if iterations is not None
-                                    else cfg.iterations_per_batch),
-                        collision=cfg.collision_mode,
-                    )
-                sp.out = U
-            # install_trained: plain table = whole-array assign (the
-            # historical `self.users.array = U`); tiered store =
-            # scatter of OUR pinned slots into the CURRENT pool binding
-            # (an async prefetch may have rebound the pool since the
-            # snapshot read above — a whole-pool assign would erase its
-            # loads)
-            self.users.install_trained(U, u_rows)
-            self.items.install_trained(V, i_rows)
+                # compile-keyed span: each pow2-padded batch length
+                # compiles its own online_train variant — the trace
+                # labels that first batch "compile", steady-state
+                # batches "execute"
+                with self._trace.span("online/partial_fit",
+                                      key=("online_train", len(ur)),
+                                      records=len(ru)) as sp:
+                    # armed in debug/CI, shared null context otherwise:
+                    # every crossing in the apply body must be an
+                    # explicit device_put (the jnp.asarray ships above)
+                    with guard_scope("online.partial_fit"):
+                        U, V = sgd_ops.online_train_inplace(
+                            U0, V0, *staged,
+                            updater=self.updater,
+                            minibatch=cfg.minibatch_size,
+                            iterations=(iterations
+                                        if iterations is not None
+                                        else cfg.iterations_per_batch),
+                            collision=cfg.collision_mode,
+                        )
+                    sp.out = U
+                del U0, V0
+                # install_trained: plain table = the update's output IS
+                # the table (`_array = U`: the donated buffer with the
+                # batch's rows rewritten); tiered store
+                # = scatter of OUR pinned slots into the CURRENT pool
+                # binding (an async prefetch may have rebound the pool
+                # since the read above — a whole-pool assign would erase
+                # its loads)
+                self.users.install_trained(U, u_rows)
+                self.items.install_trained(V, i_rows)
         finally:
             self.users.release_rows(u_rows)
             self.items.release_rows(i_rows)
-        self.step += 1
-        if self._obs_on:
-            # block so the histogram reads device time, not dispatch
-            # (enabled-only: the uninstrumented path stays async)
-            # graftlint: disable=host-sync  (deliberate, _obs_on-gated)
-            U.block_until_ready()
-            self._m_batch_s.observe(time.perf_counter() - t0)
-            self._m_batches.inc()
-            self._m_ratings.inc(len(ru))
-        if self.watchdog is not None:
-            # BEFORE the offset stamp: a tripped halt/rollback raises
-            # here, so the stream position never claims a poisoned
-            # batch and the driver's checkpoint path never persists it
-            self.watchdog.after_batch(self, U, V, u_rows, i_rows)
-        if offset is not None:
-            # stamped only now, with the update APPLIED: an offset in
-            # consumed_offsets always means "this slice is in the
-            # tables", the invariant the checkpoint contract rests on
-            self.consumed_offsets[int(offset[0])] = int(offset[1])
+        with seam("fit/online/stamp"):
+            self.step += 1
+            if self._obs_on:
+                # block so the histogram reads device time, not dispatch
+                # (enabled-only: the uninstrumented path stays async)
+                # graftlint: disable=host-sync  (deliberate, _obs_on-gated)
+                U.block_until_ready()
+                self._m_batch_s.observe(time.perf_counter() - t0)
+                self._m_batches.inc()
+                self._m_ratings.inc(len(ru))
+                self._m_table_bytes["users"].set(self.users.device_bytes)
+                self._m_table_bytes["items"].set(self.items.device_bytes)
+            if self.watchdog is not None:
+                # BEFORE the offset stamp: a tripped halt/rollback raises
+                # here, so the stream position never claims a poisoned
+                # batch and the driver's checkpoint path never persists it
+                self.watchdog.after_batch(self, U, V, u_rows, i_rows)
+            if offset is not None:
+                # stamped only now, with the update APPLIED: an offset in
+                # consumed_offsets always means "this slice is in the
+                # tables", the invariant the checkpoint contract rests on
+                self.consumed_offsets[int(offset[0])] = int(offset[1])
         if not emit_updates:
             return None
 
@@ -471,8 +511,10 @@ class OnlineMF:
             i_rows = self.items.acquire_rows(ri)
             grew = ev is not None and (self.users.capacity != cap_u
                                        or self.items.capacity != cap_i)
-            U0 = self.users.array  # immutable jax arrays: the snapshot
-            V0 = self.items.array  # is two refs, zero copies
+            # the snapshot: copies, this consumer's own (the live arrays
+            # are donated to every install and commit meanwhile)
+            U0 = self.users.array_copy()
+            V0 = self.items.array_copy()
         try:
             if grew:
                 ev.emit("online.table_growth", step=self.step,
@@ -494,7 +536,7 @@ class OnlineMF:
                                   key=("online_train", len(ur)),
                                   records=len(ru)) as sp:
                 with guard_scope("online.partial_fit"):
-                    U, V = sgd_ops.online_train(
+                    U, V = sgd_ops.online_train_inplace(
                         U0, V0,
                         jnp.asarray(ur), jnp.asarray(ir),
                         jnp.asarray(vals), jnp.asarray(w),
@@ -533,7 +575,7 @@ class OnlineMF:
                 # disjoint commits since our snapshot) — one executable
                 # per table, dispatched under the lock, drained outside
                 # it. commit_rows is the tiering seam: a plain table
-                # rebinds `.array`; a tiered store scatters into the
+                # scatters in place; a tiered store scatters into the
                 # CURRENT pool binding under its own lock.
                 self.users.commit_rows(U, ju)
                 self.items.commit_rows(V, ji)
@@ -543,15 +585,15 @@ class OnlineMF:
                     # invariant the serial path keeps, same checkpoint
                     # contract on top
                     self.consumed_offsets[int(offset[0])] = int(offset[1])
-                committed = self.users.array
         finally:
             self.users.release_rows(u_rows)
             self.items.release_rows(i_rows)
         if self._obs_on:
             # graftlint: disable=host-sync  (deliberate, _obs_on-gated)
-            committed.block_until_ready()  # outside the lock: blocking
-            # under apply_lock would serialize the overlap this mode
-            # exists to provide
+            U.block_until_ready()  # outside the lock: blocking under
+            # apply_lock would serialize the overlap this mode exists to
+            # provide. The trained snapshot, not the live table: another
+            # consumer's commit may donate that meanwhile
             self._m_batch_s.observe(time.perf_counter() - t0)
             self._m_batches.inc()
             self._m_ratings.inc(len(ru))
@@ -605,13 +647,15 @@ class OnlineMF:
         seen)`` with the reference's join-drop set exposed."""
         u_rows, u_mask = self.users.rows_for(np.asarray(user_ids))
         i_rows, i_mask = self.items.rows_for(np.asarray(item_ids))
-        # full_table(): a plain table's live array; a tiered store's
-        # merged host view (cold tier + dirty resident slots) — the
-        # rows here are TABLE rows, which only the merged view indexes
-        scores = sgd_ops.predict_rows(
-            self.users.full_table(), self.items.full_table(),
-            jnp.asarray(u_rows), jnp.asarray(i_rows),
-        )
+        # borrowed(): a plain table's live array, read without handing it
+        # out (scoring a live model must not cost the next micro-batch a
+        # copy of the tables); a tiered store's merged host view (cold
+        # tier + dirty resident slots) — the rows here are TABLE rows,
+        # which only the merged view indexes
+        with self.users.borrowed() as U, self.items.borrowed() as V:
+            scores = sgd_ops.predict_rows(
+                U, V, jnp.asarray(u_rows), jnp.asarray(i_rows),
+            )
         from large_scale_recommendation_tpu.models.mf import masked_scores
 
         return masked_scores(scores, u_mask, i_mask, return_mask)
@@ -624,11 +668,11 @@ class OnlineMF:
         n = mask.sum()
         if n == 0:
             return float("nan")
-        sse = sgd_ops.sse_rows(
-            self.users.full_table(), self.items.full_table(),
-            jnp.asarray(u_rows), jnp.asarray(i_rows),
-            jnp.asarray(rv), jnp.asarray(mask),
-        )
+        with self.users.borrowed() as U, self.items.borrowed() as V:
+            sse = sgd_ops.sse_rows(
+                U, V, jnp.asarray(u_rows), jnp.asarray(i_rows),
+                jnp.asarray(rv), jnp.asarray(mask),
+            )
         return float(np.sqrt(float(sse) / n))
 
     # -- export ------------------------------------------------------------
@@ -656,7 +700,7 @@ class OnlineMF:
             n = table.num_rows
             idx = flat_index(table.id_array(),
                              sorted_pair=table.sorted_index())
-            F = jnp.asarray(table.full_table()[:n])
+            F = jnp.asarray(table.snapshot_rows(n))
             if n == 0:  # flat_index's 1-row empty-vocab shape needs a
                 F = jnp.zeros((1, table.rank), jnp.float32)  # factor row
             return F, idx

@@ -582,14 +582,15 @@ def _heal_non_finite_rows(table) -> int:
     n = table.num_rows
     if n == 0:
         return 0
-    bad = ~jnp.isfinite(table.array[:n]).all(axis=1)
+    with table.borrowed() as live:
+        bad = ~jnp.isfinite(live[:n]).all(axis=1)
     n_bad = int(bad.sum())
     if n_bad == 0:
         return 0
     rows = np.nonzero(np.asarray(bad))[0]
     ids = np.asarray(table.id_array())[rows]
     fresh = table.initializer(jnp.asarray(ids, dtype=jnp.int32))
-    table.array = table.array.at[jnp.asarray(rows)].set(fresh)
+    table.load_rows(rows, fresh)
     return n_bad
 
 

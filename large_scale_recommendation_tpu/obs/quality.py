@@ -246,7 +246,7 @@ class OnlineEvaluator:
     thread's evaluate; evaluation itself runs outside the lock on a
     snapshot (a slow eval must never stall ingest). The model read
     rides the package's documented ``.array`` snapshot-consistency
-    point (tables swap atomically between ``partial_fit`` calls) — a
+    point (a copy taken between ``partial_fit`` calls) — a
     cadence evaluation concurrent with a capacity-growth rehash may
     drop a pair as unseen for one tick, never corrupt anything.
     """
@@ -384,7 +384,9 @@ class OnlineEvaluator:
                     sel = self._eval_rng.choice(
                         len(u_rows), self.eval_sample, replace=False)
                 u_rows, i_rows = u_rows[sel], i_rows[sel]
-            V = model.items.array
+            # copies (``array_copy``): the evaluation runs on them while
+            # ingest goes on in place
+            U, V = model.users.array, model.items.array
             item_mask = np.asarray(model.items.id_array()) >= 0
             if len(item_mask) < int(V.shape[0]):  # capacity > ids filled
                 item_mask = np.concatenate([
@@ -393,7 +395,7 @@ class OnlineEvaluator:
             with self._lock:
                 rank_seed = int(self._eval_rng.integers(1 << 31))
             rq = sampled_ranking_metrics(
-                model.users.array, V, u_rows, i_rows, k=self.k,
+                U, V, u_rows, i_rows, k=self.k,
                 num_negatives=self.num_negatives, item_mask=item_mask,
                 seed=rank_seed)
             cov_users = np.unique(u_rows)
@@ -401,7 +403,7 @@ class OnlineEvaluator:
                 with self._lock:
                     cov_users = self._eval_rng.choice(cov_users, 256,
                                                       replace=False)
-            cov = catalog_coverage(model.users.array, V, cov_users,
+            cov = catalog_coverage(U, V, cov_users,
                                    k=self.k, item_mask=item_mask)
             metrics.update(ndcg=rq["ndcg"], hr=rq["hr"], coverage=cov,
                            valid_negatives=rq["valid_negatives"])

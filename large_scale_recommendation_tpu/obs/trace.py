@@ -88,6 +88,8 @@ SEAMS = frozenset({
     "fit/dsgd/segment", "fit/mesh_dsgd/segment", "fit/als/segment",
     "fit/dsgd/after_segment", "fit/mesh_dsgd/after_segment",
     "fit/als/plan", "fit/als/init", "fit/als/after_segment",
+    "fit/online/source", "fit/online/prepare", "fit/online/update",
+    "fit/online/stamp",
 })
 
 # span sequence numbers are PROCESS-unique (module-level, not

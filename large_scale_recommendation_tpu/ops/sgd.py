@@ -416,6 +416,21 @@ def online_train(
     return U, V
 
 
+# The same body with ``U`` and ``V`` DONATED: the outputs alias the inputs,
+# the minibatch scan scatters into the tables where they lie, and a call
+# moves the touched rows alone. ``online_train`` leaves its inputs alive,
+# so XLA copies each whole table into its output once a call (7.27 GB for
+# 1 MB of ratings at 2.5M + 1M rows of rank 512) and a caller's older
+# reference stays valid; after this one the arrays passed in are dead.
+# Same arithmetic, bit for bit (tests/test_online_reference.py).
+# ``models/online.py`` runs this one alone: the live tables never leave
+# ``data.tables.GrowableFactorTable``, whose ``updating`` yields them.
+online_train_inplace = jax.jit(
+    online_train.__wrapped__,
+    static_argnames=("updater", "minibatch", "iterations", "collision"),
+    donate_argnums=(0, 1))
+
+
 def pad_minibatches(
     u_rows,
     i_rows,

@@ -162,13 +162,16 @@ class _MFWorkerLogic:
         ur, ir, rv, w = sgd_ops.pad_minibatches(u_rows, ips, vals, mb)
 
         V_old = jnp.asarray(V_chunk, dtype=jnp.float32)
-        U_new, V_new = sgd_ops.online_train(
-            self.users.array, V_old,
-            jnp.asarray(ur), jnp.asarray(ir), jnp.asarray(rv), jnp.asarray(w),
-            updater=self.updater, minibatch=mb, iterations=1,
-            t0=self._epoch,  # advance the η/√t schedule across epochs
-        )
-        self.users.array = U_new
+        # the copying update: ``V_old`` is read again below
+        with self.users.updating() as U_old:
+            U_new, V_new = sgd_ops.online_train(
+                U_old, V_old,
+                jnp.asarray(ur), jnp.asarray(ir), jnp.asarray(rv),
+                jnp.asarray(w),
+                updater=self.updater, minibatch=mb, iterations=1,
+                t0=self._epoch,  # advance the η/√t schedule across epochs
+            )
+            self.users.install_trained(U_new, u_rows)
         # The workers holding ratings for an item each push a full local
         # update computed from the same (stale) pulled value — averaging
         # over the HOLDERS keeps the combined step at the intended

@@ -36,6 +36,7 @@ rows move between tiers, never what any kernel computes.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import time
@@ -163,6 +164,26 @@ class TieredFactorStore(GrowableFactorTable):
     @array.setter
     def array(self, value):
         self._pool = value
+
+    def array_copy(self):
+        return jnp.copy(self._pool)
+
+    @contextlib.contextmanager
+    def updating(self):
+        # the pool is shared with the prefetch and serving threads and
+        # rebound under the store lock, so the update donates a copy of
+        # it (the pool's bytes once, as a copying update would pay);
+        # ``install_trained`` scatters the batch's slots into the pool
+        # as it then stands
+        yield jnp.copy(self._pool)
+
+    @contextlib.contextmanager
+    def borrowed(self):
+        yield self.full_table()  # TABLE rows: the merged hot and cold view
+
+    @property
+    def device_bytes(self) -> int:
+        return int(self._pool.nbytes)
 
     def _install(self, fresh, base: int) -> None:
         # initializer output for newly registered (+pad) rows lands in

@@ -319,8 +319,17 @@ class StreamingDriver:
                                                 if prefetcher is not None
                                                 else None))
         applied = 0
+        seam = self._trace.seam
         try:
-            for batch in self._source:
+            batches = iter(self._source)
+            while True:
+                # seam "fit/online/source": waiting on the feeder's queue
+                # and taking the next micro-batch off it (a profiler
+                # capture charges the chip's idle time inside to it)
+                with seam("fit/online/source"):
+                    batch = next(batches, None)
+                if batch is None:
+                    break
                 self._apply(batch)
                 applied += 1
                 if (max_batches is not None and applied >= max_batches) \

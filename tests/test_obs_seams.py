@@ -10,6 +10,7 @@ trainer's ``evaluator.on_segment`` hook.
 """
 
 import os
+import tempfile
 
 import numpy as np
 import pytest
@@ -83,9 +84,35 @@ class _Hook:
                            label, step))
 
 
+def _stream(directory):
+    """Two micro-batches of the online stream through the driver, from a
+    written log."""
+    from large_scale_recommendation_tpu.models.online import (
+        OnlineMF,
+        OnlineMFConfig,
+    )
+    from large_scale_recommendation_tpu.streams import (
+        EventLog,
+        StreamingDriver,
+        StreamingDriverConfig,
+    )
+
+    _, (ru, ri, rv) = _ratings()
+    log = EventLog(os.path.join(directory, "log"), fsync=False)
+    log.append_arrays(0, ru[:512], ri[:512], rv[:512])
+    driver = StreamingDriver(
+        OnlineMF(OnlineMFConfig(num_factors=RANK)), log,
+        os.path.join(directory, "ckpt"),
+        config=StreamingDriverConfig(batch_records=256,
+                                     checkpoint_every=None))
+    assert driver.run() == 2
+    log.close()
+
+
 def _drive(annotate):
-    """One flush of each serving path and one fit of each trainer, each
-    inside the span its benchmark runner would put around it."""
+    """One flush of each serving path, one fit of each trainer and one
+    run of the stream's driver, each inside the span its benchmark runner
+    would put around it."""
     train, (ru, ri, rv) = _ratings()
     rng = np.random.default_rng(1)
     engines = [
@@ -111,6 +138,9 @@ def _drive(annotate):
         ALS(ALSConfig(num_factors=RANK, lambda_=0.05,
                       iterations=SEGMENTS)).fit_device(
             ru, ri, rv, NU, NI, checkpoint_every=1)
+    with tempfile.TemporaryDirectory() as directory, \
+            annotate(CALLER["fit"]):
+        _stream(directory)
 
 
 @pytest.fixture(scope="module")
@@ -151,7 +181,7 @@ def test_no_seam_has_a_name_the_benchmark_emits(capture):
     # only events of those names are the ones _drive opened
     counts = {n: sum(1 for e in capture if e[0] == n)
               for n in CALLER.values()}
-    assert counts == {"serving/flush": 2, "fit/fit_device": 4}
+    assert counts == {"serving/flush": 2, "fit/fit_device": 5}
 
 
 def test_every_seam_is_a_row_of_the_docs_table():
