@@ -133,7 +133,7 @@ class ALS:
         num_items: int,
         checkpoint_every: int | None = None,
     ) -> MFModel:
-        """Fit via device-built solve plans (``ops.als.device_prepare_side``).
+        """Fit via device-built solve plans (``ops.als.device_prepare_counted``).
 
         Dense-id COO in (host or device arrays, ids in ``[0, num_users) ×
         [0, num_items)``), standard ``MFModel`` out — the ALS counterpart of
@@ -176,19 +176,18 @@ class ALS:
         k = cfg.num_factors
         seam = get_tracer().seam
 
-        # both sides' plans, their class-size read-backs included
+        # both sides' plans, their class-size read-backs included. Each
+        # plan counts its own side's ratings (the lengths of the runs of
+        # its row sort): the ALS-WR scale, the mask on V's init and the
+        # two id indexes below read those counts, and nothing else of a
+        # fit goes over the ratings to count them
         with seam("fit/als/plan"):
-            omega_u = jnp.zeros(num_users, jnp.int32).at[u].add(1)
-            omega_v = jnp.zeros(num_items, jnp.int32).at[i].add(1)
-            omu = (omega_u.astype(jnp.float32)
-                   if cfg.reg_mode == "als_wr" else None)
-            omv = (omega_v.astype(jnp.float32)
-                   if cfg.reg_mode == "als_wr" else None)
-            prep_u = als_ops.device_prepare_side(
-                u, i, r, num_users, omega=omu, min_pad=cfg.min_pad,
+            weighted = cfg.reg_mode == "als_wr"
+            prep_u, omega_u = als_ops.device_prepare_counted(
+                u, i, r, num_users, weighted, min_pad=cfg.min_pad,
                 rank_for_chunking=k)
-            prep_v = als_ops.device_prepare_side(
-                i, u, r, num_items, omega=omv, min_pad=cfg.min_pad,
+            prep_v, omega_v = als_ops.device_prepare_counted(
+                i, u, r, num_items, weighted, min_pad=cfg.min_pad,
                 rank_for_chunking=k)
             if cfg.implicit_alpha is not None:
                 prep_u = als_ops.implicit_prepared(prep_u,

@@ -249,7 +249,7 @@ def _chunked_bucket(bucket, omega, num_rows, k, target_bytes=256 << 20):
     with pow2 rc (bounded compile variants); chunk-padding rows point at the
     dummy row ``num_rows`` with weight 0. The ONE copy of the chunk-layout
     contract — both the host plan path (``prepare_side``) and the device
-    plan path (``device_prepare_side``) go through it; inputs may be numpy
+    plan path (``_device_prepare``) go through it; inputs may be numpy
     or device arrays. ``omega`` must already be a float32 jnp array (or
     None)."""
     rows, oidx, vals, w = bucket
@@ -299,37 +299,71 @@ def prepare_side(plan: SolvePlan, omega: np.ndarray | None, k: int,
     )
 
 
+# ``_run_starts``' tile: 128 int32 keys are one 512-byte row, the row a
+# gather moves at its best on a TPU (``als/gather``)
+_TILE = 128
+
+
+def _run_starts(sorted_keys, num_keys: int):
+    """Where each key's run begins in ascending ``sorted_keys``:
+    ``int32[num_keys + 1]``, entry ``r`` the number of keys below ``r``
+    (entry ``num_keys`` is their count, keys lying in ``[0, num_keys)``).
+
+    Two levels, because a gather from the whole of a 382 MB array costs
+    24 ns an element on a TPU and a plain binary search makes 27 of them a
+    query: the search runs over every ``_TILE``-th key (3 MB at 95.5M
+    ratings), and the one tile that holds the boundary is read as a row and
+    counted (PERF.md, Findings, PR 36, has both forms' readings)."""
+    e = sorted_keys.shape[0]
+    n_tiles = max(1, -(-e // _TILE))
+    # padding sorts after every key and is below no query
+    tiles = jnp.pad(sorted_keys, (0, n_tiles * _TILE - e),
+                    constant_values=num_keys).reshape(n_tiles, _TILE)
+    q = jnp.arange(num_keys + 1, dtype=jnp.int32)
+    # tiles that begin below q: the boundary is in the last of them, or
+    # at its end
+    below = jnp.searchsorted(tiles[:, 0], q, side="left").astype(jnp.int32)
+    t = jnp.maximum(below - 1, 0)
+    return t * _TILE + jnp.sum(tiles[t] < q[:, None], axis=1,
+                               dtype=jnp.int32)
+
+
 @partial(jax.jit, static_argnames=("num_out_rows", "n_pow2"))
 def _device_plan_keys(out_rows, other_rows, values, num_out_rows: int,
                       n_pow2: int):
     """The two sorts the device plan build needs, each carrying its payload
     (``lax.sort`` with several operands: nothing is gathered through an
-    order afterwards). Returns the rows grouped by pad class with each
-    row's rating count and first entry beside it, the tiny per-class
-    row-count vector that gets read back to fix static shapes, and the
-    partner indices and values in solve order."""
+    order afterwards). The entries' two-key sort comes first and its sorted
+    rows are kept: a row's rating count is the length of its run there and
+    its first entry where the run begins (``_run_starts``), so nothing is
+    accumulated over the entries to count them. Returns the rows grouped by
+    pad class with each row's rating count and first entry beside it, the
+    tiny per-class row-count vector that gets read back to fix static
+    shapes, the partner indices and values in solve order, and the rating
+    counts by row."""
     with jax.named_scope("plan/sort"):
-        counts = jnp.zeros(num_out_rows, jnp.int32).at[out_rows].add(1)
+        # lexsort by (out_row, other_row), stable: row-contiguous runs
+        # with ascending partner indices inside each run, the same
+        # gather-locality lever as the host plan's np.lexsort (see
+        # build_solve_plan)
+        r_sorted, o_sorted, v_sorted = jax.lax.sort(
+            (out_rows, other_rows, values), num_keys=2, is_stable=True)
+        bounds = _run_starts(r_sorted, num_out_rows)
+        starts = bounds[:-1]
+        counts = bounds[1:] - starts
         pow2s = jnp.int32(2) ** jnp.arange(n_pow2, dtype=jnp.int32)
         # smallest pow2 ≥ count, exact integer logic (no float log2 edge
         # cases); empty rows get a trailing pseudo-class that is sliced off
         pclass = jnp.searchsorted(pow2s, counts,
                                   side="left").astype(jnp.int32)
         pclass = jnp.where(counts == 0, n_pow2, pclass)
-        starts = jnp.cumsum(counts) - counts
         # rows grouped by class, ascending inside one
         _, row_order, counts_o, starts_o = jax.lax.sort(
             (pclass, jnp.arange(num_out_rows, dtype=jnp.int32), counts,
              starts), num_keys=1, is_stable=True)
         rows_per_class = jnp.zeros(n_pow2 + 1, jnp.int32).at[pclass].add(1)
-        # lexsort by (out_row, other_row), stable: row-contiguous runs
-        # with ascending partner indices inside each run, the same
-        # gather-locality lever as the host plan's np.lexsort (see
-        # build_solve_plan)
-        _, o_sorted, v_sorted = jax.lax.sort(
-            (out_rows, other_rows, values), num_keys=2, is_stable=True)
         return (row_order, counts_o, starts_o, rows_per_class, o_sorted,
-                v_sorted)
+                v_sorted, counts)
 
 
 @partial(jax.jit, static_argnames=("pad", "rc", "n_chunks", "num_rows"))
@@ -376,36 +410,20 @@ def _device_bucket(row_order, counts_o, starts_o, o_sorted, v_sorted, offset,
                 valid.astype(jnp.float32))
 
 
-def device_prepare_side(
-    out_rows,
-    other_rows,
-    values,
-    num_out_rows: int,
-    omega=None,
-    min_pad: int = 8,
-    target_bytes: int = 256 << 20,
-    rank_for_chunking: int | None = None,
-):
-    """Build one orientation's chunked solve buckets ENTIRELY on device.
-
-    Device-resident equivalent of ``build_solve_plan`` + ``prepare_side``:
-    sort, bucket, pad and chunk as XLA ops; the only host↔device traffic is
-    a ≤33-int per-class row-count readback (static shapes for the jitted
-    bucket builds). Input arrays may be device or host; dense rows in
-    ``[0, num_out_rows)``. Returns prepared chunked buckets consumable by
-    ``solve_side`` (and by ``implicit_prepared``).
-
-    ``rank_for_chunking`` sets the chunk-geometry rank (defaults to a
-    conservative 256 so one prepared layout serves any rank ≤ that without
-    exceeding ``target_bytes``).
-    """
+def _device_prepare(out_rows, other_rows, values, num_out_rows: int, omega,
+                    als_wr: bool, min_pad: int, target_bytes: int,
+                    rank_for_chunking: int | None):
+    """One side's chunked buckets and its rows' rating counts, for
+    ``device_prepare_side`` (the ridge scaled by the caller's ``omega``)
+    and ``device_prepare_counted`` (``als_wr``: by the counts)."""
     out_rows = jnp.asarray(out_rows, jnp.int32)
     other_rows = jnp.asarray(other_rows, jnp.int32)
     values = jnp.asarray(values, jnp.float32)
     k = rank_for_chunking or 256
     n_pow2 = 31
-    row_order, counts_o, starts_o, rows_per_class, o_sorted, v_sorted = \
-        _device_plan_keys(out_rows, other_rows, values, num_out_rows, n_pow2)
+    (row_order, counts_o, starts_o, rows_per_class, o_sorted, v_sorted,
+     counts) = _device_plan_keys(out_rows, other_rows, values, num_out_rows,
+                                 n_pow2)
 
     rpc = np.asarray(rows_per_class)  # the tiny readback
     offsets = np.concatenate([[0], np.cumsum(rpc)])
@@ -422,6 +440,8 @@ def device_prepare_side(
                for cls in range(m + 1, n_pow2)]
     # classes no row falls in go; the trailing one (empty rows) never came
     groups = [g for g in groups if g[2]]
+    if als_wr:
+        omega = counts
     om = None if omega is None else jnp.asarray(omega, jnp.float32)
     # room for the widest class's window at the last rating
     widest = max((pad for pad, _, _ in groups), default=0)
@@ -436,7 +456,63 @@ def device_prepare_side(
         # already whole chunks: _chunked_bucket finds nothing to pad
         prepared.append(_chunked_bucket(bucket, om, num_out_rows, k,
                                         target_bytes))
-    return tuple(prepared)
+    return tuple(prepared), counts
+
+
+def device_prepare_side(
+    out_rows,
+    other_rows,
+    values,
+    num_out_rows: int,
+    omega=None,
+    min_pad: int = 8,
+    target_bytes: int = 256 << 20,
+    rank_for_chunking: int | None = None,
+):
+    """Build one orientation's chunked solve buckets ENTIRELY on device.
+
+    Device-resident equivalent of ``build_solve_plan`` + ``prepare_side``:
+    sort, count, bucket, pad and chunk as XLA ops; the only host↔device
+    traffic is a ≤33-int per-class row-count readback (static shapes for
+    the jitted bucket builds). Input arrays may be device or host; dense
+    rows in ``[0, num_out_rows)``. Returns prepared chunked buckets
+    consumable by ``solve_side`` (and by ``implicit_prepared``).
+
+    ``omega`` is the CALLER'S ridge scale by row (a shard whose local
+    counts are not the global ones); a fit whose scale is the rows' own
+    rating counts takes ``device_prepare_counted``, which reads them off
+    this plan's sort.
+
+    ``rank_for_chunking`` sets the chunk-geometry rank (defaults to a
+    conservative 256 so one prepared layout serves any rank ≤ that without
+    exceeding ``target_bytes``).
+    """
+    return _device_prepare(out_rows, other_rows, values, num_out_rows,
+                           omega=omega, als_wr=False, min_pad=min_pad,
+                           target_bytes=target_bytes,
+                           rank_for_chunking=rank_for_chunking)[0]
+
+
+def device_prepare_counted(
+    out_rows,
+    other_rows,
+    values,
+    num_out_rows: int,
+    als_wr: bool,
+    min_pad: int = 8,
+    target_bytes: int = 256 << 20,
+    rank_for_chunking: int | None = None,
+):
+    """``device_prepare_side`` for a caller whose rows' rating counts are
+    the plan's own: returns ``(prepared, counts)``, ``counts`` the
+    ``int32[num_out_rows]`` run lengths of the plan's row sort
+    (``_device_plan_keys``). With ``als_wr`` each row's ridge is scaled by
+    its count (ALS-WR); the caller keeps the counts for what else it needs
+    them for (which ids were seen) and counts nothing itself."""
+    return _device_prepare(out_rows, other_rows, values, num_out_rows,
+                           omega=None, als_wr=als_wr, min_pad=min_pad,
+                           target_bytes=target_bytes,
+                           rank_for_chunking=rank_for_chunking)
 
 
 def publish_plan_sizes(side: str, prepared, n_ratings: int) -> None:

@@ -521,33 +521,113 @@ class TestDevicePreparedPlans:
         payload and a row's slots are one window, so no program of the
         plan gathers a rating or a slot by an index of its own (24 ns an
         element at 95.5M ratings, and a time that moved from run to run:
-        PERF.md, Findings, PR 29)."""
+        PERF.md, Findings, PR 29). The run-boundary search (PR 36) reads
+        the sorted rows at ``n_rows + 1`` places a step, never at as many
+        as there are entries."""
         e, n_rows, pad, n = 4096, 50, 64, 32
-        i32 = jax.ShapeDtypeStruct((e,), jnp.int32)
-        f32 = jax.ShapeDtypeStruct((e,), jnp.float32)
-        keys = jax.make_jaxpr(
-            lambda a, b, v: als_ops._device_plan_keys(a, b, v, n_rows, 31)
-        )(i32, i32, f32)
-        rows = jax.ShapeDtypeStruct((n_rows,), jnp.int32)
-        scalar = jax.ShapeDtypeStruct((), jnp.int32)
-        bucket = jax.make_jaxpr(
-            lambda ro, c, s, o, v, off, nb: als_ops._device_bucket(
-                ro, c, s, o, v, off, nb, pad, n, 1, n_rows)
-        )(rows, rows, rows, i32, f32, scalar, scalar)
+        keys, bucket = _plan_jaxprs(e, n_rows, pad, n)
 
-        def gathers(jaxpr):
-            for eqn in jaxpr.eqns:
-                if eqn.primitive.name == "gather":
-                    yield eqn
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    yield from gathers(sub)
+        def indices(g):
+            return int(np.prod(g.invars[1].aval.shape[:-1]))
 
-        # the keys: only searchsorted's lookups in the 31 powers of two
-        assert all(g.invars[0].aval.shape == (31,)
-                   for g in gathers(keys.jaxpr))
-        windows = [g for g in gathers(bucket.jaxpr)]
+        # the keys: searchsorted's lookups in the 31 powers of two (one a
+        # row), and the boundary search's in the sorted rows
+        found = list(_eqns(keys.jaxpr, "gather"))
+        assert found
+        assert all(indices(g) <= n_rows + 1 < e for g in found)
+        windows = list(_eqns(bucket.jaxpr, "gather"))
         assert len(windows) == 2  # partner indices, values
         assert all(g.params["slice_sizes"] == (pad,) for g in windows)
+
+    def test_plan_counts_no_rating_by_a_scatter_add(self):
+        """PR 36: a row's count is the length of its run in the plan's own
+        row sort. No program of a side's plan scatter-adds over the
+        entries (0.64 s a count vector at 95.5M ratings, four of them a
+        fit before: PERF.md, Findings, PR 36); the one scatter-add left
+        counts the ROWS of each pad class."""
+        e, n_rows = 4096, 50
+        keys, bucket = _plan_jaxprs(e, n_rows, 64, 32)
+        adds = (list(_eqns(keys.jaxpr, "scatter-add"))
+                + list(_eqns(bucket.jaxpr, "scatter-add")))
+        assert [a.invars[2].aval.shape for a in adds] == [(n_rows,)]
+
+    @pytest.mark.parametrize("case", [
+        "unsorted", "empty_front", "empty_middle", "empty_end",
+        "one_row_holds_all", "one_entry_a_row", "one_row",
+        "rows_not_a_multiple_of_128", "entries_a_multiple_of_128",
+        "runs_end_on_tile_edges"])
+    def test_plan_counts_and_starts_equal_bincount(self, case):
+        """The run-boundary search against ``np.bincount`` and its
+        exclusive cumsum, where an off-by-one at either end of a run, of a
+        128-key tile or of the arrays would show."""
+        rng = np.random.default_rng(7)
+        if case == "unsorted":
+            n_rows, rows = 70, rng.integers(0, 70, 5000)
+        elif case == "empty_front":
+            n_rows, rows = 40, rng.integers(9, 40, 700)
+        elif case == "empty_middle":
+            n_rows = 300
+            rows = np.concatenate([rng.integers(0, 20, 400),
+                                   rng.integers(200, 300, 400)])
+        elif case == "empty_end":
+            n_rows, rows = 500, rng.integers(0, 130, 900)
+        elif case == "one_row_holds_all":
+            n_rows, rows = 9, np.full(1000, 4)
+        elif case == "one_entry_a_row":
+            n_rows, rows = 333, rng.permutation(333)
+        elif case == "one_row":
+            n_rows, rows = 1, np.zeros(257, np.int64)
+        elif case == "rows_not_a_multiple_of_128":
+            n_rows, rows = 131, rng.integers(0, 131, 1023)
+        elif case == "entries_a_multiple_of_128":
+            n_rows, rows = 37, rng.integers(0, 37, 1024)
+        else:  # every run is one tile, or two: boundaries on tile edges
+            n_rows = 12
+            rows = np.repeat(np.arange(12), 128 * (1 + np.arange(12) % 2))
+            rows = rng.permutation(rows)
+        e = len(rows)
+        other = rng.integers(0, 50, e)
+        vals = rng.normal(size=e).astype(np.float32)
+        (row_order, counts_o, starts_o, _, _, _,
+         counts) = als_ops._device_plan_keys(
+            jnp.asarray(rows, jnp.int32), jnp.asarray(other, jnp.int32),
+            jnp.asarray(vals), n_rows, 31)
+        want = np.bincount(rows, minlength=n_rows)
+        np.testing.assert_array_equal(np.asarray(counts), want)
+        order = np.asarray(row_order)
+        np.testing.assert_array_equal(np.asarray(counts_o), want[order])
+        np.testing.assert_array_equal(np.asarray(starts_o),
+                                      (np.cumsum(want) - want)[order])
+        # and the rows' own search, ends included
+        got = als_ops._run_starts(jnp.sort(jnp.asarray(rows, jnp.int32)),
+                                  n_rows)
+        np.testing.assert_array_equal(
+            np.asarray(got), np.concatenate([[0], np.cumsum(want)]))
+
+
+def _eqns(jaxpr, name):
+    """Every equation of primitive ``name``, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, name)
+
+
+def _plan_jaxprs(e, n_rows, pad, n):
+    """The two programs of a side's device plan over ``e`` entries."""
+    i32 = jax.ShapeDtypeStruct((e,), jnp.int32)
+    f32 = jax.ShapeDtypeStruct((e,), jnp.float32)
+    keys = jax.make_jaxpr(
+        lambda a, b, v: als_ops._device_plan_keys(a, b, v, n_rows, 31)
+    )(i32, i32, f32)
+    rows = jax.ShapeDtypeStruct((n_rows,), jnp.int32)
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    bucket = jax.make_jaxpr(
+        lambda ro, c, s, o, v, off, nb: als_ops._device_bucket(
+            ro, c, s, o, v, off, nb, pad, n, 1, n_rows)
+    )(rows, rows, rows, i32, f32, scalar, scalar)
+    return keys, bucket
 
 
 class TestALSFitDevice:
@@ -626,6 +706,61 @@ class TestALSFitDevice:
         last = uneven.fit_device(ru, ri, rv, 120, 90, checkpoint_every=2)
         assert [c[3] for c in uneven.evaluator.calls] == [2, 3]
         np.testing.assert_array_equal(np.asarray(last.V), np.asarray(one.V))
+
+    @pytest.mark.parametrize("reg_mode,implicit_alpha",
+                             [("als_wr", None), ("direct", 2.0)],
+                             ids=["als_wr", "implicit"])
+    def test_counts_are_the_plans_own(self, reg_mode, implicit_alpha):
+        """PR 36: the two plans count their own sides. The fitted model's
+        ``omega`` are the per-id counts, ids unseen in training stay
+        unknown and their rows zero, V's init included (the implicit
+        shared Gram sums the whole table: an unseen item's row there must
+        weigh what an id outside the vocabulary weighs), and no program
+        of the fit scatter-adds over the ratings to count them."""
+        from jax._src.lax.slicing import scatter_add_p
+
+        from large_scale_recommendation_tpu.models.als import ALS, ALSConfig
+
+        gen = SyntheticMFGenerator(num_users=120, num_items=90, rank=4,
+                                   noise=0.05, seed=3)
+        ru, ri, rv, _ = gen.generate(6_000).to_numpy()
+        keep = ~np.isin(ru, [0, 57, 119]) & ~np.isin(ri, [3, 89])
+        ru, ri, rv = ru[keep], ri[keep], np.abs(rv[keep])
+        n = len(ru)
+        cfg = ALSConfig(num_factors=8, lambda_=0.05, iterations=1, seed=0,
+                        reg_mode=reg_mode, implicit_alpha=implicit_alpha)
+
+        added = []
+
+        def spy(operand, indices, updates, **params):
+            added.append(updates.shape)
+            return type(scatter_add_p).bind(scatter_add_p, operand, indices,
+                                            updates, **params)
+
+        # every eager call, and every program traced during the fit
+        scatter_add_p.bind = spy
+        try:
+            model = ALS(cfg).fit_device(ru, ri, rv, 120, 90)
+        finally:
+            del scatter_add_p.bind
+        assert all(int(np.prod(shape)) < n for shape in added), added
+
+        want_u = np.bincount(ru, minlength=120).astype(np.float32)
+        want_v = np.bincount(ri, minlength=90).astype(np.float32)
+        np.testing.assert_array_equal(model.users.omega, want_u)
+        np.testing.assert_array_equal(model.items.omega, want_v)
+        assert set(np.nonzero(model.users.ids < 0)[0]) == {0, 57, 119}
+        assert set(np.nonzero(model.items.ids < 0)[0]) == {3, 89}
+        V = np.asarray(model.V)
+        assert (V[[3, 89]] == 0).all()
+        assert (np.abs(V[want_v > 0]).sum(axis=1) > 0).all()
+        assert (np.asarray(model.U)[[0, 57, 119]] == 0).all()
+        assert float(model.predict(np.array([57]), np.array([5]))[0]) == 0.0
+        # the init is by id: without the unseen last item in the vocabulary
+        # the first half-step reads the same V, its masked row apart
+        short = ALS(cfg).fit_device(ru, ri, rv, 120, 89)
+        np.testing.assert_allclose(np.asarray(short.U), np.asarray(model.U),
+                                   rtol=0, atol=1e-5)
 
     def test_implicit_mode_matches_host_fit_ranking(self):
         """Same planted-propensity setup as the host iALS ranking test:
