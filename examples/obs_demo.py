@@ -73,7 +73,6 @@ def main(argv=None) -> int:
     # flight recorder right after, so event hooks bind too and the
     # sampler is already recording the lead-up when the incident hits
     reg, tracer = obs.enable()
-    tracer.install_jax_compile_hook()
     recorder, journal = obs.enable_flight_recorder(
         interval_s=0.25, bundle_dir=os.path.join(args.out, "postmortem"),
         # watchdog-trip bundles get a short jax.profiler capture

@@ -13,9 +13,9 @@ hides an XLA compile inside it. The tracer makes both visible:
   given key is seen the span is categorized ``"compile"`` (the call
   carried the XLA compile), every later sighting ``"execute"`` — the
   ALX-style first-call/steady-state split, distinguishable in the
-  exported trace. ``install_jax_compile_hook()`` additionally taps
-  ``jax.monitoring`` (where available) so backend-reported compile
-  durations land in the registry as ``jax_compile_s``.
+  exported trace. (Backend-reported compile counts and walls, by
+  program, are ``obs.introspect``'s: ``compile_count{key}`` /
+  ``compile_wall_s{key}``.)
 
 Spans nest via a thread-local stack (each thread traces independently;
 a background retrain thread's spans carry its own ``tid``), and export
@@ -631,32 +631,6 @@ class Tracer:
                 "args": full_args,
             })
 
-    # -- JAX compile hook ----------------------------------------------------
-
-    def install_jax_compile_hook(self, registry=None) -> bool:
-        """Tap ``jax.monitoring`` duration events: backend compile events
-        land in ``registry`` (default: the module-level one) as a
-        ``jax_compile_s`` histogram and in the trace as instant events.
-        Returns True (a ``NullTracer`` installs nothing and returns
-        False)."""
-        from jax import monitoring
-
-        if registry is None:
-            from large_scale_recommendation_tpu.obs.registry import (
-                get_registry,
-            )
-
-            registry = get_registry()
-
-        def _listener(event: str, duration: float, **kwargs) -> None:
-            if "compile" not in event:
-                return
-            registry.histogram("jax_compile_s", event=event).observe(duration)
-            self.instant("jax_compile", event=event, duration_s=duration)
-
-        monitoring.register_event_duration_secs_listener(_listener)
-        return True
-
     # -- export -------------------------------------------------------------
 
     def events(self) -> list[dict]:
@@ -738,9 +712,6 @@ class NullTracer(Tracer):
 
     def key_walls(self) -> dict:
         return {}
-
-    def install_jax_compile_hook(self, registry=None) -> bool:
-        return False
 
     def events(self) -> list[dict]:
         return []

@@ -154,12 +154,14 @@ def sgd_minibatch_update(
         )
     # named scopes: HLO metadata only, so a device trace can name the
     # phases of the sweep (the residual is scoped inside the updater,
-    # core.updaters._errors); no arithmetic moves
+    # core.updaters._errors); no arithmetic moves. A scope opened inside
+    # another nests under it: sgd/gather/omega, sgd/update/collision_counts
     with jax.named_scope("sgd/gather"):
         u = U[u_rows]
         v = V[i_rows]
-        ou = None if omega_u is None else omega_u[u_rows]
-        ov = None if omega_v is None else omega_v[i_rows]
+        with jax.named_scope("omega"):
+            ou = None if omega_u is None else omega_u[u_rows]
+            ov = None if omega_v is None else omega_v[i_rows]
     with jax.named_scope("sgd/update"):
         pred = None
         if pred_axis is not None:
@@ -179,10 +181,13 @@ def sgd_minibatch_update(
                 du = du * inv_cu[:, None]
                 dv = dv * inv_cv[:, None]
             else:
-                cu = jnp.zeros(U.shape[0], U.dtype).at[u_rows].add(weights)
-                cv = jnp.zeros(V.shape[0], V.dtype).at[i_rows].add(weights)
-                du = du / jnp.maximum(cu[u_rows], 1.0)[:, None]
-                dv = dv / jnp.maximum(cv[i_rows], 1.0)[:, None]
+                with jax.named_scope("collision_counts"):
+                    cu = jnp.zeros(U.shape[0], U.dtype).at[u_rows].add(
+                        weights)
+                    cv = jnp.zeros(V.shape[0], V.dtype).at[i_rows].add(
+                        weights)
+                    du = du / jnp.maximum(cu[u_rows], 1.0)[:, None]
+                    dv = dv / jnp.maximum(cv[i_rows], 1.0)[:, None]
     with jax.named_scope("sgd/scatter_u"):
         U = U.at[u_rows].add(du)
     with jax.named_scope("sgd/scatter_v"):

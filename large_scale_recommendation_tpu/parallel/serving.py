@@ -321,8 +321,9 @@ def run_pipelined_topk(user_rows, *, k: int, k_out: int, n_rows: int,
     become row 0 / -inf, keeping the single-device contract (rows are
     always valid table indices, dead slots identified by score). ONE
     copy of the pipeline + clamp so the per-call path and the engine
-    cannot drift. ``on_batch(bucket)`` observes each dispatched bucket;
-    each drain (the wait for the device and the copy back) is the seam
+    cannot drift. ``on_batch(bucket, c)`` observes each dispatched bucket
+    and how many of its rows are real; each drain (the wait for the
+    device and the copy back) is the seam
     ``serving/pipeline/drain``, opened through ``seam`` (default: the
     installed tracer's ``seam`` — ``obs.trace.SEAMS``).
     """
@@ -352,7 +353,7 @@ def run_pipelined_topk(user_rows, *, k: int, k_out: int, n_rows: int,
             cu = np.concatenate([cu, np.zeros(bucket - c, cu.dtype)])
         v_top, r_top = score_chunk(cu, c)
         if on_batch is not None:
-            on_batch(bucket)
+            on_batch(bucket, c)
         if pending is not None:
             drain(pending)
         pending = (c0, c, v_top, r_top)

@@ -887,7 +887,7 @@ class ServingEngine:
         if self._retriever is not None:
             ret = self._retriever
 
-            def base_chunk(cu, c):
+            def score_chunk(cu, c):
                 with seam("serving/engine/excl"):
                     excl = self._build_excl(cu, c)
                 with seam("serving/engine/gather"):
@@ -904,7 +904,7 @@ class ServingEngine:
         else:
             cat, step = self._catalog, self._step
 
-            def base_chunk(cu, c):
+            def score_chunk(cu, c):
                 with seam("serving/engine/excl"):
                     excl = self._build_excl(cu, c)
                 with seam("serving/engine/gather"):
@@ -917,32 +917,15 @@ class ServingEngine:
             k_out, n_rows, slice_size = (self._k_out, cat.n_rows,
                                          self.max_batch)
 
-        if self._obs_on:
-            def score_chunk(cu, c):
-                # per-pow2-bucket score wall: host exclusion build +
-                # dispatch (the two-deep pipeline means device drain is
-                # attributed to the flush-level synced histogram, not
-                # here — blocking per chunk would serialize the overlap
-                # the engine exists to provide)
-                t0 = time.perf_counter()
-                out = base_chunk(cu, c)
-                bucket = len(cu)
-                self._obs.histogram("serving_score_s",
-                                    bucket=bucket).observe(
-                    time.perf_counter() - t0)
-                self._obs.gauge("serving_bucket_occupancy",
-                                bucket=bucket).set(c / bucket)
-                return out
-        else:
-            score_chunk = base_chunk
-
-        def on_batch(bucket):
+        def on_batch(bucket, c):
             self.stats["microbatches"] += 1
             hist = self.stats["buckets"]
             hist[bucket] = hist.get(bucket, 0) + 1
             if self._obs_on:
                 self._obs.counter("serving_microbatches_total",
                                   bucket=bucket).inc()
+                self._obs.gauge("serving_bucket_occupancy",
+                                bucket=bucket).set(c / bucket)
 
         # armed in debug/CI, a shared null context otherwise: every
         # host→device crossing inside the scoring pipeline must be an

@@ -81,7 +81,7 @@ class TestServingEngineMetrics:
     # engine's submit/flush/refresh paths
     EXPECTED = {
         "serving_queue_wait_s", "serving_batch_assembly_s",
-        "serving_flush_s", "serving_score_s", "serving_bucket_occupancy",
+        "serving_flush_s", "serving_bucket_occupancy",
         "serving_requests_total", "serving_rows_total",
         "serving_microbatches_total", "serving_catalog_swaps_total",
         "serving_catalog_version",
@@ -98,9 +98,9 @@ class TestServingEngineMetrics:
         assert reg.counter("serving_requests_total").value == 10
         assert reg.counter("serving_rows_total").value == 120
         assert reg.histogram("serving_queue_wait_s").count == 10
-        # per-pow2-bucket labels on the score histograms
+        # per-pow2-bucket labels on the micro-batch counters
         buckets = {dict(h.labels)["bucket"]
-                   for h in reg.find("serving_score_s")}
+                   for h in reg.find("serving_microbatches_total")}
         assert buckets  # at least one bucket exercised
         assert all(int(b) & (int(b) - 1) == 0 for b in buckets)
 

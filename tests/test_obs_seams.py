@@ -295,6 +295,75 @@ def test_the_shared_gram_is_a_scope_of_the_implicit_half_step():
         als_ops._full_gram = real
 
 
+def _sweep_text(collision, with_inv, debug_info=True):
+    """The lowered text of ``sgd_block_sweep`` over two minibatches of
+    four ratings (debug info on: the scopes are in its locations)."""
+    import jax.numpy as jnp
+
+    from large_scale_recommendation_tpu.core.updaters import (
+        RegularizedSGDUpdater,
+    )
+    from large_scale_recommendation_tpu.ops import sgd
+
+    e, updater = 8, RegularizedSGDUpdater()
+    rows = jnp.arange(e, dtype=jnp.int32)
+    inv = (jnp.ones(e),) * 2 if with_inv else (None, None)
+
+    def sweep(U, V, omega_u, omega_v):
+        return sgd.sgd_block_sweep(
+            U, V, rows, rows, jnp.ones(e), jnp.ones(e), omega_u, omega_v,
+            updater, 0, 4, collision=collision, inv_cu=inv[0],
+            inv_cv=inv[1])
+
+    return jax.jit(sweep).lower(
+        jnp.ones((16, 4)), jnp.ones((12, 4)), jnp.ones(16),
+        jnp.ones(12)).as_text(debug_info=debug_info)
+
+
+@pytest.mark.parametrize("with_inv", [False, True])
+def test_the_omega_gathers_are_a_scope_nested_in_the_gathers(with_inv):
+    """``sgd/gather/omega`` names the two 4-byte gathers and no row gather:
+    ``sweep_omega_gather_ms`` reads them, ``sweep_gather_ms`` (which
+    matches ``sgd/gather`` and what is nested in it) still holds all
+    four."""
+    text = _sweep_text("mean", with_inv)
+    assert text.count('"sgd/gather/omega/gather"') == 2
+    assert text.count('"sgd/gather/gather"') == 2  # the row gathers
+    assert "sgd/gather/sgd/gather" not in text  # nested, not a literal
+
+
+@pytest.mark.parametrize("collision,with_inv,scatter_adds", [
+    ("mean", False, 2), ("mean", True, 0), ("sum", False, 0)])
+def test_the_count_vectors_are_a_scope_nested_in_the_update(
+        collision, with_inv, scatter_adds):
+    """``sgd/update/collision_counts`` names the runtime count vectors of
+    ``collision="mean"`` (two scatter-adds, two gathers back); a sweep
+    that is handed ``inv_cu`` (the DSGD fits) or sums runs none of it and
+    holds no such name: ``online_count_ms`` is then left out."""
+    text = _sweep_text(collision, with_inv)
+    counts = "sgd/update/collision_counts/"
+    assert text.count(f'"{counts}scatter-add"') == scatter_adds
+    assert text.count(f'"{counts}gather"') == scatter_adds
+    assert (counts in text) == bool(scatter_adds)
+    assert '"sgd/update/residual/' in text  # the older nested scope
+
+
+def test_the_scopes_are_metadata_and_no_part_of_the_computation(monkeypatch):
+    """Without its debug info the sweep lowers to the same text with the
+    scopes and without them. (It is also why JAX's persistent compile
+    cache, which keys a program by that text, hands back an executable
+    compiled before a scope was added: docs/OBSERVABILITY.md.)"""
+    import contextlib
+
+    scoped = _sweep_text("mean", False, debug_info=False)
+    assert "collision_counts" not in scoped
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _sweep_text("mean", False)
+    assert "sgd/" not in bare and "scatter-add" in bare
+    assert _sweep_text("mean", False, debug_info=False) == scoped
+
+
 def test_the_docs_list_the_scope_the_gauge_and_the_counter():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "docs", "OBSERVABILITY.md")) as f:
@@ -304,6 +373,8 @@ def test_the_docs_list_the_scope_the_gauge_and_the_counter():
                  "eval_percentile_rank{source=}"):
         assert any(f"`{name}`" in r.split("|")[1] for r in rows), name
     assert "`als/shared_gram`" in text
+    for scope in ("sgd/gather/omega", "sgd/update/collision_counts"):
+        assert any(f"`{scope}`" in r.split("|")[1] for r in rows), scope
 
 
 def test_unknown_seam_is_refused():
