@@ -101,6 +101,28 @@ def dsgd_flops_per_sweep(nnz: int, rank: int) -> int:
     return int(nnz * 6 * rank)
 
 
+def _lane_view(omega: jax.Array) -> jax.Array:
+    """A 1-D per-row ``omega`` as rows of 128 lanes: zero-padded to a
+    whole row and reshaped to ``[ceil(h/128), 128]``, once a block (the
+    argument ``sgd_minibatch_update`` takes; ``_take_lane`` reads it)."""
+    return jnp.pad(omega, (0, -omega.shape[0] % 128)).reshape(-1, 128)
+
+
+def _take_lane(view: jax.Array, rows: jax.Array) -> jax.Array:
+    """``omega[rows]`` from ``view = _lane_view(omega)``, rows ``>= 0``: the
+    same bits, read a 128-lane row at a time.
+
+    On the TPU a gather whose slice is one float32 element costs about
+    7 ns an index, one whose slice is a 128-lane row about 1.5 ns (PERF.md,
+    Findings, PR 39). So each index gathers its whole row and a select
+    keeps its lane: a sum of one value and 127 zeros is that value. Not a
+    one-hot ``dot``, which the TPU's default precision rounds to bfloat16.
+    """
+    lane = jax.lax.broadcasted_iota(rows.dtype, (1, 128), 1)
+    picked = view[rows >> 7]  # row rows // 128, lane rows % 128
+    return jnp.where(lane == (rows & 127)[:, None], picked, 0).sum(-1)
+
+
 def sgd_minibatch_update(
     U: jax.Array,
     V: jax.Array,
@@ -140,6 +162,10 @@ def sgd_minibatch_update(
     With ``minibatch=1`` both modes recover the reference's exact sequential
     per-rating semantics.
 
+    ``omega_u``/``omega_v`` are the per-row ω as lane views
+    (``_lane_view``, which ``sgd_block_sweep`` builds once a block), or
+    ``None``.
+
     ``pred_axis`` names the mesh axis U/V are rank-sharded over (the
     ``'model'`` axis inside a shard_map): each device then holds only
     ``rank/m`` columns, the local einsum is a PARTIAL dot, and the full
@@ -160,8 +186,8 @@ def sgd_minibatch_update(
         u = U[u_rows]
         v = V[i_rows]
         with jax.named_scope("omega"):
-            ou = None if omega_u is None else omega_u[u_rows]
-            ov = None if omega_v is None else omega_v[i_rows]
+            ou = None if omega_u is None else _take_lane(omega_u, u_rows)
+            ov = None if omega_v is None else _take_lane(omega_v, i_rows)
     with jax.named_scope("sgd/update"):
         pred = None
         if pred_axis is not None:
@@ -228,6 +254,10 @@ def sgd_block_sweep(
         return a.reshape(n_chunks, minibatch)
 
     pre = inv_cu is not None
+    # built here, before the scan: a view built in the step is sunk into
+    # the loop by XLA, a pad a minibatch (tests/test_tpu_compile.py)
+    omega_u = None if omega_u is None else _lane_view(omega_u)
+    omega_v = None if omega_v is None else _lane_view(omega_v)
 
     def body(carry, xs):
         U, V = carry

@@ -35,8 +35,9 @@ class TestKernelOracle:
 
         Un, Vn = sgd_ops.sgd_minibatch_update(
             jnp.array(U), jnp.array(V), jnp.array(ur), jnp.array(ir),
-            jnp.array(vals), jnp.array(w), jnp.array(omega), jnp.array(omega),
-            upd, 1, collision="sum")
+            jnp.array(vals), jnp.array(w),
+            sgd_ops._lane_view(jnp.array(omega)),
+            sgd_ops._lane_view(jnp.array(omega)), upd, 1, collision="sum")
 
         # NumPy oracle: additive deltas from OLD factors, accumulated
         eU, eV = U.copy(), V.copy()
@@ -59,7 +60,8 @@ class TestKernelOracle:
         Un, Vn = sgd_ops.sgd_minibatch_update(
             jnp.array(U), jnp.array(V), jnp.array(ur), jnp.array(ur),
             jnp.zeros(8, jnp.float32), jnp.array(w),
-            jnp.ones(10), jnp.ones(10), upd, 1)
+            sgd_ops._lane_view(jnp.ones(10)),
+            sgd_ops._lane_view(jnp.ones(10)), upd, 1)
         np.testing.assert_array_equal(np.asarray(Un), U)
         np.testing.assert_array_equal(np.asarray(Vn), V)
 
@@ -256,3 +258,49 @@ class TestPrecomputedCollisions:
                     c = int(((rows == rows[j]) & (w > 0)).sum())
                     np.testing.assert_allclose(flat_icu[a + j], 1.0 / c,
                                                rtol=1e-6)
+
+
+class TestOmegaByLaneRow:
+    """The sweep reads ω a 128-lane row at a time (PR 39): the same bits
+    as the per-element gather ``omega[rows]`` it replaced."""
+
+    @pytest.mark.parametrize("h", [1, 8, 127, 128, 129, 2224, 4448, 60024])
+    def test_take_lane_is_the_gather_bit_for_bit(self, h):
+        rng = np.random.default_rng(h)
+        omega = rng.integers(0, 3000, h).astype(np.float32)
+        omega[rng.integers(0, h, 3)] = [0.0, 1.0, 2.0 ** 24]
+        rows = np.concatenate([[0, h - 1], rng.integers(0, h, 509)])
+        got = sgd_ops._take_lane(sgd_ops._lane_view(jnp.asarray(omega)),
+                                 jnp.asarray(rows, jnp.int32))
+        np.testing.assert_array_equal(np.asarray(got), omega[rows])
+
+    @pytest.mark.parametrize("with_omega", [True, False])
+    def test_block_sweep_gives_the_per_element_gathers_tables(
+            self, monkeypatch, with_omega):
+        """``sgd_block_sweep`` with the lane lookup and with the parent's
+        ``omega[rows]`` (the view's first ``h`` values) give the same
+        tables, bit for bit; without omegas the lookup never runs."""
+        rng = np.random.default_rng(3)
+        nu, nv, rank, e, mb = 300, 140, 8, 512, 64
+        args = (jnp.asarray(rng.normal(size=(nu, rank)), jnp.float32),
+                jnp.asarray(rng.normal(size=(nv, rank)), jnp.float32),
+                jnp.asarray(rng.integers(0, nu, e), jnp.int32),
+                jnp.asarray(rng.integers(0, nv, e), jnp.int32),
+                jnp.asarray(rng.normal(size=e), jnp.float32),
+                jnp.ones(e, jnp.float32))
+        omegas = ((jnp.asarray(rng.integers(1, 40, nu), jnp.float32),
+                   jnp.asarray(rng.integers(1, 40, nv), jnp.float32))
+                  if with_omega else (None, None))
+        upd = RegularizedSGDUpdater(learning_rate=0.05, lambda_=0.3)
+
+        def sweep():
+            return sgd_ops.sgd_block_sweep(*args, *omegas, upd, 1, mb,
+                                           "mean")
+
+        lanes = sweep()
+        monkeypatch.setattr(sgd_ops, "_take_lane",
+                            lambda view, rows: view.reshape(-1)[rows])
+        gathers = sweep()
+        for a, b in zip(lanes, gathers):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert not np.array_equal(np.asarray(lanes[0]), np.asarray(args[0]))

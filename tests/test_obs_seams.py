@@ -322,12 +322,15 @@ def _sweep_text(collision, with_inv, debug_info=True):
 
 @pytest.mark.parametrize("with_inv", [False, True])
 def test_the_omega_gathers_are_a_scope_nested_in_the_gathers(with_inv):
-    """``sgd/gather/omega`` names the two 4-byte gathers and no row gather:
-    ``sweep_omega_gather_ms`` reads them, ``sweep_gather_ms`` (which
-    matches ``sgd/gather`` and what is nested in it) still holds all
-    four."""
+    """``sgd/gather/omega`` names the two omega gathers (a 128-lane row of
+    the lane view each, since PR 39) and their lane select, and no factor
+    row gather: ``sweep_omega_gather_ms`` reads them, ``sweep_gather_ms``
+    (which matches ``sgd/gather`` and what is nested in it) still holds
+    all four gathers."""
     text = _sweep_text("mean", with_inv)
     assert text.count('"sgd/gather/omega/gather"') == 2
+    for op in ("jit(_where)", "reduce_sum"):  # the lane select, a side each
+        assert text.count(f'"sgd/gather/omega/{op}"') == 2, op
     assert text.count('"sgd/gather/gather"') == 2  # the row gathers
     assert "sgd/gather/sgd/gather" not in text  # nested, not a literal
 

@@ -59,6 +59,11 @@ def test_report_of_a_toy_cell(cell, seams, programs, null_obs):
         get_introspector,
     )
 
+    import jax
+
+    # a program that an earlier test of this process compiled at the same
+    # toy shapes is not compiled again: the funnel would see none of it
+    jax.clear_caches()
     doc = setup_report.report(cell, 3000000000, 2.0, off_chip=True)
     assert doc["correct"]
     assert seams <= set(doc["warmup_seams"])

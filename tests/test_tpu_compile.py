@@ -61,33 +61,39 @@ def test_stage1_flat_holds_the_scores_once(one_chip, b):
             assert math.prod(map(int, shape[1].split(","))) < b * n, line
 
 
-def _inside_loops(hlo: str) -> str:
+def _inside_loops(hlo: str, nested: bool = False) -> str:
     """The text of every computation a ``while`` of the module reaches
-    (its body and condition, and what they call, fuse or loop over)."""
+    (its body and condition, and what they call, fuse or loop over);
+    ``nested``: only of the ``while``s that lie inside another loop."""
     comps = dict(re.findall(r"^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)^\}",
                             hlo, re.S | re.M))
     calls = re.compile(
         r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
-    todo = [c for body in comps.values() for line in body.splitlines()
-            if " while(" in line for c in calls.findall(line)]
-    seen = set()
-    while todo:
-        name = todo.pop()
-        if name not in seen and name in comps:
-            seen.add(name)
-            todo += calls.findall(comps[name])
+    loops = {name: [c for line in body.splitlines() if " while(" in line
+                    for c in calls.findall(line)]
+             for name, body in comps.items()}
+
+    def reach(todo):
+        seen = set()
+        while todo:
+            name = todo.pop()
+            if name not in seen and name in comps:
+                seen.add(name)
+                todo += calls.findall(comps[name])
+        return seen
+
+    seen = reach([c for cs in loops.values() for c in cs])
+    if nested:
+        seen = reach([c for name in seen for c in loops[name]])
     assert seen, "no loop found in the compiled program"
     return "\n".join(comps[name] for name in seen)
 
 
-def test_dsgd_train_scatters_into_the_visited_blocks(one_chip):
-    """``ops.sgd.dsgd_train`` at the fit cell's widths (U ``f32[480192,
-    128]``, V ``f32[17792,128]``, ``k = 8``, minibatch 32768; two
-    minibatches a block are enough for the program's shape): both
-    scatter-adds land in one row block (a k-th of the table), and the
-    block's slice and write-back do not bring a copy of the whole U into
-    the loops. On the chip the scatter into the whole 246 MB table cost
-    nine times what it costs into a shard (PERF.md Findings, PR 32)."""
+@pytest.fixture(scope="module")
+def dsgd_fit_hlo(one_chip):
+    """``ops.sgd.dsgd_train`` compiled at the fit cell's widths (U
+    ``f32[480192,128]``, V ``f32[17792,128]``, ``k = 8``, minibatch 32768;
+    two minibatches a block are enough for the program's shape)."""
     from large_scale_recommendation_tpu.core.updaters import (
         RegularizedSGDUpdater,
         warm_boost_lr,
@@ -98,21 +104,49 @@ def test_dsgd_train_scatters_into_the_visited_blocks(one_chip):
     f32, i32 = jnp.float32, jnp.int32
     sds = partial(jax.ShapeDtypeStruct, sharding=one_chip)
     blocks = (k, k, 2 * mb)
-    compiled = dsgd_train.lower(
+    return dsgd_train.lower(
         sds((nu, rank), f32), sds((nv, rank), f32),
         sds(blocks, i32), sds(blocks, i32), sds(blocks, f32),
         sds(blocks, f32), sds((nu,), f32), sds((nv,), f32),
         sds(blocks, f32), sds(blocks, f32),
         updater=RegularizedSGDUpdater(learning_rate=0.3, lambda_=0.1,
                                       schedule=warm_boost_lr(0.75, 2)),
-        minibatch=mb, num_blocks=k, iterations=1).compile()
-    hlo = compiled.as_text()
+        minibatch=mb, num_blocks=k, iterations=1).compile().as_text()
+
+
+def test_dsgd_train_scatters_into_the_visited_blocks(dsgd_fit_hlo):
+    """Both scatter-adds of the fit cell's ``dsgd_train`` land in one row
+    block (a k-th of the table), and the block's slice and write-back do
+    not bring a copy of the whole U into the loops. On the chip the
+    scatter into the whole 246 MB table cost nine times what it costs
+    into a shard (PERF.md Findings, PR 32)."""
+    rank, nu, nv, k = 128, 480192, 17792, 8
+    hlo = dsgd_fit_hlo
     scatters = re.findall(r"= (\w+\[[\d,]*\])\S* scatter\(", hlo)
     assert sorted(set(scatters)) == [f"f32[{nv // k},{rank}]",
                                      f"f32[{nu // k},{rank}]"], scatters
     loops = _inside_loops(hlo)
     assert " scatter(" in loops
     assert not re.search(rf"= f32\[{nu},{rank}\]\S* copy\(", loops)
+
+
+def test_dsgd_train_reads_the_omegas_a_lane_row_at_a_time(dsgd_fit_hlo):
+    """The fit cell's two omega gathers (scope ``sgd/gather/omega``) each
+    take a 128-lane row of the block's lane view, ``f32[469,128]`` and
+    ``f32[18,128]`` (60,024 and 2,224 rows padded), not one float32 element
+    (7 ns an index against 1.5 ns on the chip: PERF.md Findings, PR 39).
+    The views are built once a block visit: no pad and no copy of an
+    omega table runs in the minibatch loop."""
+    gathers = [line for line in dsgd_fit_hlo.splitlines()
+               if " gather(" in line and "sgd/gather/omega/" in line]
+    assert len(gathers) == 2, gathers
+    assert all("slice_sizes={1,128}" in g for g in gathers), gathers
+    minibatch = _inside_loops(dsgd_fit_hlo, nested=True)
+    assert " gather(" in minibatch and " scatter(" in minibatch
+    assert " pad(" not in minibatch
+    assert not re.search(
+        r"= f32\[(60024|2224|469,128|18,128)\]\S* (copy|pad|fusion)\(",
+        minibatch)
 
 
 @pytest.mark.parametrize("shared_gram", [False, True],
