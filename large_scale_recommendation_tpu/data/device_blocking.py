@@ -21,6 +21,14 @@ materializes the ``k × k × bmax`` stratum expansion at all:
   than the padded stratum layout + collision scales, which are built on
   chip.
 
+On the stratum ring (``mesh_block_problem``, what ``MeshDSGD.fit_device``
+runs) the same pass is split over the chips: each chip counts, places in
+the seeded shuffle and sorts only its own share of the entries, and one
+``all_to_all`` hands every entry to the chip that owns its user block,
+which lays out its own row of the device-major layout. The result is the
+one-chip layout bit for bit; no chip holds more than its share of the
+entries or of the layout, so the ring blocks shapes no one chip can.
+
 Scope: dense, pre-compacted ids in ``[0, num_users) × [0, num_items)`` —
 the contract of production feature pipelines and of the synthetic
 generators. Arbitrary external ids go through the host path
@@ -49,6 +57,7 @@ deterministic, they just draw their permutations from different RNGs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from functools import partial
 
 import numpy as np
@@ -467,16 +476,17 @@ def _inv_counts_pair(su2, si2, sw2):
     return _inv_counts_2d(su2, sw2), _inv_counts_2d(si2, sw2)
 
 
-@partial(jax.jit, static_argnames=("k", "bmax", "mb", "sort_side"))
-def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
-            k: int, bmax: int, mb: int, sort_side: str | None):
-    """Copy bucket-sorted entries into the padded [k, k, bmax] layout and
-    compute the per-minibatch collision scales (both sides) on device.
+def _layout_rows(urow_s, irow_s, vals_s, w_s, sizes, bmax: int, mb: int,
+                 sort_side: str | None):
+    """Copy bucket-sorted entries into one padded row of ``bmax`` slots a
+    bucket (``sizes`` gives the buckets in the order the entries hold
+    them) and compute the per-minibatch collision scales (both sides).
 
     The entries arrive bucket-sorted, so bucket ``b`` is one contiguous run
-    of them and one contiguous row of the layout: k² block copies, no
-    per-element scatter (``flat_s`` is implied by ``sizes`` and unread)."""
-    del flat_s
+    of them and one contiguous row of the layout: one block copy a bucket,
+    no per-element scatter. Returns six minibatch-major ``[buckets * bmax
+    / mb, mb]`` arrays."""
+    nb = sizes.shape[0]
     with jax.named_scope("layout/offsets"):
         starts = jnp.cumsum(sizes) - sizes
     with jax.named_scope("layout/copy"):
@@ -492,9 +502,10 @@ def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
                           0)
                 for a in src)
 
-        # [k², bmax] rows of whole minibatches -> the minibatch-major view
+        # [buckets, bmax] rows of whole minibatches -> the minibatch-major
+        # view
         su, si, sv, sw = (a.reshape(-1, mb) for a in jax.lax.map(
-            copy_bucket, jnp.arange(k * k, dtype=jnp.int32)))
+            copy_bucket, jnp.arange(nb, dtype=jnp.int32)))
 
     if sort_side is not None:
         # intra-minibatch locality sort (≙ blocking.block_ratings
@@ -515,9 +526,18 @@ def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
         icu = _inv_counts_2d(su, sw, presorted=sort_side == "user")
     with jax.named_scope("layout/inv_counts_v"):
         icv = _inv_counts_2d(si, sw, presorted=sort_side == "item")
+    return su, si, sv, sw, icu, icv
+
+
+@partial(jax.jit, static_argnames=("k", "bmax", "mb", "sort_side"))
+def _layout(flat_s, urow_s, irow_s, vals_s, w_s, sizes,
+            k: int, bmax: int, mb: int, sort_side: str | None):
+    """The padded stratum-major ``[k, k, bmax]`` layout of the k² buckets
+    (``flat_s`` is implied by ``sizes`` and unread)."""
+    del flat_s
     shape = (k, k, bmax)
-    return (su.reshape(shape), si.reshape(shape), sv.reshape(shape),
-            sw.reshape(shape), icu.reshape(shape), icv.reshape(shape))
+    return tuple(a.reshape(shape) for a in _layout_rows(
+        urow_s, irow_s, vals_s, w_s, sizes, bmax, mb, sort_side))
 
 
 def device_block_problem(
@@ -599,6 +619,363 @@ def device_block_problem(
             nnz=nnz, max_pad_ratio=(k * k * bmax) / max(nnz, 1),
             minibatch=mbm,
         )
+
+
+# --------------------------------------------------------------------------
+# Blocking over a mesh (the stratum ring): each chip blocks its own share
+# --------------------------------------------------------------------------
+
+# the words one all_to_all moves an entry, int32 each (floats bitcast):
+# bucket, place in the shuffle, user row, item row, value, weight
+_EXCHANGED = 6
+
+
+@dataclasses.dataclass
+class MeshBlockedProblem:
+    """What ``mesh_block_problem`` hands the stratum ring: the device-major
+    ``[k, k, bmax]`` arrays, row ``p`` on ring position ``p`` (cell
+    ``[p, s]`` is rating block ``(p, (p+s) mod k)``) with rows LOCAL to
+    the owning block; the omegas and the id maps replicated. The same
+    numbers as ``device_block_problem`` followed by the transposes to
+    device-major, bit for bit."""
+
+    ru: jax.Array  # int32[k, k, bmax] local user rows, sharded on dim 0
+    ri: jax.Array  # int32[k, k, bmax] local item rows
+    rv: jax.Array  # float32[k, k, bmax]
+    rw: jax.Array  # float32[k, k, bmax] 1=real 0=pad
+    icu: jax.Array  # float32[k, k, bmax] collision scales (user side)
+    icv: jax.Array  # float32[k, k, bmax] (item side)
+    omega_u: jax.Array  # float32[num_user_rows], replicated
+    omega_v: jax.Array
+    row_of_user: jax.Array  # int32[num_users], replicated
+    row_of_item: jax.Array
+    id_of_user_row: jax.Array
+    id_of_item_row: jax.Array
+    num_blocks: int
+    rows_per_block_u: int
+    rows_per_block_v: int
+    nnz: int
+    max_pad_ratio: float
+    minibatch: int
+    exchange_bytes: int  # what one chip sends the others in the exchange
+
+    to_id_indices = DeviceBlockedProblem.to_id_indices
+    holdout_rows = DeviceBlockedProblem.holdout_rows
+
+
+def _entry_shards(cols, n: int, q: int, sharding, k: int):
+    """Each column (a host array or an array on one device, ``n`` long) as
+    a global array of ``k * q`` entries sharded on dim 0: ring position
+    ``p`` holds entries ``[p * q, (p + 1) * q)``, zero-filled past ``n``.
+    A device array is sliced where it lies and each slice sent to its
+    chip."""
+    index = sharding.addressable_devices_indices_map((k * q,))
+    out = []
+    for x in cols:
+        pieces = []
+        for dev, (sl,) in index.items():
+            lo = sl.start or 0
+            hi = max(min(lo + q, n), lo)
+            piece = x[lo:hi]
+            if hi - lo < q:
+                pad = (0, q - (hi - lo))
+                piece = (jnp.pad(piece, pad) if isinstance(piece, jax.Array)
+                         else np.pad(piece, pad))
+            pieces.append(jax.device_put(piece, dev))
+        out.append(jax.make_array_from_single_device_arrays(
+            (k * q,), sharding, pieces))
+    return out
+
+
+def _sharded(part, fn, in_specs, out_specs):
+    return jax.jit(jax.shard_map(fn, mesh=part.mesh, in_specs=in_specs,
+                                 out_specs=out_specs))
+
+
+@functools.lru_cache(maxsize=16)
+def _mesh_ones(part, size: int):
+    return jax.jit(partial(jnp.ones, (size,), jnp.float32),
+                   out_shardings=part.sharding("ratings"))
+
+
+def _gather_rows(row, axis: str, k: int):
+    """``[k, len(row)]``, every chip's ``row`` at its ring position, the
+    same on every chip (a sum of one-hot rows over the ring)."""
+    me = jnp.arange(k)[:, None] == jax.lax.axis_index(axis)
+    return jax.lax.psum(jnp.where(me, row[None, :], 0), axis)
+
+
+@functools.lru_cache(maxsize=16)
+def _mesh_counts(part, n: int, q: int, num_users: int, num_items: int):
+    """Per chip: its share's occurrence counts, summed over the ring (exact
+    integers, so the sum is one chip's count of every entry), its own
+    per-user counts and its weight-0 entries by the chip they go to."""
+    axis, k = part.data_axis, part.num_blocks
+    shard, rep = part.spec("ratings"), part.spec()
+
+    def _weighted_counts(u, i, w):
+        gidx = (jax.lax.axis_index(axis) * q
+                + jnp.arange(q, dtype=jnp.int32))
+        present = gidx < n
+        real = (present & (w > 0)).astype(jnp.int32)
+        cu = jnp.zeros(num_users, jnp.int32).at[u].add(real)
+        cv = jnp.zeros(num_items, jnp.int32).at[i].add(real)
+        # a weight-0 entry goes to bucket gidx mod k² (_bucket_entries),
+        # which chip gidx mod k owns
+        pad = present & (w <= 0)
+        pads = jnp.sum(((gidx % k)[:, None] == jnp.arange(k)[None, :])
+                       & pad[:, None], axis=0, dtype=jnp.int32)
+        return (jax.lax.psum(cu, axis), jax.lax.psum(cv, axis), cu[None],
+                pads[None])
+
+    return _sharded(part, _weighted_counts, (shard,) * 3,
+                    (rep, rep, shard, shard))
+
+
+@functools.lru_cache(maxsize=16)
+def _mesh_send_counts(part, rpb_u: int):
+    """``[k, k]``, replicated: how many entries chip ``src`` sends chip
+    ``dst`` (the chip that owns their user block)."""
+    axis, k = part.data_axis, part.num_blocks
+    shard, rep = part.spec("ratings"), part.spec()
+
+    def _send_counts(cu, pads, row_of_u):
+        ublk = row_of_u // rpb_u
+        to = jnp.sum(jnp.where(ublk[:, None] == jnp.arange(k)[None, :],
+                               cu[0][:, None], 0), axis=0, dtype=jnp.int32)
+        return _gather_rows(to + pads[0], axis, k)
+
+    return _sharded(part, _send_counts, (shard, shard, rep), rep)
+
+
+@functools.lru_cache(maxsize=16)
+def _mesh_bucket(part, n: int, q: int, c: int, rpb_u: int, rpb_v: int):
+    """Per chip: its entries' buckets, their places in the seeded shuffle,
+    one ``all_to_all`` that hands every entry to the chip of its user
+    block (``c`` slots from each chip to each), and a sort of what it
+    received by (bucket, place). Buckets and places are unique together,
+    so each chip's buckets come out in the order ``_bucket_entries``
+    gives them on one chip."""
+    axis, k = part.data_axis, part.num_blocks
+    shard, rep = part.spec("ratings"), part.spec()
+    # an empty slot sorts after every bucket and is never laid out
+    fill = jnp.array([k * k] + [0] * (_EXCHANGED - 1), jnp.int32)[:, None]
+    as_word = partial(jax.lax.bitcast_convert_type, new_dtype=jnp.int32)
+    as_float = partial(jax.lax.bitcast_convert_type, new_dtype=jnp.float32)
+
+    def _bucket_entries(key, u, i, r, w, row_of_u, row_of_i):
+        p = jax.lax.axis_index(axis)
+        gidx = p * q + jnp.arange(q, dtype=jnp.int32)
+        with jax.named_scope("bucket/assign"):
+            # the one-chip program's keys; the round-robin of weight-0
+            # entries by their place in the whole input
+            urow = row_of_u[u]
+            irow = row_of_i[i]
+            ublk = urow // rpb_u
+            iblk = irow // rpb_v
+            flat = (((iblk - ublk) % k) * k + ublk).astype(jnp.int32)
+            flat = jnp.where(w > 0, flat, gidx % (k * k))
+            # the zero-filled tail past n is no entry: it goes nowhere
+            dest = jnp.where(gidx < n, flat % k, k)
+        with jax.named_scope("bucket/permutation"):
+            # the whole shuffle on every chip (its sorts are global), then
+            # the places of this chip's own entries
+            perm = jax.random.permutation(key, n)
+            _, rank = jax.lax.sort_key_val(
+                perm, jnp.arange(n, dtype=jnp.int32))
+            rank = jax.lax.dynamic_slice(
+                jnp.pad(rank, (0, k * q - n)), (p * q,), (q,))
+        with jax.named_scope("bucket/send"):
+            dest_s, *words = jax.lax.sort(
+                (dest, flat, rank, urow, irow, as_word(r), as_word(w)),
+                num_keys=1, is_stable=False)
+            ar = jnp.arange(k + 1, dtype=jnp.int32)
+            bounds = jnp.searchsorted(dest_s, ar)
+            first, count = bounds[:-1], jnp.diff(bounds)
+            stack = jnp.pad(jnp.stack(words), ((0, 0), (0, c)))
+            slot = jnp.arange(c, dtype=jnp.int32)
+
+            def send(d):
+                win = jax.lax.dynamic_slice(stack, (0, first[d]),
+                                            (_EXCHANGED, c))
+                return jnp.where(slot < count[d], win, fill)
+
+            out = jax.lax.map(send, ar[:k])  # [k, words, c]
+        with jax.named_scope("bucket/exchange"):
+            got = jax.lax.all_to_all(out, axis, 0, 0, tiled=True)
+        with jax.named_scope("bucket/sort"):
+            got = jnp.transpose(got, (1, 0, 2)).reshape(_EXCHANGED, k * c)
+            flat_s, _, urow_s, irow_s, v_s, w_s = jax.lax.sort(
+                tuple(got), num_keys=2, is_stable=False)
+        with jax.named_scope("bucket/sizes"):
+            mine = ar[:k] * k + p  # buckets (s, p), s = 0..k-1
+            sizes = (jnp.searchsorted(flat_s, mine + 1)
+                     - jnp.searchsorted(flat_s, mine)).astype(jnp.int32)
+        return (_gather_rows(sizes, axis, k), urow_s, irow_s,
+                as_float(v_s), as_float(w_s))
+
+    return _sharded(part, _bucket_entries,
+                    (rep,) + (shard,) * 4 + (rep, rep),
+                    (rep,) + (shard,) * 4)
+
+
+@functools.lru_cache(maxsize=16)
+def _mesh_layout(part, bmax: int, mb: int, sort_side: str | None,
+                 rpb_u: int, rpb_v: int):
+    """Per chip: its k buckets laid out as row ``p`` of the device-major
+    layout, rows made local to the chip's blocks."""
+    axis = part.data_axis
+    shard, rep = part.spec("ratings"), part.spec()
+
+    def _layout(urow_s, irow_s, vals_s, w_s, sizes):
+        su, si, sv, sw, icu, icv = (a.reshape(1, -1, bmax) for a in (
+            _layout_rows(urow_s, irow_s, vals_s, w_s,
+                         sizes[jax.lax.axis_index(axis)], bmax, mb,
+                         sort_side)))
+        return su % rpb_u, si % rpb_v, sv, sw, icu, icv
+
+    return _sharded(part, _layout, (shard,) * 4 + (rep,), (shard,) * 6)
+
+
+def exchange_slots(send: np.ndarray, q: int, k: int) -> int:
+    """Slots each chip sends each chip: a fixed 1% over an even share of
+    ``q`` (so that one shape serves every draw of the same size, and the
+    exchange compiles once), or the largest count where a pair exceeds
+    that (skewed or pre-partitioned input)."""
+    even = -(-int(q * 1.01) // k)
+    even = -(-even // 512) * 512
+    return max(int(send.max()), even, 1)
+
+
+def mesh_block_problem(
+    u,
+    i,
+    r,
+    num_users: int,
+    num_items: int,
+    partitioner,
+    minibatch_multiple: int = 1,
+    seed: int = 0,
+    row_multiple: int = 8,
+    minibatch_sort: str | None = None,
+    weights=None,
+) -> MeshBlockedProblem:
+    """``device_block_problem`` for the stratum ring, as programs over the
+    partitioner's mesh, with the same result bit for bit: no chip ever
+    holds more than its share of the entries and of the layout.
+
+    Each ring position takes a contiguous share of the COO entries (given
+    on the host or on one device, they are sliced and sent; given as
+    arrays already sharded by the partitioner's ``ratings`` rule, they
+    stay). Each chip counts its share and the counts are summed over the
+    ring (``_weighted_counts``); the balanced row assignment runs
+    replicated (``_assign_rows``, the sides' ids only); each chip finds
+    its entries' buckets and places in the seeded shuffle and one
+    ``all_to_all`` hands every entry to the chip of its user block, which
+    sorts what it received (``_bucket_entries``); and each chip lays out
+    its own row of the device-major layout (``_layout``). The host reads
+    two ``[k, k]`` count matrices (the exchange's slot count and ``bmax``
+    are static shapes) and nothing else.
+
+    The shuffle is computed whole on every chip (its sorts are global):
+    ``4 n`` bytes a chip for the places, and the sort's temporaries.
+    """
+    if minibatch_sort not in (None, "user", "item"):
+        raise ValueError(
+            f"minibatch_sort must be None|'user'|'item', got {minibatch_sort!r}")
+    part = partitioner
+    k = part.num_blocks
+    n = int(np.shape(u)[0])
+    if n == 0:
+        raise ValueError("mesh_block_problem: empty ratings input")
+    shard = part.sharding("ratings")
+    seam = get_tracer().seam
+    with seam("fit/blocking/bucket"):
+        validate_dense_ids(u, i, num_users, num_items, "mesh_block_problem")
+        cols = (u, i, r) + (() if weights is None else (weights,))
+        placed = n % k == 0 and all(
+            isinstance(x, jax.Array) and x.sharding == shard for x in cols)
+        q = n // k if placed else -(-n // k)
+        if placed:
+            u, i, r = (jnp.asarray(x, dt) for x, dt in
+                       zip(cols[:3], (jnp.int32, jnp.int32, jnp.float32)))
+            w = None if weights is None else jnp.asarray(cols[3],
+                                                         jnp.float32)
+        else:
+            as_np = not isinstance(u, jax.Array)
+            cast = (lambda x, dt: np.asarray(x, dt)) if as_np else (
+                lambda x, dt: jnp.asarray(x, dt))
+            host = [cast(u, np.int32), cast(i, np.int32),
+                    cast(r, np.float32)]
+            if weights is not None:
+                host.append(cast(weights, np.float32))
+            sent = _entry_shards(host, n, q, shard, k)
+            u, i, r = sent[:3]
+            w = sent[3] if weights is not None else None
+        if w is None:
+            w = _mesh_ones(part, k * q)()
+        base = jax.random.PRNGKey(seed)
+        rpb_u = rows_per_block(num_users, k, row_multiple)
+        rpb_v = rows_per_block(num_items, k, row_multiple)
+
+        counts_u, counts_v, cu_own, pads = _mesh_counts(
+            part, n, q, num_users, num_items)(u, i, w)
+        row_of_u, omega_u, id_of_ur = _assign_rows(
+            jax.random.fold_in(base, 10), counts_u, k, rpb_u, k * rpb_u)
+        row_of_i, omega_v, id_of_ir = _assign_rows(
+            jax.random.fold_in(base, 11), counts_v, k, rpb_v, k * rpb_v)
+        send = np.asarray(_mesh_send_counts(part, rpb_u)(
+            cu_own, pads, row_of_u))
+        c = exchange_slots(send, q, k)
+        del cu_own, pads
+        sizes, urow_s, irow_s, vals_s, w_s = _mesh_bucket(
+            part, n, q, c, rpb_u, rpb_v)(
+            jax.random.fold_in(base, 12), u, i, r, w, row_of_u, row_of_i)
+        # the bucket phase's one read-back; it also ends this seam where
+        # the device ends the phase
+        sizes_host = np.asarray(sizes)
+        nnz = n if weights is None else int(np.asarray(
+            _count_real(counts_u)))
+    with seam("fit/blocking/layout"):
+        bmax = max(int(sizes_host.max()), 1)
+        mbm = max(minibatch_multiple, 1)
+        bmax = -(-bmax // mbm) * mbm
+        ru, ri, rv, rw, icu, icv = _mesh_layout(
+            part, bmax, mbm, minibatch_sort, rpb_u, rpb_v)(
+            urow_s, irow_s, vals_s, w_s, sizes)
+    exchange_bytes = (k - 1) * c * _EXCHANGED * 4
+    _publish_exchange(exchange_bytes, sizes_host.sum(axis=1))
+    return MeshBlockedProblem(
+        ru=ru, ri=ri, rv=rv, rw=rw, icu=icu, icv=icv,
+        omega_u=omega_u, omega_v=omega_v,
+        row_of_user=row_of_u, row_of_item=row_of_i,
+        id_of_user_row=id_of_ur, id_of_item_row=id_of_ir,
+        num_blocks=k, rows_per_block_u=rpb_u, rows_per_block_v=rpb_v,
+        nnz=nnz, max_pad_ratio=(k * k * bmax) / max(nnz, 1),
+        minibatch=mbm, exchange_bytes=exchange_bytes,
+    )
+
+
+@jax.jit
+def _count_real(counts):
+    return jnp.sum(counts)
+
+
+def _publish_exchange(sent_bytes: int, held) -> None:
+    """The exchange on the live registry (``obs.enable()``; nothing
+    otherwise): ``blocking_exchange_bytes_total{chip}``, what each chip
+    sent the others, and ``blocking_shard_entries{chip}``, the entries
+    (weight-0 ones among them) each holds after it: the balance of the
+    user blocks."""
+    from large_scale_recommendation_tpu.obs.registry import get_registry
+
+    obs = get_registry()
+    if not obs.enabled:
+        return
+    for chip, entries in enumerate(held):
+        obs.counter("blocking_exchange_bytes_total",
+                    chip=str(chip)).inc(sent_bytes)
+        obs.gauge("blocking_shard_entries", chip=str(chip)).set(int(entries))
 
 
 def recompute_inv_counts(problem: DeviceBlockedProblem, minibatch: int):

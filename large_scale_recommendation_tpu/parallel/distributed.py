@@ -178,14 +178,15 @@ def global_device_blocked(
 ) -> GlobalBlockedArrays:
     """DSGD blocking computed GLOBALLY on a (possibly multi-process) mesh.
 
-    The multi-host form of the on-device pipeline
-    (``data.device_blocking``): each process contributes only ITS shard of
-    the ratings; the global entry array is assembled shard-wise
-    (``jax.make_array_from_process_local_data``) and the whole blocking —
-    weighted counts, balanced row assignment, bucket sort, stratum scatter,
-    collision scales, factor init — runs as jitted global computations with
-    explicit output shardings. XLA inserts the cross-process collectives
-    the engines' blocking shuffles became (SURVEY §2.3); no host ever
+    The multi-host spelling of the ring's blocking
+    (``data.device_blocking.mesh_block_problem``, what ``MeshDSGD
+    .fit_device`` runs): each process contributes only ITS shard of the
+    ratings, the global entry array is assembled shard-wise
+    (``jax.make_array_from_process_local_data``) and the same per-chip
+    programs run over the process-spanning mesh: counts summed over the
+    ring, the replicated row assignment, one ``all_to_all`` to the chip
+    of each entry's user block, each chip's own row of the layout, then
+    the factor init and omegas in the ring's shardings. No host ever
     materializes another host's shard OR the global layout.
 
     Contract: every process passes equal-length arrays (pad with
@@ -193,96 +194,33 @@ def global_device_blocked(
     single-process pipeline), length divisible by the process's local
     device count. Ids are dense, as in ``device_block_problem``.
     """
-    import jax
-    import jax.numpy as jnp
-
     from large_scale_recommendation_tpu.data import device_blocking as db
+    from large_scale_recommendation_tpu.parallel.dsgd_mesh import (
+        sharded_init,
+    )
     from large_scale_recommendation_tpu.parallel.partitioner import (
         as_partitioner,
     )
 
     part = as_partitioner(mesh)
-    mesh = part.mesh
-    k = part.num_blocks
-    shard = part.sharding("ratings")
-    rep = part.replicated()
-    dm3 = part.sharding("ratings")  # [k, k, b] device-major: dim 0 only
 
     def glob(a, dt):
         return part.from_process_local(np.asarray(a, dt), "ratings")
 
-    gu = glob(u_local, np.int32)
-    gi = glob(i_local, np.int32)
-    gr = glob(r_local, np.float32)
-    gw = glob(w_local, np.float32)
-
-    rpb_u = db.rows_per_block(num_users, k, row_multiple)
-    rpb_v = db.rows_per_block(num_items, k, row_multiple)
-    base = jax.random.PRNGKey(seed)
-
-    def phase_a(u, i, r, w):
-        counts_u, counts_v = db._weighted_counts(u, i, w, num_users,
-                                                 num_items)
-        row_of_u, omega_u, id_of_ur = db._assign_rows(
-            jax.random.fold_in(base, 10), counts_u, k, rpb_u, k * rpb_u)
-        row_of_i, omega_v, id_of_ir = db._assign_rows(
-            jax.random.fold_in(base, 11), counts_v, k, rpb_v, k * rpb_v)
-        sorted_ = db._bucket_entries(
-            jax.random.fold_in(base, 12), u, i, r, w, row_of_u, row_of_i,
-            k, rpb_u, rpb_v)
-        return sorted_[0], sorted_[1:], (
-            row_of_u, row_of_i, omega_u, omega_v, id_of_ur, id_of_ir)
-
-    pa = jax.jit(phase_a,
-                 out_shardings=(rep, (shard,) * 5, (rep,) * 6))
-    sizes, sorted_entries, maps = pa(gu, gi, gr, gw)
-    row_of_u, row_of_i, omega_u, omega_v, id_of_ur, id_of_ir = maps
-
-    sizes_host = np.asarray(sizes)  # replicated → legal on every process
-    bmax = max(int(sizes_host.max()), 1)
-    mbm = max(minibatch_multiple, 1)
-    bmax = -(-bmax // mbm) * mbm
-
-    def phase_b(flat_s, urow_s, irow_s, vals_s, w_s, sizes):
-        su, si, sv, sw, icu, icv = db._layout(
-            flat_s, urow_s, irow_s, vals_s, w_s, sizes, k, bmax, mbm, None)
-        # stratum-major [s, p, b] global rows → device-major [p, s, b]
-        # local rows (≙ dsgd_mesh.device_major_local_strata, on mesh)
-        ru = jnp.transpose(su, (1, 0, 2)) % rpb_u
-        ri = jnp.transpose(si, (1, 0, 2)) % rpb_v
-        rv = jnp.transpose(sv, (1, 0, 2))
-        rw = jnp.transpose(sw, (1, 0, 2))
-        icu = jnp.transpose(icu, (1, 0, 2))
-        icv = jnp.transpose(icv, (1, 0, 2))
-        return ru, ri, rv, rw, icu, icv
-
-    pb = jax.jit(phase_b, out_shardings=(dm3,) * 6)
-    ru, ri, rv, rw, icu, icv = pb(*sorted_entries, sizes)
-
-    from large_scale_recommendation_tpu.core.initializers import (
-        _keyed_uniform_rows_padded,
-    )
-
-    def init_fn(id_u, id_v):
-        key = jax.random.PRNGKey(0)
-        s = jnp.float32(init_scale)
-        return (_keyed_uniform_rows_padded(key, id_u, rank, s),
-                _keyed_uniform_rows_padded(key, id_v, rank, s))
-
-    U, V = jax.jit(init_fn, out_shardings=(
-        part.sharding("users", "rank"), part.sharding("items", "rank"),
-    ))(id_of_ur, id_of_ir)
-    ou, ov = jax.jit(lambda a, b: (a, b), out_shardings=(
-        part.sharding("users"), part.sharding("items"),
-    ))(omega_u, omega_v)
-
+    p = db.mesh_block_problem(
+        glob(u_local, np.int32), glob(i_local, np.int32),
+        glob(r_local, np.float32), num_users, num_items, part,
+        minibatch_multiple=minibatch_multiple, seed=seed,
+        row_multiple=row_multiple, weights=glob(w_local, np.float32))
+    U, V, ou, ov = sharded_init(part, p.id_of_user_row, p.id_of_item_row,
+                                p.omega_u, p.omega_v, rank, init_scale)
     return GlobalBlockedArrays(
-        U=U, V=V, ru=ru, ri=ri, rv=rv, rw=rw, icu=icu, icv=icv,
+        U=U, V=V, ru=p.ru, ri=p.ri, rv=p.rv, rw=p.rw, icu=p.icu, icv=p.icv,
         omega_u=ou, omega_v=ov,
-        row_of_user=np.asarray(row_of_u).astype(np.int64),
-        row_of_item=np.asarray(row_of_i).astype(np.int64),
-        omega_u_host=np.asarray(omega_u),
-        omega_v_host=np.asarray(omega_v),
-        num_blocks=k, rows_per_block_u=rpb_u, rows_per_block_v=rpb_v,
-        minibatch=mbm,
+        row_of_user=np.asarray(p.row_of_user).astype(np.int64),
+        row_of_item=np.asarray(p.row_of_item).astype(np.int64),
+        omega_u_host=np.asarray(p.omega_u),
+        omega_v_host=np.asarray(p.omega_v),
+        num_blocks=p.num_blocks, rows_per_block_u=p.rows_per_block_u,
+        rows_per_block_v=p.rows_per_block_v, minibatch=p.minibatch,
     )

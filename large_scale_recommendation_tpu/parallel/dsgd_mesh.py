@@ -246,6 +246,32 @@ def _build_mesh_dsgd_step(
     return jax.jit(run)
 
 
+def sharded_init(part: Partitioner, id_of_user_row, id_of_item_row,
+                 omega_u, omega_v, rank: int, scale: float):
+    """The device pipeline's factor init (``init_factors_device``: row =
+    ``scale * uniform(fold_in(PRNGKey(0), id))``) and the omegas, from the
+    replicated row maps straight into the ring's shardings: each chip
+    draws only its own blocks' rows."""
+    return _sharded_init(part, rank)(id_of_user_row, id_of_item_row,
+                                     omega_u, omega_v, jnp.float32(scale))
+
+
+@functools.lru_cache(maxsize=16)
+def _sharded_init(part: Partitioner, rank: int):
+    from large_scale_recommendation_tpu.core.initializers import (
+        _keyed_uniform_rows_padded,
+    )
+
+    def init(id_u, id_v, ou, ov, s):
+        key = jax.random.PRNGKey(0)
+        return (_keyed_uniform_rows_padded(key, id_u, rank, s),
+                _keyed_uniform_rows_padded(key, id_v, rank, s), ou, ov)
+
+    return jax.jit(init, out_shardings=(
+        part.sharding("users", "rank"), part.sharding("items", "rank"),
+        part.sharding("users"), part.sharding("items")))
+
+
 @dataclasses.dataclass(frozen=True)
 class MeshDSGDConfig:
     """Mesh variant of DSGDConfig; ``num_blocks`` is the mesh size."""
@@ -375,50 +401,45 @@ class MeshDSGD:
     ) -> MFModel:
         """Train on the mesh via the on-device data pipeline.
 
-        Dense-id COO in (host or device arrays); blocking, the device-major
-        local re-layout, collision scales and factor init all run on chip
-        (``data.device_blocking`` + two transposes and a mod — blocks are
-        contiguous row ranges, so global→local is a subtraction). The host
-        never materializes the strata; the sharded arrays are produced by
-        ``device_put``-resharding the on-chip layout across the mesh.
+        Dense-id COO in (host arrays, arrays on one device, or arrays
+        already sharded by the partitioner's ``ratings`` rule). Blocking
+        runs as programs over the mesh
+        (``data.device_blocking.mesh_block_problem``): each chip counts,
+        places and sorts only its share of the entries, one ``all_to_all``
+        hands every entry to the chip that owns its user block, and each
+        chip lays out its own row of the device-major layout with local
+        rows, bit for bit what ``device_block_problem`` and the transposes
+        to device-major give. No chip ever holds the whole layout, and
+        nothing is re-placed afterwards; the factor init and the omegas
+        come out sharded.
 
-        Single-process meshes (one host's devices, or the virtual CPU
-        mesh). For multi-host runs use
-        ``parallel.distributed.global_device_blocked`` over a
-        ``Partitioner.create()`` global mesh — the same pipeline computed
-        globally on the process-spanning mesh, each host contributing
-        only its shard (examples/distributed_demo.py).
+        The same programs span processes when the partitioner's mesh does
+        (``parallel.distributed.global_device_blocked``: each host passes
+        only its own entries; examples/distributed_demo.py).
         """
         from large_scale_recommendation_tpu.data.device_blocking import (
-            device_block_problem,
-            init_factors_device,
+            mesh_block_problem,
         )
 
         cfg = self.config
-        k = self.num_blocks
-        p = device_block_problem(
-            u, i, r, num_users, num_items, num_blocks=k,
+        part = self.partitioner
+        p = mesh_block_problem(
+            u, i, r, num_users, num_items, part,
             minibatch_multiple=cfg.minibatch_size,
             seed=cfg.seed if cfg.seed is not None else 0,
             minibatch_sort=cfg.minibatch_sort,
         )
         with get_tracer().seam("fit/mesh_dsgd/init"):
-            # stratum-major [s, p, b] global rows → device-major
-            # [p, s, b] local rows (≙ device_major_local_strata, on
-            # device)
-            ru = (jnp.transpose(p.su, (1, 0, 2)) % p.rows_per_block_u)
-            ri = (jnp.transpose(p.si, (1, 0, 2)) % p.rows_per_block_v)
-            rv = jnp.transpose(p.sv, (1, 0, 2))
-            rw = jnp.transpose(p.sw, (1, 0, 2))
-            U, V = init_factors_device(p, cfg.num_factors,
-                                       scale=cfg.init_scale)
+            U, V, ou, ov = sharded_init(part, p.id_of_user_row,
+                                        p.id_of_item_row, p.omega_u,
+                                        p.omega_v, cfg.num_factors,
+                                        cfg.init_scale)
             if cfg.precompute_collisions and cfg.collision_mode == "mean":
-                inv_args = (jnp.transpose(p.icu, (1, 0, 2)),
-                            jnp.transpose(p.icv, (1, 0, 2)))
+                inv_args = (p.icu, p.icv)
             else:
                 inv_args = ()
         U, V = self._train_segments(
-            U, V, (ru, ri, rv, rw), p.omega_u, p.omega_v, inv_args,
+            U, V, (p.ru, p.ri, p.rv, p.rw), ou, ov, inv_args,
             "mesh_dsgd_device_segment",
             checkpoint_manager, checkpoint_every, resume,
             n_ratings=int(np.shape(u)[0]),

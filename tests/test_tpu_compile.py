@@ -19,6 +19,8 @@ import os
 import re
 from functools import partial
 
+import numpy as np
+
 import pytest
 
 import jax
@@ -29,15 +31,19 @@ from large_scale_recommendation_tpu.serving import retrieval
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -181,3 +187,57 @@ def test_solve_bucket_solves_in_the_lanes_kernel(one_chip, shared_gram):
     gather, gram = rc * pad * k * 4, rc * k * k * 4
     assert (compiled.memory_analysis().temp_size_in_bytes
             < gather + gram + (1 << 20))
+
+
+def test_ring_blocking_holds_no_array_of_every_entry_but_the_shuffle(topo):
+    """The ring's blocking (``mesh_block_problem``'s per-chip programs)
+    compiled for the four chips of a ``v5e:2x2`` host, at an entry count
+    no other size shares (40,003: a chip's share 10,001, the zero-filled
+    whole 40,004): no instruction holds an array of every entry except the
+    seeded shuffle's, under ``bucket/permutation``, computed whole on each
+    chip (its sorts are global), and what enters it (a parameter, an
+    iota); the counts and the layout hold none at all, and the exchange is
+    one ``all-to-all``."""
+    from large_scale_recommendation_tpu.data import device_blocking as db
+    from large_scale_recommendation_tpu.parallel import Partitioner
+
+    n, nu, ni, k, mb = 40003, 300, 200, 4, 256
+    part = Partitioner(devices=list(topo.devices)[:k])
+    q = -(-n // k)
+    rpb_u, rpb_v = db.rows_per_block(nu, k), db.rows_per_block(ni, k)
+    c = db.exchange_slots(np.zeros((k, k), int), q, k)
+    shard, rep = part.sharding("ratings"), part.replicated()
+    i32, f32 = jnp.int32, jnp.float32
+    entries = partial(jax.ShapeDtypeStruct, (k * q,), sharding=shard)
+    key = jax.random.PRNGKey(0)
+    hlo = {
+        "counts": db._mesh_counts(part, n, q, nu, ni).lower(
+            entries(i32), entries(i32), entries(f32)),
+        "bucket": db._mesh_bucket(part, n, q, c, rpb_u, rpb_v).lower(
+            jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep),
+            entries(i32), entries(i32), entries(f32), entries(f32),
+            jax.ShapeDtypeStruct((nu,), i32, sharding=rep),
+            jax.ShapeDtypeStruct((ni,), i32, sharding=rep)),
+        "layout": db._mesh_layout(part, 4 * mb, mb, "item", rpb_u,
+                                  rpb_v).lower(
+            *(jax.ShapeDtypeStruct((k * k * c,), dt, sharding=shard)
+              for dt in (i32, i32, f32, f32)),
+            jax.ShapeDtypeStruct((k, k), i32, sharding=rep)),
+    }
+    for name, lowered in hlo.items():
+        text = lowered.compile().as_text()
+        whole = []
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) (\S+)\(", line)
+            if not m:
+                continue
+            dims = {int(d) for shape in re.findall(r"\[([\d,]+)\]",
+                                                   m[1])
+                    for d in shape.split(",") if d}
+            if dims & {n, k * q} and m[2] not in ("parameter", "iota"):
+                whole.append(line)
+        if name == "bucket":
+            assert whole and all("bucket/permutation" in w for w in whole)
+            assert len(re.findall(r" all-to-all(?:-start)?\(", text)) == 1
+        else:
+            assert not whole, whole[:3]
