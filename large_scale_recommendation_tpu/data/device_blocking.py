@@ -64,6 +64,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.extend.random import threefry2x32_p
 
 from large_scale_recommendation_tpu.obs.trace import get_tracer
 
@@ -748,14 +749,144 @@ def _mesh_send_counts(part, rpb_u: int):
     return _sharded(part, _send_counts, (shard, shard, rep), rep)
 
 
+def _slots(words, first, count, c: int, fill):
+    """``[k, len(words), c]``: for each chip ``d``, the ``count[d]``
+    columns of ``words`` from ``first[d]`` on, then ``fill`` up to ``c``
+    slots; what one ``all_to_all`` hands the chips."""
+    stack = jnp.pad(words, ((0, 0), (0, c)))
+    slot = jnp.arange(c, dtype=jnp.int32)
+
+    def send(d):
+        win = jax.lax.dynamic_slice(stack, (0, first[d]), (words.shape[0], c))
+        return jnp.where(slot < count[d], win, fill)
+
+    return jax.lax.map(send, jnp.arange(first.shape[0], dtype=jnp.int32))
+
+
+def shuffle_rounds(n: int) -> int:
+    """The stable sorts by fresh 32-bit keys that ``jax.random.permutation``
+    runs over ``n`` entries: ``3 ln n / ln(2^32 - 1)``, rounded up (JAX's
+    ``random._shuffle``)."""
+    return int(np.ceil(3 * np.log(max(1, n))
+                       / np.log(np.iinfo(np.uint32).max)))
+
+
+def even_slots(q: int, k: int) -> int:
+    """Slots for an even share of ``q`` entries over ``k`` chips: a fixed
+    1% over ``q / k``, in 512s, so that one shape serves every draw of the
+    same size and compiles once. A shuffle round's count from a chip to a
+    chip is binomial about ``q / k``: at 95.5M entries on four chips the
+    1% is 28 standard deviations."""
+    even = -(-int(q * 1.01) // k)
+    return -(-even // 512) * 512
+
+
+def _shuffle_places(key, n: int, q: int, s: int, axis: str, k: int):
+    """Each chip's ``q`` entries' places in ``jax.random.permutation(key,
+    n)``, bit for bit, with no array of every entry on any chip; and the
+    largest count a chip sent a chip (the rounds' slots held every entry
+    if it is at most ``s``).
+
+    ``permutation`` is ``shuffle_rounds(n)`` stable sorts of the entries
+    by fresh 32-bit keys, the key of place ``t`` drawn from ``t`` alone.
+    In each round a chip holds a run of consecutive places, draws their
+    keys, and sends each entry to the chip of its key's range (chip ``d``
+    takes ``[d w, (d + 1) w)``, ``w = ceil(2^32 / k)``) in ``s`` slots a
+    chip through one ``all_to_all``, in order of place; what a chip
+    receives, in chip order, sorted stably by key is its run of the
+    round's sorted order. Then every entry's place goes to the chip that
+    holds the entry (entry ``e`` on chip ``e // q``) through one more
+    ``all_to_all``, which sorts what it receives by entry.
+
+    A sender's sort is by one word, its chip above its place, which is
+    unique: no stable sort's index and no key ride along, the keys are
+    drawn again in the sorted order (round 1 sorts that word alone)."""
+    p = jax.lax.axis_index(axis)
+    u32 = jnp.uint32
+    width = -(-(1 << 32) // k)
+    shift = 32 - k.bit_length()  # a place's bits under its chip (k: none)
+    ar_k = jnp.arange(k, dtype=jnp.int32)
+
+    def draw(sub, places):
+        b1, b2 = threefry2x32_p.bind(sub[0], sub[1], jnp.zeros_like(places),
+                                     places)
+        return b1 ^ b2
+
+    def chip_counts(dest):
+        # what this chip sends each chip; all chips' (rows: the sender)
+        mine = jnp.sum(dest[None, :] == ar_k[:, None], axis=1,
+                       dtype=jnp.int32)
+        return mine, _gather_rows(mine, axis, k)
+
+    def exchange(words, mine, fill):
+        out = _slots(words, jnp.cumsum(mine) - mine, mine, s, fill)
+        got = jax.lax.all_to_all(out, axis, 0, 0, tiled=True)
+        return jnp.transpose(got, (1, 0, 2)).reshape(words.shape[0], k * s)
+
+    # round 1 shuffles arange(n): chip p holds places [p q, p q + q), and
+    # each entry is its place (vals None)
+    held = jnp.clip(n - p * q, 0, q)
+    start = (p * q).astype(u32)
+    size, vals = q, None
+    need = jnp.int32(0)
+    for _ in range(shuffle_rounds(n)):
+        if size > 1 << shift:
+            raise ValueError(f"_shuffle_places: {size} places a chip leave "
+                             f"no room for {k} chips in 32 bits")
+        key, sub = jax.random.split(key)
+        # the key is the same on every chip, the places are not
+        sub = jax.lax.pcast(sub, axis, to="varying")
+        j = jnp.arange(size, dtype=u32)
+        dest = ((draw(sub, start + j) // u32(width)).astype(jnp.int32)
+                if k > 1 else jnp.zeros(size, jnp.int32))
+        dest = jnp.where(j < held.astype(u32), dest, k)
+        mine, counts = chip_counts(dest)
+        need = jnp.maximum(need, counts.max())
+        order = (dest.astype(u32) << shift) | j
+        if vals is None:
+            order = jax.lax.sort(order, is_stable=False)
+        else:
+            order, vals = jax.lax.sort((order, vals), num_keys=1,
+                                       is_stable=False)
+        j = order & u32((1 << shift) - 1)
+        vals = start + j if vals is None else vals
+        # the key less its range's start (below w): an empty slot sorts
+        # last; stable, so equal keys stay in order of place
+        low = draw(sub, start + j)
+        low = low % u32(width) if k > 1 else low
+        got = exchange(jnp.stack([low, vals]), mine,
+                       jnp.array([[0xFFFFFFFF], [0]], u32))
+        _, vals = jax.lax.sort(tuple(got), num_keys=1, is_stable=True)
+        holds = counts.sum(axis=0)
+        held = holds[p]
+        start = (jnp.cumsum(holds)[p] - held).astype(u32)
+        size = k * s
+    # the inversion: entry e's place goes to the chip that holds e
+    j = jnp.arange(size, dtype=u32)
+    ent = start + j if vals is None else vals
+    ent = jnp.where(j < held.astype(u32), ent, u32(k * q))
+    mine, counts = chip_counts((ent // u32(q)).astype(jnp.int32))
+    need = jnp.maximum(need, counts.max())
+    ent, place = jax.lax.sort((ent, start + j), num_keys=1, is_stable=False)
+    got = exchange(jnp.stack([ent, place]), mine,
+                   jnp.array([[k * q], [0]], u32))
+    _, place = jax.lax.sort(tuple(got), num_keys=1, is_stable=False)
+    place = jnp.pad(place, (0, max(q - k * s, 0)))[:q]
+    return place.astype(jnp.int32), need
+
+
 @functools.lru_cache(maxsize=16)
-def _mesh_bucket(part, n: int, q: int, c: int, rpb_u: int, rpb_v: int):
-    """Per chip: its entries' buckets, their places in the seeded shuffle,
-    one ``all_to_all`` that hands every entry to the chip of its user
-    block (``c`` slots from each chip to each), and a sort of what it
-    received by (bucket, place). Buckets and places are unique together,
-    so each chip's buckets come out in the order ``_bucket_entries``
-    gives them on one chip."""
+def _mesh_bucket(part, n: int, q: int, c: int, s: int, rpb_u: int,
+                 rpb_v: int):
+    """Per chip: its entries' buckets, their places in the seeded shuffle
+    (``_shuffle_places``: the chips sort it in shares, ``s`` slots a pair
+    of chips a round), one ``all_to_all`` that hands every entry to the
+    chip of its user block (``c`` slots from each chip to each), and a
+    sort of what it received by (bucket, place). Buckets and places are
+    unique together, so each chip's buckets come out in the order
+    ``_bucket_entries`` gives them on one chip. Beside the sizes, the
+    largest count a chip sent a chip in the shuffle: above ``s``, the
+    shuffle lost entries."""
     axis, k = part.data_axis, part.num_blocks
     shard, rep = part.spec("ratings"), part.spec()
     # an empty slot sorts after every bucket and is never laid out
@@ -778,29 +909,15 @@ def _mesh_bucket(part, n: int, q: int, c: int, rpb_u: int, rpb_v: int):
             # the zero-filled tail past n is no entry: it goes nowhere
             dest = jnp.where(gidx < n, flat % k, k)
         with jax.named_scope("bucket/permutation"):
-            # the whole shuffle on every chip (its sorts are global), then
-            # the places of this chip's own entries
-            perm = jax.random.permutation(key, n)
-            _, rank = jax.lax.sort_key_val(
-                perm, jnp.arange(n, dtype=jnp.int32))
-            rank = jax.lax.dynamic_slice(
-                jnp.pad(rank, (0, k * q - n)), (p * q,), (q,))
+            rank, need = _shuffle_places(key, n, q, s, axis, k)
         with jax.named_scope("bucket/send"):
             dest_s, *words = jax.lax.sort(
                 (dest, flat, rank, urow, irow, as_word(r), as_word(w)),
                 num_keys=1, is_stable=False)
             ar = jnp.arange(k + 1, dtype=jnp.int32)
             bounds = jnp.searchsorted(dest_s, ar)
-            first, count = bounds[:-1], jnp.diff(bounds)
-            stack = jnp.pad(jnp.stack(words), ((0, 0), (0, c)))
-            slot = jnp.arange(c, dtype=jnp.int32)
-
-            def send(d):
-                win = jax.lax.dynamic_slice(stack, (0, first[d]),
-                                            (_EXCHANGED, c))
-                return jnp.where(slot < count[d], win, fill)
-
-            out = jax.lax.map(send, ar[:k])  # [k, words, c]
+            out = _slots(jnp.stack(words), bounds[:-1], jnp.diff(bounds), c,
+                         fill)  # [k, words, c]
         with jax.named_scope("bucket/exchange"):
             got = jax.lax.all_to_all(out, axis, 0, 0, tiled=True)
         with jax.named_scope("bucket/sort"):
@@ -811,12 +928,12 @@ def _mesh_bucket(part, n: int, q: int, c: int, rpb_u: int, rpb_v: int):
             mine = ar[:k] * k + p  # buckets (s, p), s = 0..k-1
             sizes = (jnp.searchsorted(flat_s, mine + 1)
                      - jnp.searchsorted(flat_s, mine)).astype(jnp.int32)
-        return (_gather_rows(sizes, axis, k), urow_s, irow_s,
+        return (_gather_rows(sizes, axis, k), need, urow_s, irow_s,
                 as_float(v_s), as_float(w_s))
 
     return _sharded(part, _bucket_entries,
                     (rep,) + (shard,) * 4 + (rep, rep),
-                    (rep,) + (shard,) * 4)
+                    (rep, rep) + (shard,) * 4)
 
 
 @functools.lru_cache(maxsize=16)
@@ -838,13 +955,10 @@ def _mesh_layout(part, bmax: int, mb: int, sort_side: str | None,
 
 
 def exchange_slots(send: np.ndarray, q: int, k: int) -> int:
-    """Slots each chip sends each chip: a fixed 1% over an even share of
-    ``q`` (so that one shape serves every draw of the same size, and the
-    exchange compiles once), or the largest count where a pair exceeds
-    that (skewed or pre-partitioned input)."""
-    even = -(-int(q * 1.01) // k)
-    even = -(-even // 512) * 512
-    return max(int(send.max()), even, 1)
+    """Slots each chip sends each chip in the exchange: ``even_slots``,
+    or the largest count where a pair exceeds that (skewed or
+    pre-partitioned input)."""
+    return max(int(send.max()), even_slots(q, k))
 
 
 def mesh_block_problem(
@@ -859,6 +973,7 @@ def mesh_block_problem(
     row_multiple: int = 8,
     minibatch_sort: str | None = None,
     weights=None,
+    _shuffle_slots: int | None = None,
 ) -> MeshBlockedProblem:
     """``device_block_problem`` for the stratum ring, as programs over the
     partitioner's mesh, with the same result bit for bit: no chip ever
@@ -877,8 +992,13 @@ def mesh_block_problem(
     two ``[k, k]`` count matrices (the exchange's slot count and ``bmax``
     are static shapes) and nothing else.
 
-    The shuffle is computed whole on every chip (its sorts are global):
-    ``4 n`` bytes a chip for the places, and the sort's temporaries.
+    The chips sort the shuffle in shares (``_shuffle_places``: a round of
+    key-range routing by ``all_to_all`` for each of ``permutation``'s
+    sorts, and one more that hands each chip its entries' places), in
+    ``even_slots`` slots a pair of chips a round. Where a round's count
+    outgrows them the bucket program runs again with room for it
+    (``_shuffle_slots`` sets the first slots, for tests), counted by
+    ``blocking_shuffle_retries_total`` on the live registry.
     """
     if minibatch_sort not in (None, "user", "item"):
         raise ValueError(
@@ -928,12 +1048,23 @@ def mesh_block_problem(
             cu_own, pads, row_of_u))
         c = exchange_slots(send, q, k)
         del cu_own, pads
-        sizes, urow_s, irow_s, vals_s, w_s = _mesh_bucket(
-            part, n, q, c, rpb_u, rpb_v)(
-            jax.random.fold_in(base, 12), u, i, r, w, row_of_u, row_of_i)
-        # the bucket phase's one read-back; it also ends this seam where
-        # the device ends the phase
-        sizes_host = np.asarray(sizes)
+        s = even_slots(q, k) if _shuffle_slots is None else _shuffle_slots
+        retries = 0
+        while True:
+            sizes, need, urow_s, irow_s, vals_s, w_s = _mesh_bucket(
+                part, n, q, c, s, rpb_u, rpb_v)(
+                jax.random.fold_in(base, 12), u, i, r, w, row_of_u,
+                row_of_i)
+            # the bucket phase's one read-back; it also ends this seam
+            # where the device ends the phase
+            sizes_host, need = jax.device_get((sizes, need))
+            if need <= s:
+                break
+            # a round's count outgrew its slots and entries were lost: run
+            # again with room for it, at least twice the slots (a count
+            # read after a loss may be short), never more than n
+            retries += 1
+            s = min(max(int(need), 2 * s), n)
         nnz = n if weights is None else int(np.asarray(
             _count_real(counts_u)))
     with seam("fit/blocking/layout"):
@@ -944,7 +1075,7 @@ def mesh_block_problem(
             part, bmax, mbm, minibatch_sort, rpb_u, rpb_v)(
             urow_s, irow_s, vals_s, w_s, sizes)
     exchange_bytes = (k - 1) * c * _EXCHANGED * 4
-    _publish_exchange(exchange_bytes, sizes_host.sum(axis=1))
+    _publish_exchange(exchange_bytes, sizes_host.sum(axis=1), retries)
     return MeshBlockedProblem(
         ru=ru, ri=ri, rv=rv, rw=rw, icu=icu, icv=icv,
         omega_u=omega_u, omega_v=omega_v,
@@ -961,17 +1092,19 @@ def _count_real(counts):
     return jnp.sum(counts)
 
 
-def _publish_exchange(sent_bytes: int, held) -> None:
+def _publish_exchange(sent_bytes: int, held, retries: int) -> None:
     """The exchange on the live registry (``obs.enable()``; nothing
     otherwise): ``blocking_exchange_bytes_total{chip}``, what each chip
-    sent the others, and ``blocking_shard_entries{chip}``, the entries
+    sent the others, ``blocking_shard_entries{chip}``, the entries
     (weight-0 ones among them) each holds after it: the balance of the
-    user blocks."""
+    user blocks; and ``blocking_shuffle_retries_total``, the bucket
+    program's reruns for a shuffle round that outgrew its slots."""
     from large_scale_recommendation_tpu.obs.registry import get_registry
 
     obs = get_registry()
     if not obs.enabled:
         return
+    obs.counter("blocking_shuffle_retries_total").inc(retries)
     for chip, entries in enumerate(held):
         obs.counter("blocking_exchange_bytes_total",
                     chip=str(chip)).inc(sent_bytes)

@@ -100,6 +100,68 @@ def test_a_share_of_one_hot_user_sends_unevenly_and_still_agrees():
         (k - 1) * even * 6 * 4)
 
 
+# n, k: one, two and three rounds of the shuffle; n that k does not divide
+SHUFFLES = [(1000, 4), (1001, 2), (100003, 4), (100003, 2), (3000001, 4)]
+
+
+@pytest.mark.parametrize("n,k", SHUFFLES)
+def test_sharded_shuffle_equals_permutation_bit_for_bit(n, k):
+    """Each chip's places from ``_shuffle_places``, run as the per-chip
+    program over the mesh, are its share of the inverse of
+    ``jax.random.permutation`` with the blocking's key, and its rounds
+    are the sorts ``permutation`` runs."""
+    part = Partitioner(num_devices=k)
+    q = -(-n // k)
+    s = db.even_slots(q, k)
+    key = jax.random.fold_in(jax.random.PRNGKey(5), 12)
+    jaxpr = str(jax.make_jaxpr(
+        lambda key: jax.random.permutation(key, n))(key))
+    assert jaxpr.count(" sort[") == db.shuffle_rounds(n) == {
+        1000: 1, 1001: 1, 100003: 2, 3000001: 3}[n]
+
+    def places(key):
+        rank, need = db._shuffle_places(key, n, q, s, part.data_axis, k)
+        return rank, need[None]
+
+    rank, need = jax.jit(jax.shard_map(
+        places, mesh=part.mesh, in_specs=part.spec(),
+        out_specs=(part.spec("ratings"),) * 2))(key)
+    assert rank.shape == (k * q,)
+    assert len(set(np.asarray(need))) == 1 and need[0] <= s
+    perm = np.asarray(jax.random.permutation(key, n))
+    inverse = np.empty(n, np.int32)
+    inverse[perm] = np.arange(n, dtype=np.int32)
+    for shard in rank.addressable_shards:
+        lo = shard.index[0].start or 0
+        got = np.asarray(shard.data)
+        assert got.shape == (q,)
+        assert _bits(got[:max(min(q, n - lo), 0)]) == _bits(
+            inverse[lo:lo + q]), lo
+
+
+def test_a_shuffle_round_that_outgrows_its_slots_runs_again():
+    """Slots too small for a round lose entries; the bucket program runs
+    again with twice the slots, the layout is the one-chip layout bit for
+    bit, and the registry counts the rerun."""
+    rng = np.random.default_rng(9)
+    n, nu, ni, k = 5000, 80, 60, 4
+    u, i = rng.integers(0, nu, n), rng.integers(0, ni, n)
+    r = rng.normal(0, 1, n).astype(np.float32)
+    registry, _ = obs.enable()
+    try:
+        # a pair's count is about n / k^2 = 312 in every round
+        m = db.mesh_block_problem(u, i, r, nu, ni, Partitioner(num_devices=k),
+                                  minibatch_multiple=32, seed=4,
+                                  _shuffle_slots=256)
+        got = _blocking_metrics(registry)
+    finally:
+        obs.disable()
+    p, want = _one_chip_device_major(u, i, r, nu, ni, k,
+                                     minibatch_multiple=32, seed=4)
+    _assert_equal(m, p, want)
+    assert got["blocking_shuffle_retries_total"] == {None: 1}
+
+
 def test_entries_already_sharded_stay_where_they_are():
     part = Partitioner(num_devices=4)
     rng = np.random.default_rng(8)
@@ -116,6 +178,17 @@ def test_entries_already_sharded_stay_where_they_are():
     _assert_equal(m, p, want)
 
 
+def _blocking_metrics(registry):
+    """``{name: {chip label (or None): value}}`` of the ``blocking_*``
+    metrics on the registry."""
+    got = {}
+    for metric in registry.snapshot()["metrics"]:
+        if metric["name"].startswith("blocking_"):
+            got.setdefault(metric["name"], {})[
+                metric["labels"].get("chip")] = metric["value"]
+    return got
+
+
 def test_exchange_on_the_registry():
     rng = np.random.default_rng(3)
     n, nu, ni = 5000, 80, 60
@@ -125,14 +198,11 @@ def test_exchange_on_the_registry():
     try:
         m = db.mesh_block_problem(u, i, r, nu, ni, Partitioner(num_devices=4),
                                   minibatch_multiple=32, seed=1)
-        snap = registry.snapshot()
+        got = _blocking_metrics(registry)
     finally:
         obs.disable()
-    got = {}
-    for metric in snap["metrics"]:
-        if metric["name"].startswith("blocking_"):
-            got.setdefault(metric["name"], {})[metric["labels"]["chip"]] = \
-                metric["value"]
+    # the shuffle's rounds fit their even slots: no rerun
+    assert got.pop("blocking_shuffle_retries_total") == {None: 0}
     chips = ["0", "1", "2", "3"]
     # every chip sent (k - 1) pairs of slots of six 4-byte words
     assert got["blocking_exchange_bytes_total"] == {

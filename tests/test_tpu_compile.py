@@ -189,15 +189,15 @@ def test_solve_bucket_solves_in_the_lanes_kernel(one_chip, shared_gram):
             < gather + gram + (1 << 20))
 
 
-def test_ring_blocking_holds_no_array_of_every_entry_but_the_shuffle(topo):
+def test_ring_blocking_holds_no_array_of_every_entry(topo):
     """The ring's blocking (``mesh_block_problem``'s per-chip programs)
     compiled for the four chips of a ``v5e:2x2`` host, at an entry count
     no other size shares (40,003: a chip's share 10,001, the zero-filled
-    whole 40,004): no instruction holds an array of every entry except the
-    seeded shuffle's, under ``bucket/permutation``, computed whole on each
-    chip (its sorts are global), and what enters it (a parameter, an
-    iota); the counts and the layout hold none at all, and the exchange is
-    one ``all-to-all``."""
+    whole 40,004): no instruction but what enters a program (a parameter,
+    an iota) holds an array of every entry, the seeded shuffle's sorts
+    among them (the chips sort it in shares); the exchange under
+    ``bucket/exchange`` is one ``all-to-all``, and the shuffle under
+    ``bucket/permutation`` one a round and one for its inversion."""
     from large_scale_recommendation_tpu.data import device_blocking as db
     from large_scale_recommendation_tpu.parallel import Partitioner
 
@@ -206,6 +206,7 @@ def test_ring_blocking_holds_no_array_of_every_entry_but_the_shuffle(topo):
     q = -(-n // k)
     rpb_u, rpb_v = db.rows_per_block(nu, k), db.rows_per_block(ni, k)
     c = db.exchange_slots(np.zeros((k, k), int), q, k)
+    s = db.even_slots(q, k)
     shard, rep = part.sharding("ratings"), part.replicated()
     i32, f32 = jnp.int32, jnp.float32
     entries = partial(jax.ShapeDtypeStruct, (k * q,), sharding=shard)
@@ -213,7 +214,7 @@ def test_ring_blocking_holds_no_array_of_every_entry_but_the_shuffle(topo):
     hlo = {
         "counts": db._mesh_counts(part, n, q, nu, ni).lower(
             entries(i32), entries(i32), entries(f32)),
-        "bucket": db._mesh_bucket(part, n, q, c, rpb_u, rpb_v).lower(
+        "bucket": db._mesh_bucket(part, n, q, c, s, rpb_u, rpb_v).lower(
             jax.ShapeDtypeStruct(key.shape, key.dtype, sharding=rep),
             entries(i32), entries(i32), entries(f32), entries(f32),
             jax.ShapeDtypeStruct((nu,), i32, sharding=rep),
@@ -236,8 +237,9 @@ def test_ring_blocking_holds_no_array_of_every_entry_but_the_shuffle(topo):
                     for d in shape.split(",") if d}
             if dims & {n, k * q} and m[2] not in ("parameter", "iota"):
                 whole.append(line)
+        assert not whole, whole[:3]
         if name == "bucket":
-            assert whole and all("bucket/permutation" in w for w in whole)
-            assert len(re.findall(r" all-to-all(?:-start)?\(", text)) == 1
-        else:
-            assert not whole, whole[:3]
+            scopes = re.findall(r' all-to-all(?:-start)?\(.*op_name="[^"]*'
+                                r'(bucket/\w+)/', text)
+            assert sorted(scopes) == ["bucket/exchange"] + [
+                "bucket/permutation"] * (db.shuffle_rounds(n) + 1), scopes
