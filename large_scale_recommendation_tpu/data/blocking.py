@@ -233,6 +233,20 @@ def build_id_index(
     return index, row_of_sorted_pos[inv_order0[inverse]]
 
 
+def seen_rows_per_block(omega, num_blocks: int):
+    """int32[k]: the rows of each of the ``k`` equal row blocks that hold
+    an id seen in training (``omega > 0``), as a NumPy or a JAX array
+    like ``omega``.
+
+    Both deals (``build_id_index`` and ``device_blocking._assign_rows``)
+    hand ids out hottest first, round by round, a row a round to each
+    block, and ids never seen count 0 and come last: so block ``b``'s seen
+    rows are its first ones, ``[b·rpb, b·rpb + count_b)``, and the rest
+    (unseen ids, padding) follow (``tests/test_bpr.py`` pins it for both).
+    BPR draws its negatives from that prefix (``ops.sgd.dsgd_train``)."""
+    return (omega > 0).reshape(num_blocks, -1).sum(axis=1, dtype=np.int32)
+
+
 def block_ratings(
     ratings: Ratings | tuple,
     users: IdIndex,

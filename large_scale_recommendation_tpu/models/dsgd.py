@@ -41,6 +41,7 @@ from large_scale_recommendation_tpu.core.updaters import (
 from large_scale_recommendation_tpu.core.types import Ratings
 from large_scale_recommendation_tpu.data import blocking
 from large_scale_recommendation_tpu.models.mf import MFModel
+from large_scale_recommendation_tpu.obs.registry import get_registry
 from large_scale_recommendation_tpu.obs.trace import get_tracer
 from large_scale_recommendation_tpu.obs.transfers import guard_scope
 from large_scale_recommendation_tpu.ops import sgd as sgd_ops
@@ -93,6 +94,20 @@ class DSGDConfig:
     # semantics stay exact. Checkpoints round-trip the dtype
     # (utils.checkpoint bit-view encoding).
     factor_dtype: str = "float32"
+    # the objective: "squared" (the reference's, on the ratings' values)
+    # or "bpr" (Rendle et al., UAI 2009: every entry is a positive and a
+    # negative is drawn for it on the device, from the real rows of the
+    # visited item block; values are ignored; ops.sgd.bpr_minibatch_update)
+    loss: str = "squared"
+
+    def __post_init__(self):
+        if self.loss not in ("squared", "bpr"):
+            raise ValueError(
+                f"unknown loss {self.loss!r}; expected 'squared' or 'bpr'")
+        if self.loss == "bpr" and self.kernel == "pallas":
+            raise ValueError(
+                "kernel='pallas' inlines the squared loss's rule; "
+                "loss='bpr' runs on kernel='xla'")
 
     def schedule_fn(self):
         return schedule_from_name(self.lr_schedule, self.lambda_)
@@ -186,6 +201,7 @@ class DSGD:
             jnp.asarray(problem.users.omega),
             jnp.asarray(problem.items.omega),
             *inv,
+            *self._negatives(problem.items.omega, k),
         )
         U, V = self._train_segments(
             U, V, args, k, "dsgd_segment",
@@ -194,6 +210,18 @@ class DSGD:
         )
         self.model = MFModel(U=U, V=V, users=problem.users, items=problem.items)
         return self.model
+
+    def _negatives(self, omega_v, k: int) -> tuple:
+        """The two arguments ``dsgd_train`` takes after the collision
+        scales: under ``loss="bpr"`` each item block's count of real rows
+        and the negatives' root key (the config's seed folded with 13;
+        blocking folds 10 to 12); under the squared loss none."""
+        cfg = self.config
+        if cfg.loss == "squared":
+            return ()
+        key = jax.random.fold_in(
+            jax.random.PRNGKey(cfg.seed if cfg.seed is not None else 0), 13)
+        return (blocking.seen_rows_per_block(omega_v, k), key)
 
     def _train_segments(self, U, V, args, k, kind, checkpoint_manager,
                         checkpoint_every, resume, n_ratings=None):
@@ -252,6 +280,10 @@ class DSGD:
                     U, V = train(U, V, iterations=seg, t0=done, k=k)
                 h.out = (U, V)
             done += seg
+            if cfg.loss == "bpr" and n_ratings is not None:
+                # one negative a real entry a sweep: the host's count
+                get_registry().counter("dsgd_negatives_total").inc(
+                    n_ratings * seg)
             # the host's time between sweeps
             with seam("fit/dsgd/after_segment"):
                 if self.watchdog is not None:
@@ -282,10 +314,11 @@ class DSGD:
                 n_ratings, int(np.shape(U)[-1]), kernel=cfg.kernel,
                 num_blocks=k, rows_u=int(np.shape(U)[0]),
                 rows_v=int(np.shape(V)[0]),
-                factor_bytes=jnp.dtype(cfg.factor_dtype).itemsize)),
+                factor_bytes=jnp.dtype(cfg.factor_dtype).itemsize,
+                loss=cfg.loss)),
             flops_per_iteration=(
                 None if n_ratings is None else sgd_ops.dsgd_flops_per_sweep(
-                    n_ratings, int(np.shape(U)[-1]))))
+                    n_ratings, int(np.shape(U)[-1]), loss=cfg.loss)))
         return U, V
 
     def _train_fn(self, args):
@@ -305,6 +338,7 @@ class DSGD:
                 iterations=iterations,
                 collision=cfg.collision_mode,
                 t0=t0,
+                loss=cfg.loss,
             )
 
         if cfg.kernel == "xla":
@@ -369,7 +403,9 @@ class DSGD:
         external ids go through ``fit`` (host blocking). Init is always the
         deterministic per-id form (``seed=None`` falls back to seed 0).
 
-        Same checkpoint/segmentation contract as ``fit``.
+        Same checkpoint/segmentation contract as ``fit``. Under
+        ``loss="bpr"`` every entry is a positive and ``r`` is not read by
+        the step; the rest of the path is the squared loss's.
         """
         from large_scale_recommendation_tpu.data.device_blocking import (
             device_block_problem,
@@ -390,7 +426,8 @@ class DSGD:
 
         use_inv = cfg.precompute_collisions and cfg.collision_mode == "mean"
         inv = (p.icu, p.icv) if use_inv else (None, None)
-        args = (p.su, p.si, p.sv, p.sw, p.omega_u, p.omega_v, *inv)
+        args = (p.su, p.si, p.sv, p.sw, p.omega_u, p.omega_v, *inv,
+                *self._negatives(p.omega_v, k))
         U, V = self._train_segments(
             U, V, args, k, "dsgd_device_segment",
             checkpoint_manager, checkpoint_every, resume,

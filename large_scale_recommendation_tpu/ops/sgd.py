@@ -30,7 +30,7 @@ import jax.numpy as jnp
 def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
                          num_blocks: int = 1, rows_u: int = 0,
                          rows_v: int = 0, factor_bytes: int = 4,
-                         model_size: int = 1) -> int:
+                         model_size: int = 1, loss: str = "squared") -> int:
     """Bytes of HBM traffic one full DSGD sweep moves PER DEVICE, per kernel.
 
     The shared roofline model behind every ``effective_hbm_gbs`` number
@@ -40,7 +40,10 @@ def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
     - ``kernel="xla"`` (the gather path): every rating pays ~4 row
       transactions (read+write of a u row and a v row) of
       ``rank × factor_bytes`` plus ~16 B of COO stream. This is the
-      historical bench model (4·rank·4 + 16 at f32).
+      historical bench model (4·rank·4 + 16 at f32). Under
+      ``loss="bpr"`` a rating is a triple and pays ~6 (the negative's
+      row read and written too); its stream (u, i, weight, user scale)
+      is 16 B as well.
     - ``kernel="pallas"`` (the VMEM-staged path): factor traffic is
       CONTIGUOUS — each of the k strata reads+writes every factor row
       once per sweep (k² block visits × rows-per-block), plus the
@@ -61,6 +64,8 @@ def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
         raise ValueError(
             f"model_size {model_size} must be ≥1 and divide rank {rank}")
     if kernel == "pallas":
+        if loss != "squared":
+            raise ValueError("the pallas kernel runs the squared loss only")
         if model_size != 1:
             raise ValueError(
                 "pallas kernel has no rank-sharded traffic model "
@@ -70,7 +75,8 @@ def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
                 "pallas traffic model needs rows_u/rows_v (table heights)")
         factor = num_blocks * (rows_u + rows_v) * rank * factor_bytes * 2
         return int(factor + nnz * 32)
-    return int(nnz * (4 * (rank // model_size) * factor_bytes + 16))
+    rows = 6 if loss == "bpr" else 4
+    return int(nnz * (rows * (rank // model_size) * factor_bytes + 16))
 
 
 def dsgd_collective_bytes_per_sweep(nnz: int, rank: int,
@@ -93,12 +99,14 @@ def dsgd_collective_bytes_per_sweep(nnz: int, rank: int,
     return int(nnz * 4 * 2 * (model_size - 1) / model_size)
 
 
-def dsgd_flops_per_sweep(nnz: int, rank: int) -> int:
+def dsgd_flops_per_sweep(nnz: int, rank: int, loss: str = "squared") -> int:
     """FLOPs one full DSGD sweep computes: ~6·rank per rating visit
     (2·rank for the prediction dot, ~4·rank for the error broadcast and
-    the two factor deltas). The FLOP twin of ``dsgd_bytes_per_sweep`` —
+    the two factor deltas); under ``loss="bpr"`` ~10·rank per triple
+    (the difference ``v_i − v_j``, the dot with ``u``, and the three
+    deltas at 2·rank each). The FLOP twin of ``dsgd_bytes_per_sweep`` —
     the ONE hand model behind the ``/rooflinez`` model column."""
-    return int(nnz * 6 * rank)
+    return int(nnz * (10 if loss == "bpr" else 6) * rank)
 
 
 def _lane_view(omega: jax.Array) -> jax.Array:
@@ -221,6 +229,130 @@ def sgd_minibatch_update(
     return U, V
 
 
+def bpr_minibatch_update(
+    U: jax.Array,
+    V: jax.Array,
+    u_rows: jax.Array,
+    i_rows: jax.Array,
+    weights: jax.Array,
+    n_real: jax.Array,
+    key: jax.Array,
+    updater: Any,
+    t: jax.Array | int,
+    collision: str = "mean",
+    inv_cu: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """One minibatch of BPR (Rendle et al., UAI 2009): every entry
+    ``(u, i)`` is a positive, and a negative ``j`` is drawn for it
+    uniformly from the item rows ``[0, n_real)`` of ``V`` (the visited
+    block's rows seen in training) with ``key``. For a triple with weight
+    ``w``, ``x = u·(v_i − v_j)`` and ``g = σ(−x)``:
+
+        Δu   = w·η(g(v_i − v_j) − λu)
+        Δv_i = w·η(g·u − λv_i)
+        Δv_j = w·η(−g·u − λv_j)
+
+    so a padding entry (``w = 0``) changes nothing. ``η`` is the
+    updater's schedule at ``t``; one ``λ`` (the updater's ``lambda_``)
+    for all three, as the ``implicit`` library's BPR has it, where the
+    paper gives each its own. No ω: the per-occurrence weighting is the
+    squared loss's (``sgd_minibatch_update``).
+
+    ``collision="mean"``: the user side divides by the precomputed
+    ``inv_cu`` (or its runtime count); the item side by the weighted
+    occurrences of a row as positive and as negative in this minibatch,
+    counted here, since the negatives are drawn anew every sweep."""
+    if collision not in ("mean", "sum"):
+        raise ValueError(
+            f"collision must be 'mean' or 'sum', got {collision!r}")
+    with jax.named_scope("sgd/negatives"):
+        j_rows = jax.random.randint(key, u_rows.shape, 0, n_real,
+                                    dtype=jnp.int32)
+    with jax.named_scope("sgd/gather"):
+        u = U[u_rows]
+        vi = V[i_rows]
+        vj = V[j_rows]
+    with jax.named_scope("sgd/update"):
+        lr = updater.schedule(jnp.float32(updater.learning_rate), t)
+        lam = jnp.float32(updater.lambda_)
+        d = vi - vj
+        g = jax.nn.sigmoid(-jnp.einsum("bk,bk->b", u, d))[:, None]
+        lw = (lr * weights)[:, None]
+        du = lw * (g * d - lam * u)
+        dv = jnp.concatenate([lw * (g * u - lam * vi),
+                              lw * (-g * u - lam * vj)])
+        if collision == "mean":
+            if inv_cu is not None:
+                du = du * inv_cu[:, None]
+            else:
+                with jax.named_scope("collision_counts"):
+                    cu = jnp.zeros(U.shape[0], U.dtype).at[u_rows].add(
+                        weights)
+                    du = du / jnp.maximum(cu[u_rows], 1.0)[:, None]
+    v_rows = jnp.concatenate([i_rows, j_rows])
+    if collision == "mean":
+        with jax.named_scope("sgd/negatives"):
+            # counted into whole 128-lane rows and read back a row at a
+            # time (_take_lane), not one 4-byte element an index
+            w2 = jnp.concatenate([weights, weights])
+            cv = jnp.zeros(-(-V.shape[0] // 128) * 128, V.dtype).at[
+                v_rows].add(w2)
+            cv = _take_lane(cv.reshape(-1, 128), v_rows)
+            dv = dv / jnp.maximum(cv, 1.0)[:, None]
+    with jax.named_scope("sgd/scatter_u"):
+        U = U.at[u_rows].add(du)
+    with jax.named_scope("sgd/scatter_v"):
+        V = V.at[v_rows].add(dv)
+    return U, V
+
+
+def _minibatch_scan(step, U: jax.Array, V: jax.Array, minibatch: int,
+                    streams: tuple, indexed: bool = False,
+                    ) -> tuple[jax.Array, jax.Array]:
+    """``lax.scan`` of ``U, V = step(U, V, *chunk)`` over the minibatches
+    of ``streams`` (1-D, of one length divisible by ``minibatch``; a
+    ``None`` stream reaches ``step`` as ``None``). ``indexed`` hands each
+    minibatch's index to ``step`` first."""
+    e = streams[0].shape[0]
+    assert e % minibatch == 0, f"block nnz {e} not divisible by minibatch {minibatch}"
+    n_chunks = e // minibatch
+    index = (jnp.arange(n_chunks, dtype=jnp.int32),) if indexed else ()
+    xs = index + tuple(None if a is None else a.reshape(n_chunks, minibatch)
+                       for a in streams)
+
+    def body(carry, x):
+        return step(*carry, *x), None
+
+    (U, V), _ = jax.lax.scan(body, (U, V), xs)
+    return U, V
+
+
+def bpr_block_sweep(
+    U: jax.Array,
+    V: jax.Array,
+    u_rows: jax.Array,  # int32[e] (e divisible by minibatch)
+    i_rows: jax.Array,
+    weights: jax.Array,
+    n_real: jax.Array,
+    key: jax.Array,
+    updater: Any,
+    t: jax.Array | int,
+    minibatch: int,
+    collision: str = "mean",
+    inv_cu: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """``sgd_block_sweep`` for BPR: minibatch ``m`` of the block draws its
+    negatives with ``fold_in(key, m)``."""
+
+    def step(U, V, m, ur, ir, w, icu):
+        return bpr_minibatch_update(
+            U, V, ur, ir, w, n_real, jax.random.fold_in(key, m), updater, t,
+            collision, icu)
+
+    return _minibatch_scan(step, U, V, minibatch,
+                           (u_rows, i_rows, weights, inv_cu), indexed=True)
+
+
 def sgd_block_sweep(
     U: jax.Array,
     V: jax.Array,
@@ -246,40 +378,26 @@ def sgd_block_sweep(
     (the reference shuffles per visit unless seeded, DSGDforMF.scala:392-393;
     we are deterministic-by-default, the seeded behavior).
     """
-    e = u_rows.shape[0]
-    assert e % minibatch == 0, f"block nnz {e} not divisible by minibatch {minibatch}"
-    n_chunks = e // minibatch
-
-    def chunk(a):
-        return a.reshape(n_chunks, minibatch)
-
-    pre = inv_cu is not None
     # built here, before the scan: a view built in the step is sunk into
     # the loop by XLA, a pad a minibatch (tests/test_tpu_compile.py)
     omega_u = None if omega_u is None else _lane_view(omega_u)
     omega_v = None if omega_v is None else _lane_view(omega_v)
 
-    def body(carry, xs):
-        U, V = carry
-        ur, ir, vals, w = xs[:4]
-        icu, icv = (xs[4], xs[5]) if pre else (None, None)
-        U, V = sgd_minibatch_update(
+    def step(U, V, ur, ir, vals, w, icu, icv):
+        return sgd_minibatch_update(
             U, V, ur, ir, vals, w, omega_u, omega_v, updater, t, collision,
             icu, icv, pred_axis,
         )
-        return (U, V), None
 
-    xs = (chunk(u_rows), chunk(i_rows), chunk(values), chunk(weights))
-    if pre:
-        xs = xs + (chunk(inv_cu), chunk(inv_cv))
-    (U, V), _ = jax.lax.scan(body, (U, V), xs)
-    return U, V
+    return _minibatch_scan(
+        step, U, V, minibatch,
+        (u_rows, i_rows, values, weights, inv_cu, inv_cv))
 
 
 @partial(
     jax.jit,
     static_argnames=("updater", "minibatch", "num_blocks", "iterations",
-                     "collision"),
+                     "collision", "loss"),
 )
 def dsgd_train(
     U: jax.Array,
@@ -292,6 +410,8 @@ def dsgd_train(
     omega_v: jax.Array,
     inv_cu: jax.Array | None = None,  # [k, k, b] precomputed collision
     inv_cv: jax.Array | None = None,  # scales (blocking.minibatch_inv_counts)
+    n_real: jax.Array | None = None,  # int32[k] (loss="bpr")
+    neg_key: jax.Array | None = None,  # the negatives' PRNG key (loss="bpr")
     *,
     updater: Any,
     minibatch: int,
@@ -299,12 +419,26 @@ def dsgd_train(
     iterations: int,
     collision: str = "mean",
     t0: jax.Array | int = 0,
+    loss: str = "squared",
 ) -> tuple[jax.Array, jax.Array]:
     """Full single-device DSGD training loop as ONE jitted computation.
 
     ``t0`` is the number of iterations already completed — segmented runs
     (checkpoint boundaries, utils.checkpoint) pass it so the η/√t schedule
     continues instead of restarting.
+
+    ``loss`` is ``"squared"`` or ``"bpr"`` (``DSGDConfig`` checks the
+    name). ``loss="bpr"`` runs the same blocking, schedule and block
+    visits with the pairwise step of ``bpr_minibatch_update`` in the
+    squared loss's place: ``sv`` and the omegas are not read, ``inv_cv``
+    is not used (the item side counts its negatives as it draws them),
+    and each visit draws its negatives from the real rows of the visited
+    item block, ``[0, n_real[q])`` of block ``q``
+    (``data.blocking.seen_rows_per_block``), so that the blocks of a
+    stratum stay row-disjoint. Minibatch ``m`` of
+    block ``p`` in stratum ``s`` at sweep ``t`` (global, ``t0`` counted)
+    draws with ``neg_key`` folded with ``t``, ``s``, ``p`` and ``m``: a
+    fresh draw each sweep, the same in any segmentation.
 
     ≙ the reference's cluster-wide bulk iteration
     ``union(userBlocks, itemBlocks).iterate(iterations * k)``
@@ -341,6 +475,8 @@ def dsgd_train(
     after every visit would round each row ``k`` times a sweep, another
     arithmetic than this configuration's.
     """
+    if loss == "bpr" and (n_real is None or neg_key is None):
+        raise ValueError("loss='bpr' needs n_real and neg_key")
     store_dtype = U.dtype
     if store_dtype != jnp.float32:
         U = U.astype(jnp.float32)
@@ -373,16 +509,26 @@ def dsgd_train(
         t = v_idx // (k * k) + 1 + jnp.asarray(t0, jnp.int32)
         U_blk = jax.lax.dynamic_slice(U, (p * rpb_u, 0), (rpb_u, rank))
         V_blk = jax.lax.dynamic_slice(V, (q * rpb_v, 0), (rpb_v, rank))
-        ou_blk = jax.lax.dynamic_slice(omega_u, (p * rpb_u,), (rpb_u,))
-        ov_blk = jax.lax.dynamic_slice(omega_v, (q * rpb_v,), (rpb_v,))
-        U_blk, V_blk = sgd_block_sweep(
-            U_blk, V_blk,
-            su_l[s, p], si_l[s, p], sv[s, p], sw[s, p],
-            ou_blk, ov_blk,
-            updater, t, minibatch, collision,
-            None if inv_cu is None else inv_cu[s, p],
-            None if inv_cv is None else inv_cv[s, p],
-        )
+        if loss == "bpr":
+            key = neg_key
+            for x in (t, s, p):
+                key = jax.random.fold_in(key, x)
+            U_blk, V_blk = bpr_block_sweep(
+                U_blk, V_blk, su_l[s, p], si_l[s, p], sw[s, p], n_real[q],
+                key, updater, t, minibatch, collision,
+                None if inv_cu is None else inv_cu[s, p],
+            )
+        else:
+            ou_blk = jax.lax.dynamic_slice(omega_u, (p * rpb_u,), (rpb_u,))
+            ov_blk = jax.lax.dynamic_slice(omega_v, (q * rpb_v,), (rpb_v,))
+            U_blk, V_blk = sgd_block_sweep(
+                U_blk, V_blk,
+                su_l[s, p], si_l[s, p], sv[s, p], sw[s, p],
+                ou_blk, ov_blk,
+                updater, t, minibatch, collision,
+                None if inv_cu is None else inv_cu[s, p],
+                None if inv_cv is None else inv_cv[s, p],
+            )
         U = jax.lax.dynamic_update_slice(U, U_blk, (p * rpb_u, 0))
         V = jax.lax.dynamic_update_slice(V, V_blk, (q * rpb_v, 0))
         return (U, V), None

@@ -391,6 +391,22 @@ def _percentile_kernel():
     return _PERCENTILE_KERNEL
 
 
+# the most one score matrix of expected_percentile_rank may hold
+SCORE_BUDGET_BYTES = 2 << 30
+
+
+def score_chunk(n_items: int, chunk: int = 2048) -> int:
+    """Rows of a ``[rows, n_items]`` float32 score matrix: ``chunk``, or
+    the largest power of two (8 at least) whose matrix stays under
+    ``SCORE_BUDGET_BYTES``: 2048 at 41,140 items (337 MB), 128 at
+    2,262,292 (1.16 GB), where 2048 rows would be 18.5 GB. A chunk reads
+    all of ``V`` and passes it through the matrix unit once, whatever its
+    rows up to the unit's 128: fewer rows than that cost as much a
+    chunk."""
+    fit = SCORE_BUDGET_BYTES // (4 * max(int(n_items), 1))
+    return int(min(chunk, max(8, 1 << max(int(fit).bit_length() - 1, 0))))
+
+
 def expected_percentile_rank(U, V, eval_u, eval_i, weights=None,
                              item_mask=None, chunk: int = 2048) -> float:
     """Expected percentile rank of held-out interactions (Hu, Koren and
@@ -405,7 +421,9 @@ def expected_percentile_rank(U, V, eval_u, eval_i, weights=None,
 
     Row-space ids into the tables, as ``ranking_metrics``; one
     ``[chunk, n_items]`` score matrix at a time, on the device, so the
-    whole catalog is ranked for every pair."""
+    whole catalog is ranked for every pair. ``chunk`` is a most: a tall
+    catalog takes fewer rows (``score_chunk``). The chunks are queued
+    and their ranks read once at the end."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -420,16 +438,14 @@ def expected_percentile_rank(U, V, eval_u, eval_i, weights=None,
                           else np.asarray(item_mask, bool))
     U, V = jnp.asarray(U, jnp.float32), jnp.asarray(V, jnp.float32)
     kern = _percentile_kernel()
-    chunk = min(chunk, n)
-    total = 0.0
-    for c0 in range(0, n, chunk):
-        c = min(chunk, n - c0)
-        pad = chunk - c  # the tail chunk keeps the one compiled shape
-        ranks = kern(U, V, jnp.asarray(np.pad(eval_u[c0:c0 + c], (0, pad))),
-                     jnp.asarray(np.pad(eval_i[c0:c0 + c], (0, pad))),
-                     item_ok)
-        total += float(np.asarray(ranks, np.float64)[:c] @ w[c0:c0 + c])
-    return total / float(w.sum())
+    chunk = min(score_chunk(V.shape[0], chunk), n)
+    pad = -n % chunk  # the tail chunk keeps the one compiled shape
+    eval_u, eval_i = np.pad(eval_u, (0, pad)), np.pad(eval_i, (0, pad))
+    ranks = [kern(U, V, jnp.asarray(eval_u[c0:c0 + chunk]),
+                  jnp.asarray(eval_i[c0:c0 + chunk]), item_ok)
+             for c0 in range(0, n, chunk)]
+    ranks = np.concatenate([np.asarray(x, np.float64) for x in ranks])
+    return float(ranks[:n] @ w) / float(w.sum())
 
 
 _TOPK_KERNEL = None
