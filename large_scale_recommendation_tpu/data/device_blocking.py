@@ -433,6 +433,21 @@ def _carry(flag: jax.Array, vals: tuple, reverse: bool = False) -> tuple:
     return tuple(out)
 
 
+def sorted_run_weights(rows: jax.Array, w: jax.Array) -> jax.Array:
+    """For every position of ``rows`` (ascending along the last axis), the
+    sum of ``w`` over its run of equal rows: a cumsum difference between
+    the run's two ends, which ``_carry`` brings to every member without a
+    gather. Weights that are whole numbers sum exactly (below 2**24)."""
+    diff = rows[..., 1:] != rows[..., :-1]
+    ones = jnp.ones_like(rows[..., :1], bool)
+    new = jnp.concatenate([ones, diff], axis=-1)  # run starts
+    last = jnp.concatenate([diff, ones], axis=-1)  # run ends
+    cumw = jnp.cumsum(w, axis=-1)
+    cumw_start, w_start = _carry(new, (cumw, w))
+    cumw_end, = _carry(last, (cumw,), reverse=True)
+    return cumw_end - cumw_start + w_start
+
+
 def _inv_counts_2d(rows: jax.Array, w: jax.Array,
                    presorted: bool = False) -> jax.Array:
     """Per-entry 1/(weight-sum of its row within its minibatch).
@@ -456,15 +471,7 @@ def _inv_counts_2d(rows: jax.Array, w: jax.Array,
         j = jax.lax.broadcasted_iota(jnp.int32, rows.shape, rows.ndim - 1)
         sr, sw, sj = jax.lax.sort((rows, w, j), dimension=-1, num_keys=1,
                                   is_stable=True)
-    diff = sr[:, 1:] != sr[:, :-1]
-    ones = jnp.ones_like(sr[:, :1], bool)
-    new = jnp.concatenate([ones, diff], axis=-1)  # run starts
-    last = jnp.concatenate([diff, ones], axis=-1)  # run ends
-    cumw = jnp.cumsum(sw, axis=-1)
-    cumw_start, sw_start = _carry(new, (cumw, sw))
-    cumw_end, = _carry(last, (cumw,), reverse=True)
-    W = cumw_end - cumw_start + sw_start
-    inv_sorted = 1.0 / jnp.maximum(W, 1.0)
+    inv_sorted = 1.0 / jnp.maximum(sorted_run_weights(sr, sw), 1.0)
     if presorted:
         return inv_sorted
     # un-sort: the original positions rode along as payload

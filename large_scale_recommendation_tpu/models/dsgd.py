@@ -284,6 +284,10 @@ class DSGD:
                 # one negative a real entry a sweep: the host's count
                 get_registry().counter("dsgd_negatives_total").inc(
                     n_ratings * seg)
+                # its positive and its negative, through the item side's
+                # sorted scatter (ops.sgd.bpr_minibatch_update)
+                get_registry().counter("dsgd_item_rows_sorted_total").inc(
+                    2 * n_ratings * seg)
             # the host's time between sweeps
             with seam("fit/dsgd/after_segment"):
                 if self.watchdog is not None:

@@ -26,6 +26,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from large_scale_recommendation_tpu.data.device_blocking import (
+    sorted_run_weights,
+)
+
 
 def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
                          num_blocks: int = 1, rows_u: int = 0,
@@ -261,7 +265,8 @@ def bpr_minibatch_update(
     ``collision="mean"``: the user side divides by the precomputed
     ``inv_cu`` (or its runtime count); the item side by the weighted
     occurrences of a row as positive and as negative in this minibatch,
-    counted here, since the negatives are drawn anew every sweep."""
+    counted here in row order (``_add_item_side``), since the negatives
+    are drawn anew every sweep."""
     if collision not in ("mean", "sum"):
         raise ValueError(
             f"collision must be 'mean' or 'sum', got {collision!r}")
@@ -289,21 +294,37 @@ def bpr_minibatch_update(
                     cu = jnp.zeros(U.shape[0], U.dtype).at[u_rows].add(
                         weights)
                     du = du / jnp.maximum(cu[u_rows], 1.0)[:, None]
-    v_rows = jnp.concatenate([i_rows, j_rows])
-    if collision == "mean":
-        with jax.named_scope("sgd/negatives"):
-            # counted into whole 128-lane rows and read back a row at a
-            # time (_take_lane), not one 4-byte element an index
-            w2 = jnp.concatenate([weights, weights])
-            cv = jnp.zeros(-(-V.shape[0] // 128) * 128, V.dtype).at[
-                v_rows].add(w2)
-            cv = _take_lane(cv.reshape(-1, 128), v_rows)
-            dv = dv / jnp.maximum(cv, 1.0)[:, None]
     with jax.named_scope("sgd/scatter_u"):
         U = U.at[u_rows].add(du)
+    return U, _add_item_side(V, i_rows, j_rows, weights, dv, collision)
+
+
+def _add_item_side(V: jax.Array, i_rows: jax.Array, j_rows: jax.Array,
+                   weights: jax.Array, dv: jax.Array,
+                   collision: str) -> jax.Array:
+    """``V.at[concat([i_rows, j_rows])].add(dv)`` applied in row order;
+    under ``"mean"`` each delta divided by the weight sum of its row's
+    occurrences in the minibatch, as positive and as negative (at least 1).
+
+    One stable sort of the rows with the weights and the positions gives
+    the counts as the weight sums of the sorted runs and the order in
+    which the deltas are gathered into a scatter told its rows are sorted.
+    Stable, so a row's addends keep the order in which an unsorted scatter
+    adds them. On the TPU a scatter of rows in random order cost four
+    times a sorted one (PERF.md, Findings)."""
+    with jax.named_scope("sgd/negatives"):
+        v_rows = jnp.concatenate([i_rows, j_rows])
+        rows, w, perm = jax.lax.sort(
+            (v_rows, jnp.concatenate([weights, weights]),
+             jax.lax.iota(jnp.int32, v_rows.shape[0])),
+            num_keys=1, is_stable=True)
+        if collision == "mean":
+            counts = sorted_run_weights(rows, w)
     with jax.named_scope("sgd/scatter_v"):
-        V = V.at[v_rows].add(dv)
-    return U, V
+        dv = dv[perm]
+        if collision == "mean":
+            dv = dv / jnp.maximum(counts, 1.0)[:, None]
+        return V.at[rows].add(dv, indices_are_sorted=True)
 
 
 def _minibatch_scan(step, U: jax.Array, V: jax.Array, minibatch: int,

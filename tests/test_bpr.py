@@ -6,6 +6,7 @@ surface, and ``utils.metrics.expected_percentile_rank``'s chunk."""
 import dataclasses
 import os
 import sys
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +84,55 @@ def test_one_triple_at_a_time_is_the_update_written_out(collision):
             Vr[j] += dvj / c
     np.testing.assert_allclose(np.asarray(U), Ur, atol=1e-6)
     np.testing.assert_allclose(np.asarray(V), Vr, atol=1e-6)
+
+
+def _unsorted_item_side(V, i_rows, j_rows, weights, dv, collision):
+    """The BPR step's item side as it stood before it was applied in row
+    order, copied plainly: the weighted count scattered into a zero vector
+    and gathered back, the division, and one scatter of
+    ``concat([i_rows, j_rows])`` in that order."""
+    v_rows = jnp.concatenate([i_rows, j_rows])
+    if collision == "mean":
+        cv = jnp.zeros(V.shape[0], V.dtype).at[v_rows].add(
+            jnp.concatenate([weights, weights]))
+        dv = dv / jnp.maximum(cv[v_rows], 1.0)[:, None]
+    return V.at[v_rows].add(dv)
+
+
+@pytest.mark.parametrize("weights", ["binary", "fractional"])
+@pytest.mark.parametrize("collision", ["mean", "sum"])
+@pytest.mark.parametrize("mb,n_real,h", [(8, 3, 5), (512, 40, 64)])
+def test_the_sorted_item_side_is_the_unsorted_scatter(mb, n_real, h,
+                                                      collision, weights):
+    """``_add_item_side`` against ``_unsorted_item_side`` on the same
+    deltas, with a negative equal to its own positive, one negative drawn
+    twice and weight-0 padding on local row 0: bit for bit at 0/1 weights
+    and under "sum" (the run sums are exact, and a row's addends keep
+    their order). At fractional weights under "mean" a run's sum is a
+    difference of two cumsums, each within a few float32 ulps of the
+    total weight ``S``: a delta moves by at most ``|dv| * 8 * S * eps``."""
+    rng = np.random.default_rng(mb)
+    V = jnp.asarray(rng.normal(0, 0.3, (h, 4)).astype(np.float32))
+    i = rng.integers(0, n_real, mb).astype(np.int32)
+    j = rng.integers(0, n_real, mb).astype(np.int32)
+    j[0], j[2] = i[0], j[1]  # its own positive; one negative twice
+    w = (np.ones(mb, np.float32) if weights == "binary"
+         else rng.uniform(0.1, 2.0, mb).astype(np.float32))
+    i[-2:], w[-2:] = 0, 0.0  # padding: row 0, and a zero delta
+    dv = rng.normal(0, 0.05, (2 * mb, 4)).astype(np.float32)
+    dv[[mb - 2, mb - 1, 2 * mb - 2, 2 * mb - 1]] = 0.0
+    args = [jnp.asarray(a) for a in (V, i, j, w, dv)]
+    want = np.asarray(jax.jit(partial(_unsorted_item_side,
+                                      collision=collision))(*args))
+    got = np.asarray(jax.jit(partial(sgd_ops._add_item_side,
+                                     collision=collision))(*args))
+    if weights == "binary" or collision == "sum":
+        np.testing.assert_array_equal(got, want)
+    else:
+        eps = np.finfo(np.float32).eps
+        atol = np.abs(dv).max() * 8 * 2 * w.sum() * eps
+        np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+    assert not np.array_equal(got, np.asarray(V))
 
 
 def test_a_padding_entry_changes_nothing():
@@ -267,6 +317,8 @@ def test_the_model_serves_and_counts_its_negatives():
     finally:
         obs.disable()
     assert got["dsgd_negatives_total"] == 2 * len(u)
+    # two sweeps, a positive and a negative an entry each
+    assert got["dsgd_item_rows_sorted_total"] == 4 * len(u)
     scores = solver.predict(u[:8], i[:8])
     assert np.isfinite(np.asarray(scores)).all()
     from large_scale_recommendation_tpu.core.types import Ratings
@@ -283,6 +335,7 @@ def test_the_model_serves_and_counts_its_negatives():
     finally:
         obs.disable()
     assert "dsgd_negatives_total" not in names
+    assert "dsgd_item_rows_sorted_total" not in names
 
 
 @pytest.mark.parametrize("loss,rows,flops", [("bpr", 6, 10),

@@ -155,6 +155,45 @@ def test_dsgd_train_reads_the_omegas_a_lane_row_at_a_time(dsgd_fit_hlo):
         minibatch)
 
 
+def test_bpr_applies_its_item_side_in_row_order(one_chip):
+    """``dsgd_train(loss="bpr")`` at reduced widths whose item block
+    (``f32[5000,128]``) is taller than a minibatch's ``2 x 512`` item rows:
+    inside the minibatch loop the scatter into the item block is told its
+    rows are sorted, and no scatter into a 1-D ``f32`` vector as tall as
+    the block (a count scattered row by row) is left. At the Million
+    Playlist widths the unsorted scatter cost 44-46 ns a row on the chip,
+    four times a sorted one (PERF.md, Findings)."""
+    from large_scale_recommendation_tpu.core.updaters import (
+        RegularizedSGDUpdater,
+        constant_lr,
+    )
+    from large_scale_recommendation_tpu.ops.sgd import dsgd_train
+
+    k, mb, rank, nu, nv = 2, 512, 128, 6000, 10000
+    f32, i32 = jnp.float32, jnp.int32
+    sds = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    blocks = (k, k, 2 * mb)
+    key = jax.random.PRNGKey(0)
+    hlo = dsgd_train.lower(
+        sds((nu, rank), f32), sds((nv, rank), f32),
+        sds(blocks, i32), sds(blocks, i32), sds(blocks, f32),
+        sds(blocks, f32), sds((nu,), f32), sds((nv,), f32),
+        sds(blocks, f32), None, sds((k,), i32),
+        sds(key.shape, key.dtype),
+        updater=RegularizedSGDUpdater(learning_rate=0.5, lambda_=0.01,
+                                      schedule=constant_lr),
+        minibatch=mb, num_blocks=k, iterations=1,
+        loss="bpr").compile().as_text()
+    minibatch = _inside_loops(hlo, nested=True)
+    h = nv // k
+    item = [line for line in minibatch.splitlines()
+            if re.search(rf"= f32\[{h},{rank}\]\S* scatter\(", line)]
+    assert item and all("indices_are_sorted=true" in line
+                        for line in item), item
+    assert not re.search(rf"= f32\[({h}|{-(-h // 128) * 128})\]\S* "
+                         r"scatter\(", minibatch)
+
+
 @pytest.mark.parametrize("shared_gram", [False, True],
                          ids=["explicit", "implicit"])
 def test_solve_bucket_solves_in_the_lanes_kernel(one_chip, shared_gram):
