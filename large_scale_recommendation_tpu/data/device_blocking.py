@@ -355,6 +355,59 @@ def _assign_rows(key, counts: jax.Array, k: int, rpb: int, num_rows: int):
     return row_of_id, omega, id_of_row
 
 
+def lane_view(table: jax.Array) -> jax.Array:
+    """A 1-D per-row table as rows of 128 lanes: zero-padded to a whole
+    row and reshaped to ``[ceil(h/128), 128]``, built once before the
+    lookups that read it (``take_lane``)."""
+    return jnp.pad(table, (0, -table.shape[0] % 128)).reshape(-1, 128)
+
+
+def take_lane(view: jax.Array, rows: jax.Array) -> jax.Array:
+    """``table[rows]`` from ``view = lane_view(table)``, rows ``>= 0``:
+    the same bits, read a 128-lane row at a time.
+
+    On the TPU a gather whose slice is one 4-byte element costs about
+    7 ns an index, one whose slice is a 128-lane row about 1.5 ns (PERF.md,
+    Findings, PR 39). So each index gathers its whole row and a select
+    keeps its lane: a sum of one value and 127 zeros is that value. Not a
+    one-hot ``dot``, which the TPU's default precision rounds to bfloat16.
+    """
+    lane = jax.lax.broadcasted_iota(rows.dtype, (1, 128), 1)
+    picked = view[rows >> 7]  # row rows // 128, lane rows % 128
+    return jnp.where(lane == (rows & 127)[:, None], picked, 0).sum(-1)
+
+
+# the ids ``_lookup_rows`` reads at a time: each gathers its whole
+# 128-lane row, so a chunk holds 128 words an id (32 MiB a table). All
+# 95.5M entries of the fit at once would hold 49 GB: XLA does not fuse
+# the row gather into the lane select, and the program does not compile
+_LOOKUP_CHUNK = 1 << 16
+
+
+def _lookup_rows(tables: tuple, ids: tuple) -> tuple:
+    """``tuple(t[x] for t, x in zip(tables, ids))`` for 1-D tables and
+    ids ``>= 0`` of one length, the same bits: ``take_lane`` from each
+    table's lane view, built once before the loop, ``_LOOKUP_CHUNK`` ids
+    at a time, written into the results in place. The last chunk ends at
+    the last id and reads some of the one before it again: the same rows
+    written to the same places."""
+    n = ids[0].shape[0]
+    c = min(n, _LOOKUP_CHUNK)
+    views = tuple(lane_view(t) for t in tables)
+
+    def chunk(j, outs):
+        s = jnp.minimum(j * c, n - c)
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                out, take_lane(view, jax.lax.dynamic_slice(x, (s,), (c,))),
+                (s,))
+            for out, view, x in zip(outs, views, ids))
+
+    return jax.lax.fori_loop(0, -(-n // c), chunk,
+                             tuple(jnp.zeros_like(x, t.dtype)
+                                   for t, x in zip(tables, ids)))
+
+
 @partial(jax.jit, static_argnames=("k", "rpb_u", "rpb_v"))
 def _bucket_entries(key, u, i, r, w, row_of_u, row_of_i,
                     k: int, rpb_u: int, rpb_v: int):
@@ -366,8 +419,7 @@ def _bucket_entries(key, u, i, r, w, row_of_u, row_of_i,
     # named scopes here and in _layout: HLO metadata only, so a device
     # trace can name the phases (the sorts, the offsets, the block copies)
     with jax.named_scope("bucket/assign"):
-        urow = row_of_u[u]
-        irow = row_of_i[i]
+        urow, irow = _lookup_rows((row_of_u, row_of_i), (u, i))
         ublk = urow // rpb_u
         iblk = irow // rpb_v
         strat = (iblk - ublk) % k
@@ -608,6 +660,7 @@ def device_block_problem(
         # the one tiny device→host sync: it also makes this seam's end
         # the device's true end of the bucket phase
         sizes_host = np.asarray(sizes)
+        _count_lane_lookups(u.shape[0])
     with seam("fit/blocking/layout"):
         bmax = max(int(sizes_host.max()), 1)
         mbm = max(minibatch_multiple, 1)
@@ -907,8 +960,7 @@ def _mesh_bucket(part, n: int, q: int, c: int, s: int, rpb_u: int,
         with jax.named_scope("bucket/assign"):
             # the one-chip program's keys; the round-robin of weight-0
             # entries by their place in the whole input
-            urow = row_of_u[u]
-            irow = row_of_i[i]
+            urow, irow = _lookup_rows((row_of_u, row_of_i), (u, i))
             ublk = urow // rpb_u
             iblk = irow // rpb_v
             flat = (((iblk - ublk) % k) * k + ublk).astype(jnp.int32)
@@ -1083,6 +1135,7 @@ def mesh_block_problem(
             urow_s, irow_s, vals_s, w_s, sizes)
     exchange_bytes = (k - 1) * c * _EXCHANGED * 4
     _publish_exchange(exchange_bytes, sizes_host.sum(axis=1), retries)
+    _count_lane_lookups(k * q * (retries + 1))
     return MeshBlockedProblem(
         ru=ru, ri=ri, rv=rv, rw=rw, icu=icu, icv=icv,
         omega_u=omega_u, omega_v=omega_v,
@@ -1116,6 +1169,19 @@ def _publish_exchange(sent_bytes: int, held, retries: int) -> None:
         obs.counter("blocking_exchange_bytes_total",
                     chip=str(chip)).inc(sent_bytes)
         obs.gauge("blocking_shard_entries", chip=str(chip)).set(int(entries))
+
+
+def _count_lane_lookups(entries: int) -> None:
+    """``blocking_lane_lookups_total`` on the live registry
+    (``obs.enable()``; nothing otherwise): the id→row lookups
+    ``_lookup_rows`` made, a user's and an item's for each entry a
+    bucket program looked up (a chip's zero-filled tail and a shuffle
+    retry's second pass among them)."""
+    from large_scale_recommendation_tpu.obs.registry import get_registry
+
+    obs = get_registry()
+    if obs.enabled:
+        obs.counter("blocking_lane_lookups_total").inc(2 * int(entries))
 
 
 def recompute_inv_counts(problem: DeviceBlockedProblem, minibatch: int):

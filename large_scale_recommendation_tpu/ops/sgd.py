@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 
 from large_scale_recommendation_tpu.data.device_blocking import (
+    lane_view,
     sorted_run_weights,
+    take_lane,
 )
 
 
@@ -113,28 +115,6 @@ def dsgd_flops_per_sweep(nnz: int, rank: int, loss: str = "squared") -> int:
     return int(nnz * (10 if loss == "bpr" else 6) * rank)
 
 
-def _lane_view(omega: jax.Array) -> jax.Array:
-    """A 1-D per-row ``omega`` as rows of 128 lanes: zero-padded to a
-    whole row and reshaped to ``[ceil(h/128), 128]``, once a block (the
-    argument ``sgd_minibatch_update`` takes; ``_take_lane`` reads it)."""
-    return jnp.pad(omega, (0, -omega.shape[0] % 128)).reshape(-1, 128)
-
-
-def _take_lane(view: jax.Array, rows: jax.Array) -> jax.Array:
-    """``omega[rows]`` from ``view = _lane_view(omega)``, rows ``>= 0``: the
-    same bits, read a 128-lane row at a time.
-
-    On the TPU a gather whose slice is one float32 element costs about
-    7 ns an index, one whose slice is a 128-lane row about 1.5 ns (PERF.md,
-    Findings, PR 39). So each index gathers its whole row and a select
-    keeps its lane: a sum of one value and 127 zeros is that value. Not a
-    one-hot ``dot``, which the TPU's default precision rounds to bfloat16.
-    """
-    lane = jax.lax.broadcasted_iota(rows.dtype, (1, 128), 1)
-    picked = view[rows >> 7]  # row rows // 128, lane rows % 128
-    return jnp.where(lane == (rows & 127)[:, None], picked, 0).sum(-1)
-
-
 def sgd_minibatch_update(
     U: jax.Array,
     V: jax.Array,
@@ -175,7 +155,7 @@ def sgd_minibatch_update(
     per-rating semantics.
 
     ``omega_u``/``omega_v`` are the per-row ω as lane views
-    (``_lane_view``, which ``sgd_block_sweep`` builds once a block), or
+    (``lane_view``, which ``sgd_block_sweep`` builds once a block), or
     ``None``.
 
     ``pred_axis`` names the mesh axis U/V are rank-sharded over (the
@@ -198,8 +178,8 @@ def sgd_minibatch_update(
         u = U[u_rows]
         v = V[i_rows]
         with jax.named_scope("omega"):
-            ou = None if omega_u is None else _take_lane(omega_u, u_rows)
-            ov = None if omega_v is None else _take_lane(omega_v, i_rows)
+            ou = None if omega_u is None else take_lane(omega_u, u_rows)
+            ov = None if omega_v is None else take_lane(omega_v, i_rows)
     with jax.named_scope("sgd/update"):
         pred = None
         if pred_axis is not None:
@@ -401,8 +381,8 @@ def sgd_block_sweep(
     """
     # built here, before the scan: a view built in the step is sunk into
     # the loop by XLA, a pad a minibatch (tests/test_tpu_compile.py)
-    omega_u = None if omega_u is None else _lane_view(omega_u)
-    omega_v = None if omega_v is None else _lane_view(omega_v)
+    omega_u = None if omega_u is None else lane_view(omega_u)
+    omega_v = None if omega_v is None else lane_view(omega_v)
 
     def step(U, V, ur, ir, vals, w, icu, icv):
         return sgd_minibatch_update(

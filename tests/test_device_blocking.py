@@ -572,6 +572,60 @@ class TestLayoutBitForBit:
                 _bits(getattr(padded, name)), _bits(want), name)
 
 
+class TestLaneLookupBitForBit:
+    """``bucket/assign`` looks its id→row tables up a 128-lane row at a
+    time, ``_LOOKUP_CHUNK`` ids a pass (``_lookup_rows``): the same bits
+    as the element gathers ``row_of_u[u]``, ``row_of_i[i]`` it replaced."""
+
+    CHUNK = device_blocking._LOOKUP_CHUNK
+
+    @pytest.mark.parametrize("n", [1000, CHUNK, CHUNK + 1],
+                             ids=["below", "one_chunk", "one_over"])
+    @pytest.mark.parametrize("h", [1, 127, 128, 129, 17770])
+    def test_bucket_entries_equals_element_gathers(self, monkeypatch, h, n):
+        k = 4
+        rpb = device_blocking.rows_per_block(h, k)
+        rng = np.random.default_rng(h * 7 + n)
+        u = rng.integers(0, h, n)
+        i = rng.integers(0, h, n)
+        # the highest ids, whose lanes end the padded view's last row
+        u[:2] = i[-2:] = h - 1
+        args = (jax.random.PRNGKey(h), jnp.asarray(u, jnp.int32),
+                jnp.asarray(i, jnp.int32),
+                jnp.asarray(rng.normal(size=n), jnp.float32),
+                jnp.asarray(rng.random(n) > 0.1, jnp.float32),
+                jnp.asarray(rng.integers(0, k * rpb, h), jnp.int32),
+                jnp.asarray(rng.integers(0, k * rpb, h), jnp.int32),
+                k, rpb, rpb)
+        bucket = device_blocking._bucket_entries
+        lanes = bucket(*args)
+        monkeypatch.setattr(device_blocking, "_lookup_rows",
+                            lambda tables, ids: tuple(
+                                t[x] for t, x in zip(tables, ids)))
+        bucket.clear_cache()  # trace again with the element gathers
+        try:
+            gathers = bucket(*args)
+        finally:
+            bucket.clear_cache()
+        for a, b in zip(lanes, gathers):
+            np.testing.assert_array_equal(_bits(a), _bits(b))
+        assert int(np.asarray(lanes[0]).sum()) == n
+
+    def test_lookups_on_the_registry(self):
+        from large_scale_recommendation_tpu import obs
+
+        u, i, r, nu, ni = _toy(n=3001, nu=70, ni=50, seed=2)
+        registry, _ = obs.enable()
+        try:
+            device_blocking.device_block_problem(u, i, r, nu, ni,
+                                                 num_blocks=2)
+            got = [m["value"] for m in registry.snapshot()["metrics"]
+                   if m["name"] == "blocking_lane_lookups_total"]
+        finally:
+            obs.disable()
+        assert got == [2 * 3001]  # a user's row and an item's an entry
+
+
 # Today's bodies before ISSUE 27, kept as the oracles: an index vector
 # computed, then applied one element at a time.
 
@@ -734,8 +788,8 @@ class TestNoPerElementIndexing:
                 jax.ShapeDtypeStruct((self.N,), jnp.int32)))
         assert ops == [("scatter", 2 * self.N)], ops
 
-    def _lowered(self):
-        k, n = self.K, self.N
+    def _lowered(self, n=None):
+        k, n = self.K, n or self.N
         rpb_u = device_blocking.rows_per_block(self.NU, k)
         rpb_v = device_blocking.rows_per_block(self.NI, k)
         i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
@@ -754,11 +808,17 @@ class TestNoPerElementIndexing:
         return bucket, layouts, k * k * bmax
 
     def test_bucket_entries_gathers_only_the_row_tables(self):
-        # all four steps of the issue are in: step 4 (the bucket phase) too
-        bucket, _, _ = self._lowered()
-        long_ops = [(op, sz) for op, sz in
-                    self._indexed_ops(bucket) if sz >= self.N]
-        assert long_ops == [("gather", self.N)] * 2, long_ops  # row_of_*[·]
+        # all four steps of the issue are in: step 4 (the bucket phase) too.
+        # The two id→row lookups gather 128-lane rows a chunk at a time
+        # (_lookup_rows): below a chunk, one chunk of every entry; above
+        # it, chunks of _LOOKUP_CHUNK, and nothing as long as the entries
+        chunk = device_blocking._LOOKUP_CHUNK
+        for n in (self.N, 2 * chunk + 3):
+            bucket, _, _ = self._lowered(n)
+            long_ops = [(op, sz) for op, sz in
+                        self._indexed_ops(bucket) if sz >= min(n, chunk)]
+            rows = [("gather", min(n, chunk) * 128)] * 2  # row_of_*'s views
+            assert long_ops == rows, (n, long_ops)
 
     @pytest.mark.parametrize("side", [None, "user", "item"])
     def test_layout_has_no_gather_or_scatter_of_layout_length(self, side):

@@ -36,8 +36,8 @@ class TestKernelOracle:
         Un, Vn = sgd_ops.sgd_minibatch_update(
             jnp.array(U), jnp.array(V), jnp.array(ur), jnp.array(ir),
             jnp.array(vals), jnp.array(w),
-            sgd_ops._lane_view(jnp.array(omega)),
-            sgd_ops._lane_view(jnp.array(omega)), upd, 1, collision="sum")
+            sgd_ops.lane_view(jnp.array(omega)),
+            sgd_ops.lane_view(jnp.array(omega)), upd, 1, collision="sum")
 
         # NumPy oracle: additive deltas from OLD factors, accumulated
         eU, eV = U.copy(), V.copy()
@@ -60,8 +60,8 @@ class TestKernelOracle:
         Un, Vn = sgd_ops.sgd_minibatch_update(
             jnp.array(U), jnp.array(V), jnp.array(ur), jnp.array(ur),
             jnp.zeros(8, jnp.float32), jnp.array(w),
-            sgd_ops._lane_view(jnp.ones(10)),
-            sgd_ops._lane_view(jnp.ones(10)), upd, 1)
+            sgd_ops.lane_view(jnp.ones(10)),
+            sgd_ops.lane_view(jnp.ones(10)), upd, 1)
         np.testing.assert_array_equal(np.asarray(Un), U)
         np.testing.assert_array_equal(np.asarray(Vn), V)
 
@@ -270,7 +270,7 @@ class TestOmegaByLaneRow:
         omega = rng.integers(0, 3000, h).astype(np.float32)
         omega[rng.integers(0, h, 3)] = [0.0, 1.0, 2.0 ** 24]
         rows = np.concatenate([[0, h - 1], rng.integers(0, h, 509)])
-        got = sgd_ops._take_lane(sgd_ops._lane_view(jnp.asarray(omega)),
+        got = sgd_ops.take_lane(sgd_ops.lane_view(jnp.asarray(omega)),
                                  jnp.asarray(rows, jnp.int32))
         np.testing.assert_array_equal(np.asarray(got), omega[rows])
 
@@ -298,7 +298,7 @@ class TestOmegaByLaneRow:
                                            "mean")
 
         lanes = sweep()
-        monkeypatch.setattr(sgd_ops, "_take_lane",
+        monkeypatch.setattr(sgd_ops, "take_lane",
                             lambda view, rows: view.reshape(-1)[rows])
         gathers = sweep()
         for a, b in zip(lanes, gathers):

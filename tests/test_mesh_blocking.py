@@ -160,6 +160,8 @@ def test_a_shuffle_round_that_outgrows_its_slots_runs_again():
                                      minibatch_multiple=32, seed=4)
     _assert_equal(m, p, want)
     assert got["blocking_shuffle_retries_total"] == {None: 1}
+    # both passes looked every entry's two rows up
+    assert got["blocking_lane_lookups_total"] == {None: 2 * 2 * n}
 
 
 def test_entries_already_sharded_stay_where_they_are():
@@ -215,6 +217,23 @@ def test_exchange_on_the_registry():
     assert sum(held.values()) == n
     rw = np.asarray(m.rw)
     assert held == {c: float((rw[int(c)] > 0).sum()) for c in chips}
+
+
+def test_lane_lookups_on_the_registry():
+    """Two id→row lookups an entry a chip's share holds, the zero-filled
+    tail past ``n`` among them: ``k * q`` entries."""
+    rng = np.random.default_rng(4)
+    n, nu, ni, k = 4001, 70, 50, 4
+    u, i = rng.integers(0, nu, n), rng.integers(0, ni, n)
+    r = rng.normal(0, 1, n).astype(np.float32)
+    registry, _ = obs.enable()
+    try:
+        db.mesh_block_problem(u, i, r, nu, ni, Partitioner(num_devices=k),
+                              minibatch_multiple=32, seed=2)
+        got = _blocking_metrics(registry)
+    finally:
+        obs.disable()
+    assert got["blocking_lane_lookups_total"] == {None: 2 * k * 1001}
 
 
 def test_mesh_fit_device_blocks_on_every_chip():

@@ -282,3 +282,33 @@ def test_ring_blocking_holds_no_array_of_every_entry(topo):
                                 r'(bucket/\w+)/', text)
             assert sorted(scopes) == ["bucket/exchange"] + [
                 "bucket/permutation"] * (db.shuffle_rounds(n) + 1), scopes
+
+
+def test_bucket_assign_gathers_lane_rows_a_chunk_at_a_time(one_chip):
+    """``_bucket_entries`` compiled for one v5e chip at an entry count no
+    other size shares (200,003: three chunks and a tail), tables of 3,001
+    and 1,777 ids: the two id→row lookups (scope ``bucket/assign``) gather
+    128-lane rows of the tables' lane views, ``_LOOKUP_CHUNK`` ids at a
+    time. No buffer holds 128 words an entry (every entry at once is 49 GB
+    at the fit's 95.5M entries, and does not compile), and no pad or copy
+    of a table runs in the chunk loop: the views are built once."""
+    from large_scale_recommendation_tpu.data import device_blocking as db
+
+    n, nu, ni, k = 200003, 3001, 1777, 8
+    i32, f32 = jnp.int32, jnp.float32
+    sds = partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    key = jax.random.PRNGKey(0)
+    hlo = db._bucket_entries.lower(
+        sds(key.shape, key.dtype), sds((n,), i32), sds((n,), i32),
+        sds((n,), f32), sds((n,), f32), sds((nu,), i32), sds((ni,), i32),
+        k, db.rows_per_block(nu, k),
+        db.rows_per_block(ni, k)).compile().as_text()
+    assert not re.search(rf"s32\[{n},128\]", hlo)
+    gathers = [line for line in hlo.splitlines()
+               if " gather(" in line and "bucket/assign/" in line]
+    assert len(gathers) == 2, gathers
+    assert all("slice_sizes={1,128}" in g and
+               f"= s32[{db._LOOKUP_CHUNK},128]" in g for g in gathers), gathers
+    views = f"{-(-nu // 128)},128|{-(-ni // 128)},128"
+    assert not re.search(rf"= s32\[({nu}|{ni}|{views})\]\S* (copy|pad|fusion)\(",
+                         _inside_loops(hlo))
