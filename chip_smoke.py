@@ -8,16 +8,11 @@ points a user calls, at the full width of the flagship — the ML-25M shape,
 
 1. train   ``DSGD.fit_device`` for a few sweeps; holdout RMSE is finite and
            lower after the last sweep than after the first.
-2. pallas  the same tables through both Pallas routes COMPILED (the
-           compile funnel must see a Mosaic custom call): the pipelined
-           stratum kernel (k=32, minibatch 1024) and the per-block kernel
-           (k=32, minibatch 2048), each against ``kernel="xla"`` from
-           identical initial factors, chip to chip.
-3. serve   ``ServingEngine`` over the trained 59,047 x 128 catalog, mixed
+2. serve   ``ServingEngine`` over the trained 59,047 x 128 catalog, mixed
            request sizes through ``submit``/``flush``: the exact path's ids
            equal ``model.recommend``; the two-stage retriever's recall is
            measured against it.
-4. mesh    with four or more devices: ``MeshDSGD`` on the 4x1 mesh, the
+3. mesh    with four or more devices: ``MeshDSGD`` on the 4x1 mesh, the
            2x2 rank-sharded mesh against its 2x1 twin, one ``MeshALS``
            round and mesh serving — every placed array proven to sit on
            four distinct devices.
@@ -44,13 +39,12 @@ import time
 import numpy as np
 
 # Chip-to-chip agreement bounds, set from what the chip showed (PERF.md
-# Findings, PR 21). After one sweep from identical initial factors both
-# Pallas routes differed from the XLA kernel by at most 6.0e-8 on factor
-# entries of magnitude 0.118, and the 2x2 rank-sharded mesh from its 2x1
-# twin by 5.2e-8 of 0.110: 5e-7 relative, a few f32 ulps — the order of
-# the scatter-adds and of the psum. The holdout RMSEs were equal to the
-# last printed digit. The bounds leave ~20x room over that and none for
-# a real disagreement (one bf16 pass would be ~4e-3 relative).
+# Findings). After one sweep from identical initial factors the 2x2
+# rank-sharded mesh differed from its 2x1 twin by 5.2e-8 on factor
+# entries of magnitude 0.110: 5e-7 relative, a few f32 ulps — the order
+# of the psum. The holdout RMSEs were equal to the last printed digit.
+# The bounds leave ~20x room over that and none for a real disagreement
+# (one bf16 pass would be ~4e-3 relative).
 FACTORS_MAX_REL = 1e-5         # max|d factors| / max|factors|
 HOLDOUT_RMSE_MAX_ABS = 1e-5
 TWO_STAGE_MIN_RECALL = 0.90    # int8 stage 1 + f32 rescore vs exact top-10
@@ -70,12 +64,8 @@ class Sizes:
     sweeps: int = 4
     blocks: int = 8                # the fit cells': k = 8, minibatch 32768
     minibatch: int = 32768
-    pallas_blocks: int = 32        # the AOT gate's ML-25M geometries
-    pipelined_minibatch: int = 1024   # stratum_sweep k32_rank128_mb1024_f32
-    per_block_minibatch: int = 2048   # block_sweep k32_rank128_mb2048
     als_nnz: int = 1_000_000
     request_sizes: tuple = (1, 7, 64, 300, 1500)
-    pallas_interpret: bool = False  # the CPU rehearsal's seam, never main()
 
 
 def log(msg: str) -> None:
@@ -101,32 +91,6 @@ def dsgd_config(s: Sizes, **overrides):
               num_blocks=s.blocks)
     kw.update(overrides)
     return DSGDConfig(**kw)
-
-
-def make_witness():
-    """The repo's compile-funnel hook (``obs.introspect``), extended to
-    note for every module handed to XLA whether it holds a Mosaic custom
-    call — read off the module itself, so a persistent-cache hit is
-    witnessed like a fresh compile."""
-    from large_scale_recommendation_tpu.obs.introspect import (
-        Introspector,
-        _module_name,
-    )
-
-    class MosaicWitness(Introspector):
-        def __init__(self):
-            super().__init__()
-            self.modules: list[tuple[str, bool]] = []
-
-        def _on_compile(self, args, kwargs, executable, wall):
-            super()._on_compile(args, kwargs, executable, wall)
-            computation = kwargs.get("computation",
-                                     args[1] if len(args) > 1 else None)
-            asm = computation.operation.get_asm(large_elements_limit=8)
-            self.modules.append((_module_name(computation),
-                                 "tpu_custom_call" in asm))
-
-    return MosaicWitness()
 
 
 class SegmentTables:
@@ -191,7 +155,7 @@ def leg_train(s: Sizes, data, holdout, vocab):
              for _, U, V in solver.evaluator.tables]
     wall = time.perf_counter() - t0
     log(f"  holdout RMSE per sweep: {[round(x, 4) for x in curve]} "
-        f"(fit + scoring {wall:.1f}s, route {solver.kernel_route})")
+        f"(fit + scoring {wall:.1f}s)")
     check(model.U.shape[1] == s.rank and model.V.shape[1] == s.rank
           and model.U.shape[0] >= vocab[0] and model.V.shape[0] >= vocab[1],
           f"factor tables are full width: U {tuple(model.U.shape)}, "
@@ -202,62 +166,6 @@ def leg_train(s: Sizes, data, holdout, vocab):
           f"holdout RMSE fell: {curve[0]:.4f} after sweep 1 -> "
           f"{curve[-1]:.4f} after sweep {s.sweeps}")
     return model
-
-
-def leg_pallas(s: Sizes, data, holdout, vocab, witness) -> None:
-    """Both Pallas routes compiled, each against the XLA kernel from
-    identical initial factors (same seed, same blocking), one sweep.
-
-    Constant eta 0.1 (what ``probe_variants`` uses), not the bench's
-    warm_boost: that schedule's 0.75 first-sweep step is tuned for
-    minibatch 32768 and takes BOTH kernels to NaN at minibatch 1024 on
-    the chip (PR 21's first run; reproduced on the CPU)."""
-    from large_scale_recommendation_tpu.models.dsgd import DSGD
-
-    for mb, want in ((s.pipelined_minibatch, "stratum_pipeline"),
-                     (s.per_block_minibatch, "per_block")):
-        if s.pallas_interpret:  # skips the budgets: always pipelines
-            want = "stratum_pipeline"
-        log(f"[pallas] one sweep at k={s.pallas_blocks}, minibatch {mb}: "
-            f"kernel=pallas vs kernel=xla")
-        models = {}
-        for kernel in ("pallas", "xla"):
-            solver = DSGD(dsgd_config(
-                s, iterations=1, num_blocks=s.pallas_blocks,
-                minibatch_size=mb, kernel=kernel,
-                learning_rate=0.1, lr_schedule="constant",
-                pallas_interpret=s.pallas_interpret))
-            seen = len(witness.modules)
-            t0 = time.perf_counter()
-            models[kernel] = m = solver.fit_device(*data, *vocab)
-            rmse = holdout_rmse(m, m.U, m.V, holdout)
-            log(f"  kernel={kernel}: route {solver.kernel_route}, holdout "
-                f"RMSE {rmse:.5f}, fit {time.perf_counter() - t0:.1f}s")
-            models[kernel + "_rmse"] = rmse
-            if kernel == "pallas":
-                check(solver.kernel_route == f"pallas/{want}",
-                      f"the geometry selected the {want} kernel")
-            if kernel == "pallas" and not s.pallas_interpret:
-                mosaic = [name for name, has in witness.modules[seen:]
-                          if has]
-                check(any("dsgd_train_pallas" in name for name in mosaic),
-                      "the compiled training module holds a Mosaic custom "
-                      f"call (tpu_custom_call in {mosaic})")
-        dU = float(np.max(np.abs(np.asarray(models["pallas"].U)
-                                 - np.asarray(models["xla"].U))))
-        dV = float(np.max(np.abs(np.asarray(models["pallas"].V)
-                                 - np.asarray(models["xla"].V))))
-        scale = float(np.max(np.abs(np.asarray(models["xla"].U))))
-        d_rmse = abs(models["pallas_rmse"] - models["xla_rmse"])
-        log(f"  chip to chip: max|dU| {dU:.3e}, max|dV| {dV:.3e} (max|U| "
-            f"{scale:.3f}), |d holdout RMSE| {d_rmse:.3e}")
-        check(np.isfinite(dU) and np.isfinite(dV)
-              and max(dU, dV) <= FACTORS_MAX_REL * scale,
-              f"{want}: factors agree with XLA within {FACTORS_MAX_REL} "
-              "of their magnitude")
-        check(d_rmse <= HOLDOUT_RMSE_MAX_ABS,
-              f"{want}: holdout RMSE agrees with XLA within "
-              f"{HOLDOUT_RMSE_MAX_ABS}")
 
 
 def make_requests(s: Sizes, num_users: int):
@@ -443,10 +351,10 @@ def leg_mesh(s: Sizes, data, holdout, vocab) -> None:
     del model
 
     # -- 2x2: rank-sharded factors (the psum of partial dots) -----------------
-    # constant eta 0.1 like the Pallas leg: at k=2 the bench's warm_boost
-    # step takes the 2x2 mesh AND its 2x1 twin to NaN in one sweep (four-
-    # chip run, PR 21; single-device k=2 does the same on the CPU, so it
-    # is the step size, not the mesh)
+    # constant eta 0.1: at k=2 the bench's warm_boost step takes the 2x2
+    # mesh AND its 2x1 twin to NaN in one sweep (a four-chip run;
+    # single-device k=2 does the same on the CPU, so it is the step size,
+    # not the mesh)
     stable = mesh_config(1, learning_rate=0.1, lr_schedule="constant")
     part22 = recording_partitioner(num_devices=4, model_parallel=2)
     m22 = MeshDSGD(stable, partitioner=part22).fit_device(*data, *vocab)
@@ -491,12 +399,11 @@ def leg_mesh(s: Sizes, data, holdout, vocab) -> None:
 # --------------------------------------------------------------------------
 
 
-def run(s: Sizes, witness) -> None:
+def run(s: Sizes) -> None:
     import jax
 
     data, holdout, vocab = generate(s)
     model = leg_train(s, data, holdout, vocab)
-    leg_pallas(s, data, holdout, vocab, witness)
     leg_serve(s, model, vocab[0])
     del model
     if len(jax.devices()) >= 4:
@@ -511,6 +418,7 @@ def main() -> int:
     import jax
     import jaxlib
 
+    from large_scale_recommendation_tpu.obs.introspect import Introspector
     from large_scale_recommendation_tpu.utils.platform import (
         device_summary,
         enable_compilation_cache,
@@ -532,10 +440,10 @@ def main() -> int:
               "runs on a TPU only", file=sys.stderr)
         return 1
 
-    witness = make_witness()
+    witness = Introspector()
     check(witness.install(), "compile funnel hooked")
     try:
-        run(Sizes(), witness)
+        run(Sizes())
     finally:
         witness.uninstall()
     check(witness.errors == 0, "the compile witness saw every module")

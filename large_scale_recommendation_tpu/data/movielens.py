@@ -113,17 +113,6 @@ _SHAPES = {
 }
 
 
-def vocab_overrides_from_env() -> tuple[int | None, int | None]:
-    """BENCH_USERS/BENCH_ITEMS → (num_users, num_items) overrides, the ONE
-    copy of the probes' env contract: reduced-nnz runs must shrink the
-    vocab along with nnz, or the workload degenerates (DSGD: obs/row below
-    the recoverable regime; ALS: mostly-empty normal equations). Used by
-    the scripts/ probes so the parse cannot drift."""
-    nu = os.environ.get("BENCH_USERS")
-    ni = os.environ.get("BENCH_ITEMS")
-    return (int(nu) if nu else None, int(ni) if ni else None)
-
-
 def synthetic_like(name: str, nnz: int | None = None, rank: int = 16,
                    noise: float = 0.3, seed: int = 0,
                    skew_lam: float = 2.0,

@@ -65,7 +65,7 @@ class DSGDConfig:
     init_scale: float = 1.0  # factor init upper bound (nextDouble ∈ [0,1))
     collision_mode: str = "mean"  # minibatch row-collision handling (ops.sgd)
     # precompute the "mean"-mode collision scales at blocking time (same
-    # math, removes two full-table scatter+gather rounds per kernel step)
+    # math, removes two full-table scatter+gather rounds per minibatch)
     precompute_collisions: bool = True
     # intra-minibatch ordering ("user"|"item"|None): gather/scatter locality
     # lever, same math (data.blocking.block_ratings). Measured at full
@@ -74,25 +74,12 @@ class DSGDConfig:
     # stays None for bit-reproducibility with earlier runs; perf-sensitive
     # callers should set "item" (the bench does).
     minibatch_sort: str | None = None
-    # "xla" (ops.sgd.dsgd_train) | "pallas" (ops.pallas_sgd VMEM-staged
-    # sweeps — AOT-verified to compile for v5e, docs/PERF.md "Mosaic
-    # lowering verdicts"). The pallas path inlines the λ/ω rule, so it
-    # requires the default RegularizedSGDUpdater family,
-    # collision_mode="mean" and precompute_collisions=True.
-    kernel: str = "xla"
-    # run kernel="pallas" through the Pallas INTERPRETER instead of
-    # Mosaic — the explicit CPU spelling the parity tests use. Off a TPU
-    # without it, kernel="pallas" raises: nothing picks the interpreter
-    # by looking at the backend (it skips the VMEM/SMEM/alignment
-    # guards, so a silent slide into it would hide geometry errors too).
-    pallas_interpret: bool = False
-    # factor table storage dtype: "float32" | "bfloat16" (the ALX
-    # recipe, training half — ISSUE 6). bf16 halves the tables' HBM
-    # footprint and per-sweep factor traffic; BOTH kernels accumulate
-    # gradients in f32 (dsgd_train upcasts once per segment, the Pallas
-    # kernels upcast the VMEM-resident slice), so duplicate-row scatter
-    # semantics stay exact. Checkpoints round-trip the dtype
-    # (utils.checkpoint bit-view encoding).
+    # factor table storage dtype: "float32" | "bfloat16" (the ALX recipe,
+    # training half). bf16 halves the tables' HBM footprint and per-sweep
+    # factor traffic; the sweep accumulates gradients in f32 (dsgd_train
+    # upcasts once per segment), so duplicate-row scatter semantics stay
+    # exact. Checkpoints round-trip the dtype (utils.checkpoint bit-view
+    # encoding).
     factor_dtype: str = "float32"
     # the objective: "squared" (the reference's, on the ratings' values)
     # or "bpr" (Rendle et al., UAI 2009: every entry is a positive and a
@@ -104,10 +91,6 @@ class DSGDConfig:
         if self.loss not in ("squared", "bpr"):
             raise ValueError(
                 f"unknown loss {self.loss!r}; expected 'squared' or 'bpr'")
-        if self.loss == "bpr" and self.kernel == "pallas":
-            raise ValueError(
-                "kernel='pallas' inlines the squared loss's rule; "
-                "loss='bpr' runs on kernel='xla'")
 
     def schedule_fn(self):
         return schedule_from_name(self.lr_schedule, self.lambda_)
@@ -128,11 +111,6 @@ class DSGD:
             schedule=self.config.schedule_fn(),
         )
         self.model: MFModel | None = None
-        # which kernel the last fit ran: "xla", or "pallas/<route>" with
-        # the ops.pallas_sgd.pallas_route the geometry selected — the
-        # Pallas path is two kernels, and which one ran is decided by a
-        # VMEM model, not by the caller
-        self.kernel_route: str | None = None
         # divergence guard (obs.health.TrainingWatchdog): when attached,
         # each segment boundary scans the full tables for NaN/Inf (a
         # segment is seconds of work — the sweep is noise) and trips per
@@ -261,10 +239,6 @@ class DSGD:
             U, V, done = restore_segment_state(checkpoint_manager, kind, U, V)
         segment = checkpoint_every or cfg.iterations
 
-        # Module-level jitted train fn: stable function object + hashable
-        # static args (frozen-dataclass updater) → refits/segments with the
-        # same shapes/config hit the XLA compile cache.
-        train = self._train_fn(args)
         seam = get_tracer().seam
         timer = TrainSegmentTimer(
             "dsgd", kind,
@@ -277,7 +251,14 @@ class DSGD:
                 # already lives on device, so an armed transfer guard
                 # flags any implicit host round-trip sneaking in
                 with guard_scope("dsgd.fit"):
-                    U, V = train(U, V, iterations=seg, t0=done, k=k)
+                    # module-level jitted fn + hashable static args
+                    # (frozen-dataclass updater): refits and segments
+                    # of the same shapes hit the XLA compile cache
+                    U, V = sgd_ops.dsgd_train(
+                        U, V, *args, updater=self.updater,
+                        minibatch=cfg.minibatch_size, num_blocks=k,
+                        iterations=seg, collision=cfg.collision_mode,
+                        t0=done, loss=cfg.loss)
                 h.out = (U, V)
             done += seg
             if cfg.loss == "bpr" and n_ratings is not None:
@@ -315,71 +296,13 @@ class DSGD:
                                           kind=kind, step=int(done))
         timer.finish(n_ratings, bytes_per_iteration=(
             None if n_ratings is None else sgd_ops.dsgd_bytes_per_sweep(
-                n_ratings, int(np.shape(U)[-1]), kernel=cfg.kernel,
-                num_blocks=k, rows_u=int(np.shape(U)[0]),
-                rows_v=int(np.shape(V)[0]),
+                n_ratings, int(np.shape(U)[-1]),
                 factor_bytes=jnp.dtype(cfg.factor_dtype).itemsize,
                 loss=cfg.loss)),
             flops_per_iteration=(
                 None if n_ratings is None else sgd_ops.dsgd_flops_per_sweep(
                     n_ratings, int(np.shape(U)[-1]), loss=cfg.loss)))
         return U, V
-
-    def _train_fn(self, args):
-        """Kernel routing for the segment loop: ``cfg.kernel`` picks the
-        XLA scatter-add path (default) or the VMEM-staged Pallas path
-        (``ops.pallas_sgd.dsgd_train_pallas`` — the drop-in twin, same
-        positional layout; parity pinned by tests/test_pallas_sgd.py at
-        minibatch == and < block size, with and without LR schedules)."""
-        cfg = self.config
-
-        def xla(U, V, *, iterations, t0, k):
-            return sgd_ops.dsgd_train(
-                U, V, *args,
-                updater=self.updater,
-                minibatch=cfg.minibatch_size,
-                num_blocks=k,
-                iterations=iterations,
-                collision=cfg.collision_mode,
-                t0=t0,
-                loss=cfg.loss,
-            )
-
-        if cfg.kernel == "xla":
-            self.kernel_route = "xla"
-            return xla
-        if cfg.kernel != "pallas":
-            raise ValueError(
-                f"unknown kernel {cfg.kernel!r}; expected 'xla' or 'pallas'")
-
-        from large_scale_recommendation_tpu.ops.pallas_sgd import (
-            dsgd_train_pallas,
-            pallas_route,
-            require_mosaic_platform,
-            validate_pallas_contract,
-        )
-
-        upd = self.updater
-        validate_pallas_contract(upd, cfg.collision_mode,
-                                 args[-1] is not None)
-        require_mosaic_platform(jax.devices()[0].platform,
-                                cfg.pallas_interpret, "DSGD")
-
-        def pallas(U, V, *, iterations, t0, k):
-            self.kernel_route = "pallas/" + pallas_route(
-                int(U.shape[0]) // k, int(V.shape[0]) // k,
-                int(U.shape[-1]), int(args[0].shape[-1]),
-                cfg.minibatch_size, U.dtype.itemsize,
-                interpret=cfg.pallas_interpret)
-            return dsgd_train_pallas(
-                U, V, *args,
-                lr=float(upd.learning_rate), lam=float(upd.lambda_),
-                minibatch=cfg.minibatch_size, num_blocks=k,
-                iterations=iterations, interpret=cfg.pallas_interpret,
-                schedule=upd.schedule, t0=t0,
-            )
-
-        return pallas
 
     def fit_device(
         self,

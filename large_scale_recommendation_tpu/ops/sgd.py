@@ -33,28 +33,16 @@ from large_scale_recommendation_tpu.data.device_blocking import (
 )
 
 
-def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
-                         num_blocks: int = 1, rows_u: int = 0,
-                         rows_v: int = 0, factor_bytes: int = 4,
+def dsgd_bytes_per_sweep(nnz: int, rank: int, *, factor_bytes: int = 4,
                          model_size: int = 1, loss: str = "squared") -> int:
-    """Bytes of HBM traffic one full DSGD sweep moves PER DEVICE, per kernel.
+    """Bytes of HBM traffic one full DSGD sweep moves PER DEVICE.
 
-    The shared roofline model behind every ``effective_hbm_gbs`` number
-    (the probe variants and the ``train_hbm_gbs`` obs gauge) — one copy
-    so the accounting cannot drift between them.
-
-    - ``kernel="xla"`` (the gather path): every rating pays ~4 row
-      transactions (read+write of a u row and a v row) of
-      ``rank × factor_bytes`` plus ~16 B of COO stream. This is the
-      historical bench model (4·rank·4 + 16 at f32). Under
-      ``loss="bpr"`` a rating is a triple and pays ~6 (the negative's
-      row read and written too); its stream (u, i, weight, user scale)
-      is 16 B as well.
-    - ``kernel="pallas"`` (the VMEM-staged path): factor traffic is
-      CONTIGUOUS — each of the k strata reads+writes every factor row
-      once per sweep (k² block visits × rows-per-block), plus the
-      per-entry streams (2 int32 rows + 6 f32
-      vals/w/icu/icv/ωu/ωv ⇒ 32 B/rating).
+    The roofline model behind the ``train_hbm_gbs`` obs gauge. Every
+    rating pays ~4 row transactions (read+write of a u row and a v row)
+    of ``rank × factor_bytes`` plus ~16 B of COO stream. This is the
+    historical bench model (4·rank·4 + 16 at f32). Under ``loss="bpr"``
+    a rating is a triple and pays ~6 (the negative's row read and
+    written too); its stream (u, i, weight, user scale) is 16 B as well.
 
     ``model_size`` is the size of the ``'model'`` mesh axis: rank-sharded
     tables hold ``rank/model_size`` columns per device, so the factor-row
@@ -62,25 +50,11 @@ def dsgd_bytes_per_sweep(nnz: int, rank: int, *, kernel: str = "xla",
     axis and does NOT divide). The extra wire traffic the reduction
     collectives move is a SEPARATE term — see
     ``dsgd_collective_bytes_per_sweep`` — so the roofline can show HBM
-    and interconnect as distinct costs. The pallas kernel has no
-    rank-sharded variant (it stages full rows through VMEM), so
-    ``model_size > 1`` there is a modeling error, not a silent division.
+    and interconnect as distinct costs.
     """
     if model_size < 1 or rank % model_size:
         raise ValueError(
             f"model_size {model_size} must be ≥1 and divide rank {rank}")
-    if kernel == "pallas":
-        if loss != "squared":
-            raise ValueError("the pallas kernel runs the squared loss only")
-        if model_size != 1:
-            raise ValueError(
-                "pallas kernel has no rank-sharded traffic model "
-                "(model_size must be 1)")
-        if not rows_u or not rows_v:
-            raise ValueError(
-                "pallas traffic model needs rows_u/rows_v (table heights)")
-        factor = num_blocks * (rows_u + rows_v) * rank * factor_bytes * 2
-        return int(factor + nnz * 32)
     rows = 6 if loss == "bpr" else 4
     return int(nnz * (rows * (rank // model_size) * factor_bytes + 16))
 
@@ -452,8 +426,8 @@ def dsgd_train(
 
     On one device a sweep is ``k²`` block visits under one ``lax.scan``,
     strata ``s = 0..k-1`` and within a stratum blocks ``p = 0..k-1`` (the
-    order of ``ops.pallas_sgd.dsgd_train_pallas`` and, block for block, of
-    the mesh ring). Block ``p``'s users are the contiguous rows
+    mesh ring's order, block for block). Block ``p``'s users are the
+    contiguous rows
     ``[p·rpb_u, (p+1)·rpb_u)`` and its items in stratum ``s`` the rows of
     item block ``(p+s) mod k``, so a visit slices those two row ranges (and
     their omegas), runs ``sgd_block_sweep`` against them with block-local
@@ -472,9 +446,9 @@ def dsgd_train(
     storage dtype on exit, all inside this jitted computation. The
     tables at rest (HBM between segments, checkpoints, host↔device
     transfers) are half-width. The upcast stays one per call, not one
-    per block visit as the Pallas kernel stages it: rounding a block back
-    after every visit would round each row ``k`` times a sweep, another
-    arithmetic than this configuration's.
+    per block visit: rounding a block back after every visit would round
+    each row ``k`` times a sweep, another arithmetic than this
+    configuration's.
     """
     if loss == "bpr" and (n_real is None or neg_key is None):
         raise ValueError("loss='bpr' needs n_real and neg_key")

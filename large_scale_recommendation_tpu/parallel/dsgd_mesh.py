@@ -82,8 +82,6 @@ def build_mesh_dsgd_step(
     iterations: int,
     collision: str = "mean",
     with_inv: bool = False,
-    kernel: str = "xla",
-    pallas_interpret: bool = False,
 ):
     """Build the jitted multi-chip training function.
 
@@ -100,7 +98,7 @@ def build_mesh_dsgd_step(
     """
     return _build_mesh_dsgd_step(
         as_partitioner(mesh), updater, minibatch, num_blocks, iterations,
-        collision, with_inv, kernel, pallas_interpret)
+        collision, with_inv)
 
 
 @functools.lru_cache(maxsize=32)
@@ -112,8 +110,6 @@ def _build_mesh_dsgd_step(
     iterations: int,
     collision: str,
     with_inv: bool,
-    kernel: str,
-    pallas_interpret: bool,
 ):
     k = num_blocks
     axis = part.data_axis
@@ -127,22 +123,6 @@ def _build_mesh_dsgd_step(
     # replicated goldens bit-exact.
     pred_axis = part.model_axis if rank_sharded else None
     n_sharded = 10 if with_inv else 8
-    if kernel not in ("xla", "pallas"):
-        raise ValueError(
-            f"unknown kernel {kernel!r}; expected 'xla' or 'pallas'")
-    if kernel == "pallas":
-        # The Pallas block kernel stages FULL factor rows through VMEM
-        # (its whole DMA design); there is no rank-sliced variant, so a
-        # >1 model axis must refuse at build time rather than compute on
-        # slices. This is the one reasoned surviving caller of the
-        # escape hatch.
-        part.require_no_model_parallel(  # graftlint: disable=model-guard
-            "mesh DSGD pallas kernel")
-        from large_scale_recommendation_tpu.ops.pallas_sgd import (
-            validate_pallas_contract,
-        )
-
-        validate_pallas_contract(updater, collision, with_inv)
     if rank_sharded:
         factor_in = (part.spec("users", "rank"), part.spec("items", "rank"))
     else:
@@ -156,16 +136,12 @@ def _build_mesh_dsgd_step(
         mesh=part.mesh,
         in_specs=factor_in + (spec,) * (n_sharded - 2) + (part.spec(),),
         out_specs=factor_in,
-        # the replication checker has no rule for pallas_call at all
-        # ("No replication rule for pallas_call" — AOT-measured,
-        # docs/MOSAIC_AOT.json), and the Pallas interpreter's internal
-        # scan additionally drops varying-axis metadata on index arrays;
         # the rank-sharded route mixes model-axis-varying factor slices
         # with model-replicated strata through a psum, whose varying-axis
         # propagation the checker mis-infers across scan carries — the
         # model-parity tests pin its correctness instead. The replicated
-        # XLA route keeps the checker on.
-        check_vma=kernel != "pallas" and not rank_sharded,
+        # route keeps the checker on.
+        check_vma=not rank_sharded,
     )
     def run(U_l, V_l, ru_l, ri_l, rv_l, rw_l, ou_l, ov_l, *rest):
         # shard_map gives [1, k, b] for the device-major strata; drop the
@@ -177,8 +153,8 @@ def _build_mesh_dsgd_step(
         else:
             icu, icv, t0 = None, None, rest[0]
 
-        # bf16 factor shards on the XLA route: ONE f32 upcast per jitted
-        # segment (this whole scan), rounded back on exit — the same
+        # bf16 factor shards: ONE f32 upcast per jitted segment (this
+        # whole scan), rounded back on exit — the same
         # cadence as ops.sgd.dsgd_train, so gradient accumulation stays
         # exact across every sweep of the segment. Rounding per block
         # sweep instead stalls convergence at small learning rates (the
@@ -186,12 +162,9 @@ def _build_mesh_dsgd_step(
         # sweep's work is rounded away — measured: mesh bf16 RMSE froze
         # while f32 kept converging). The in-segment ppermute therefore
         # carries f32 shards; half-width applies AT REST (HBM between
-        # segments, checkpoints, host↔device). The Pallas route keeps
-        # store-dtype tables instead: per-visit VMEM rounding is
-        # intrinsic to its halved-HBM-DMA design (matching its
-        # single-device twin dsgd_train_pallas).
+        # segments, checkpoints, host↔device).
         fdt = U_l.dtype
-        if fdt == jnp.bfloat16 and kernel != "pallas":
+        if fdt == jnp.bfloat16:
             U_l = U_l.astype(jnp.float32)
             V_l = V_l.astype(jnp.float32)
 
@@ -202,29 +175,13 @@ def _build_mesh_dsgd_step(
             # η/√t schedule continues instead of restarting (same contract
             # as ops.sgd.dsgd_train)
             t = idx // k + 1 + t0
-            if kernel == "pallas":
-                from large_scale_recommendation_tpu.ops.pallas_sgd import (
-                    pallas_block_sweep,
-                )
-
-                # per-device block sweep through the VMEM-staged kernel;
-                # η evaluated here (trace level) and passed as a runtime
-                # scalar — same convention as ops.pallas_sgd.dsgd_train_pallas
-                lr_t = updater.schedule(
-                    jnp.float32(updater.learning_rate), t)
-                U, V = pallas_block_sweep(
-                    U, V, ru[s], ri[s], rv[s], rw[s], icu[s], icv[s],
-                    ou_l, ov, lr=lr_t, lam=float(updater.lambda_),
-                    minibatch=minibatch, interpret=pallas_interpret,
-                )
-            else:
-                U, V = sgd_ops.sgd_block_sweep(
-                    U, V, ru[s], ri[s], rv[s], rw[s], ou_l, ov,
-                    updater, t, minibatch, collision,
-                    None if icu is None else icu[s],
-                    None if icv is None else icv[s],
-                    pred_axis,
-                )
+            U, V = sgd_ops.sgd_block_sweep(
+                U, V, ru[s], ri[s], rv[s], rw[s], ou_l, ov,
+                updater, t, minibatch, collision,
+                None if icu is None else icu[s],
+                None if icv is None else icv[s],
+                pred_axis,
+            )
             # Rotate the item shard (and its omegas) one step down the ring
             # — ≙ the reference's inter-superstep shuffle of item blocks
             # (DSGDforMF.scala:611-619 / OfflineSpark.scala:196-201), now an
@@ -238,7 +195,7 @@ def _build_mesh_dsgd_step(
             step, (U_l, V_l, ov_l),
             jnp.arange(iterations * k, dtype=jnp.int32),
         )
-        if fdt == jnp.bfloat16 and kernel != "pallas":
+        if fdt == jnp.bfloat16:
             U_l = U_l.astype(fdt)
             V_l = V_l.astype(fdt)
         return U_l, V_l
@@ -287,11 +244,9 @@ class MeshDSGDConfig:
     collision_mode: str = "mean"  # see ops.sgd.sgd_minibatch_update
     precompute_collisions: bool = True  # see DSGDConfig
     minibatch_sort: str | None = None  # see DSGDConfig
-    kernel: str = "xla"  # "xla" | "pallas" — see DSGDConfig.kernel
-    pallas_interpret: bool = False  # see DSGDConfig.pallas_interpret
     # "float32" | "bfloat16" — see DSGDConfig.factor_dtype: half-width
     # factor shards at rest (HBM, checkpoints, the ppermute ring) with
-    # f32 accumulation inside both kernels
+    # f32 accumulation inside the sweep
     factor_dtype: str = "float32"
 
 
@@ -505,16 +460,6 @@ class MeshDSGD:
             with_inv = bool(inv_args)
             inv_args = tuple(part.place(x, "ratings") for x in inv_args)
 
-        if cfg.kernel == "pallas":
-            from large_scale_recommendation_tpu.ops.pallas_sgd import (
-                require_mosaic_platform,
-            )
-
-            # the MESH's devices decide, not the default backend: a CPU
-            # mesh on a TPU host still cannot run Mosaic
-            require_mosaic_platform(part.mesh.devices.flat[0].platform,
-                                    cfg.pallas_interpret, "MeshDSGD")
-
         from large_scale_recommendation_tpu.obs.instrument import (
             TrainSegmentTimer,
         )
@@ -528,8 +473,7 @@ class MeshDSGD:
             seg = min(segment, cfg.iterations - done)
             step_fn = build_mesh_dsgd_step(
                 part, self.updater, cfg.minibatch_size, k, seg,
-                cfg.collision_mode, with_inv, cfg.kernel,
-                cfg.kernel == "pallas" and cfg.pallas_interpret,
+                cfg.collision_mode, with_inv,
             )
             with timer.segment(seg) as h:
                 U, V = step_fn(U, V, *args, ou, ov, *inv_args,
@@ -553,9 +497,7 @@ class MeshDSGD:
         m = part.model_parallel
         timer.finish(n_ratings, bytes_per_iteration=(
             None if n_ratings is None else sgd_ops.dsgd_bytes_per_sweep(
-                n_ratings, int(np.shape(U)[-1]), kernel=cfg.kernel,
-                num_blocks=k, rows_u=int(np.shape(U)[0]),
-                rows_v=int(np.shape(V)[0]), factor_bytes=fdt.itemsize,
+                n_ratings, int(np.shape(U)[-1]), factor_bytes=fdt.itemsize,
                 model_size=m)),
             flops_per_iteration=(
                 None if n_ratings is None else sgd_ops.dsgd_flops_per_sweep(

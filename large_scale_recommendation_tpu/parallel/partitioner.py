@@ -306,9 +306,9 @@ class Partitioner:
         DSGD, mesh ALS, the serving top-k, the quantized catalog) all
         insert the rank-reduction collectives and run at model_parallel
         > 1. A path that accumulates across the full rank dimension with
-        NO cross-model-axis reduction (e.g. the Pallas block kernel,
-        which stages full factor rows through VMEM) must refuse loudly
-        here rather than silently compute on rank slices. Every call
+        NO cross-model-axis reduction (e.g. a kernel that stages full
+        factor rows through VMEM) must refuse loudly here rather than
+        silently compute on rank slices. Every call
         site outside this module needs a reasoned inline graftlint
         suppression — rule ``model-guard`` (tools/graftlint) flags any
         new unsuppressed caller, the same contract as the

@@ -397,8 +397,7 @@ def main(n_devices: int = 16, two_process: bool = True) -> dict:
         assert out["two_process"].get("ok") or \
             out["two_process"].get("skipped"), out["two_process"]
 
-    # machine-readable contract (same as scripts/pallas_probe.py):
-    # flush stderr BEFORE the final JSON line
+    # machine-readable contract: flush stderr BEFORE the final JSON line
     # so wrappers that merge 2>&1 still parse the LAST line
     sys.stderr.flush()
     print(json.dumps(out), flush=True)

@@ -3,7 +3,6 @@ step against the update written out a triple at a time, the fit against
 ``benchmark/reference/bpr_ref.py``, the sampler's draws, the model's
 surface, and ``utils.metrics.expected_percentile_rank``'s chunk."""
 
-import dataclasses
 import os
 import sys
 from functools import partial
@@ -375,9 +374,6 @@ def test_the_roofline_gauges_count_the_loss_s_work(monkeypatch, loss, rows,
     per_rating = (got[("train_hbm_gbs", "all")] * 1e9
                   / got[("train_throughput_ratings_per_s", "all")])
     assert per_rating == pytest.approx(rows * rank * 4 + 16, rel=1e-9)
-    with pytest.raises(ValueError, match="squared loss only"):
-        sgd_ops.dsgd_bytes_per_sweep(10, 8, kernel="pallas", num_blocks=1,
-                                     rows_u=8, rows_v=8, loss="bpr")
 
 
 def test_run_time_user_counts_match_the_precomputed_ones():
@@ -401,13 +397,9 @@ def test_the_host_path_fits_bpr_on_its_own_blocks():
     assert np.isfinite(np.asarray(model.V)).all()
 
 
-def test_unknown_loss_and_the_pallas_kernel_raise():
+def test_an_unknown_loss_and_a_missing_n_real_raise():
     with pytest.raises(ValueError, match="unknown loss"):
         DSGDConfig(loss="hinge")
-    with pytest.raises(ValueError, match="pallas"):
-        DSGDConfig(loss="bpr", kernel="pallas")
-    assert dataclasses.replace(DSGDConfig(), kernel="pallas").loss == (
-        "squared")
     u, i, U0, V0 = _one_block()
     with pytest.raises(ValueError, match="n_real"):
         sgd_ops.dsgd_train(

@@ -41,7 +41,7 @@ def test_a_failing_leg_fails_the_run_without_a_result_line(monkeypatch,
                         platform.compilation_cache_dir)
     ran = []
 
-    def failing_run(sizes, witness):
+    def failing_run(sizes):
         ran.append(sizes)
         raise AssertionError("chip_smoke check failed: planted")
 
@@ -52,7 +52,7 @@ def test_a_failing_leg_fails_the_run_without_a_result_line(monkeypatch,
     assert '"ok"' not in capsys.readouterr().out
 
     # and the same main() prints the contract's last line when legs pass
-    monkeypatch.setattr(chip_smoke, "run", lambda sizes, witness: None)
+    monkeypatch.setattr(chip_smoke, "run", lambda sizes: None)
     assert chip_smoke.main() == 0
     last = capsys.readouterr().out.strip().splitlines()[-1]
     assert json.loads(last) == {"ok": True, "device": {
@@ -61,16 +61,13 @@ def test_a_failing_leg_fails_the_run_without_a_result_line(monkeypatch,
 
 def test_flagship_sizes_are_full_width():
     """Width is not negotiable: the default sizes are the ML-25M shape at
-    rank 128 with the bench's geometry, and the AOT-gated Pallas ones."""
+    rank 128 with the bench's geometry."""
     import chip_smoke
 
     s = chip_smoke.Sizes()
     assert (s.num_users, s.num_items) == (None, None)  # the named shape
     assert (s.nnz, s.rank, s.blocks, s.minibatch) == (
         25_000_095, 128, 8, 32768)
-    assert (s.pallas_blocks, s.pipelined_minibatch,
-            s.per_block_minibatch) == (32, 1024, 2048)
-    assert not s.pallas_interpret
     cfg = chip_smoke.dsgd_config(s)
     assert (cfg.lambda_, cfg.learning_rate, cfg.lr_schedule,
             cfg.init_scale, cfg.minibatch_sort) == (
@@ -105,19 +102,17 @@ def test_fit_cells_train_with_the_smokes_settings(cell):
 
 def test_legs_rehearse_on_cpu_at_toy_size():
     """The legs against today's entry points, on virtual CPU devices at a
-    toy size with the Pallas kernels explicitly interpreted: control flow
-    and checks only."""
+    toy size: control flow and checks only."""
     import chip_smoke
+    from large_scale_recommendation_tpu.obs.introspect import Introspector
 
     toy = chip_smoke.Sizes(
         num_users=640, num_items=384, nnz=120_000, rank=16, sweeps=3,
-        blocks=2, minibatch=1024, pallas_blocks=4,
-        pipelined_minibatch=128, per_block_minibatch=256, als_nnz=40_000,
-        request_sizes=(1, 7, 40), pallas_interpret=True)
-    witness = chip_smoke.make_witness()
+        blocks=2, minibatch=1024, als_nnz=40_000, request_sizes=(1, 7, 40))
+    witness = Introspector()
     assert witness.install()
     try:
-        chip_smoke.run(toy, witness)
+        chip_smoke.run(toy)
     finally:
         witness.uninstall()
-    assert witness.errors == 0 and witness.modules
+    assert witness.errors == 0 and witness.compile_count

@@ -122,31 +122,6 @@ class TestMeshDSGD:
         np.testing.assert_allclose(np.asarray(mm.V), np.asarray(sm.V),
                                    rtol=2e-3, atol=2e-4)
 
-    def test_pallas_kernel_matches_single_device(self, gen):
-        """kernel='pallas' on the mesh (per-device block sweeps through the
-        VMEM-staged Pallas path inside shard_map, explicitly interpreted)
-        must match the single-device XLA run — so a measured kernel win on
-        hardware needs zero plumbing on the mesh too (VERDICT r4 #4).
-        Decaying schedule on purpose: exercises the runtime-scalar η."""
-        train = gen.generate(10000)
-        mesh = make_block_mesh(4)
-        mcfg = MeshDSGDConfig(num_factors=8, lambda_=0.01, iterations=3,
-                              learning_rate=0.05,
-                              lr_schedule="inverse_sqrt",
-                              seed=0, minibatch_size=256, init_scale=0.3,
-                              kernel="pallas", pallas_interpret=True)
-        mm = MeshDSGD(mcfg, mesh=mesh).fit(train)
-
-        scfg = DSGDConfig(num_factors=8, lambda_=0.01, iterations=3,
-                          learning_rate=0.05, lr_schedule="inverse_sqrt",
-                          seed=0, minibatch_size=256, init_scale=0.3)
-        sm = DSGD(scfg).fit(train, num_blocks=4)
-
-        np.testing.assert_allclose(np.asarray(mm.U), np.asarray(sm.U),
-                                   rtol=2e-3, atol=2e-4)
-        np.testing.assert_allclose(np.asarray(mm.V), np.asarray(sm.V),
-                                   rtol=2e-3, atol=2e-4)
-
     def test_bf16_tracks_f32_at_small_lr(self, gen):
         """factor_dtype='bfloat16' on the mesh XLA route must CONVERGE
         like f32, not just run: the route upcasts once per jitted

@@ -30,8 +30,8 @@ class TestPodShapedMesh:
         fields) the --family multichip regression gate consumes.
 
         The final stdout line must parse as JSON even with stderr
-        merged in (the stderr-flush-before-final-line hardening
-        pallas_probe.py also carries), so run with 2>&1."""
+        merged in (stderr is flushed before the final line), so run
+        with 2>&1."""
         env = {k: v for k, v in os.environ.items()
                if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
         proc = subprocess.run(

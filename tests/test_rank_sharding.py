@@ -94,16 +94,6 @@ class TestMeshDSGDParity:
             MeshDSGD(_dsgd_cfg(rank=6), partitioner=part).fit_device(
                 ru, ri, rv, NU, NI)
 
-    def test_pallas_kernel_refuses_model_parallel(self, ratings):
-        import dataclasses
-
-        ru, ri, rv, _ = ratings.to_numpy()
-        part = Partitioner(num_devices=8, model_parallel=2)
-        cfg = dataclasses.replace(_dsgd_cfg(), kernel="pallas",
-                                  pallas_interpret=True)
-        with pytest.raises(NotImplementedError, match="model"):
-            MeshDSGD(cfg, partitioner=part).fit_device(ru, ri, rv, NU, NI)
-
 
 class TestMeshALSParity:
     def _fit(self, part, ratings, implicit=False):
@@ -349,9 +339,8 @@ class TestRankShardedCheckpoint:
 
 class TestRooflineModelSize:
     def test_bytes_per_sweep_divides_by_model_size(self):
-        full = sgd_ops.dsgd_bytes_per_sweep(1000, 64, kernel="xla")
-        quarter = sgd_ops.dsgd_bytes_per_sweep(1000, 64, kernel="xla",
-                                               model_size=4)
+        full = sgd_ops.dsgd_bytes_per_sweep(1000, 64)
+        quarter = sgd_ops.dsgd_bytes_per_sweep(1000, 64, model_size=4)
         # the 16-byte COO term is per rating, not per factor column
         assert quarter == 1000 * (4 * 16 * 4 + 16)
         assert quarter < full
@@ -361,9 +350,6 @@ class TestRooflineModelSize:
             sgd_ops.dsgd_bytes_per_sweep(1000, 64, model_size=0)
         with pytest.raises(ValueError, match="divisible|divide"):
             sgd_ops.dsgd_bytes_per_sweep(1000, 63, model_size=4)
-        with pytest.raises(ValueError, match="pallas"):
-            sgd_ops.dsgd_bytes_per_sweep(1000, 64, kernel="pallas",
-                                         model_size=2)
 
     def test_collective_bytes_formula(self):
         assert sgd_ops.dsgd_collective_bytes_per_sweep(1000, 64, 1) == 0
